@@ -101,8 +101,8 @@ def gups_single_best(
 # -- handle/arena flavor: the oncilla number ------------------------------
 #
 # BASELINE config 4 says "random remote-access over ICI via ocm handles";
-# the flavors above measure XLA scatter on a standalone table (VERDICT r3
-# weak #5). Here the table IS an OcmAlloc extent inside an SpmdIciPlane
+# the flavors above measure XLA scatter on a standalone table. Here the
+# table IS an OcmAlloc extent inside an SpmdIciPlane
 # arena row — the same (rank, device, offset) handle-addressed HBM the
 # one-sided fabric serves. What the timed program does, precisely: slice
 # the extent out of the (donated) arena row, apply ``steps`` batched

@@ -42,9 +42,9 @@ _BLOCK = 4096
 
 # Aligned extents at/above this size route through the Pallas DMA kernels
 # (ops/pallas_ici.py pallas_read_rows/pallas_write_rows/pallas_local_copy)
-# on real TPU: the XLA dynamic-slice composition reads GB-scale extents at
-# ~14 GB/s where the DMA copy engine sustains hundreds (VERDICT r3 weak #3).
-# Below it, slice/update fuses fine and avoids a kernel launch.
+# on real TPU: the XLA dynamic-slice composition reads GB-scale extents far
+# below what the DMA copy engine sustains. Below it, slice/update fuses
+# fine and avoids a kernel launch.
 _PALLAS_IO_MIN = 1 << 20
 
 

@@ -374,9 +374,6 @@ def smoke(seed: int, verbose: bool = False) -> int:
 
 
 def main(argv=None) -> int:
-    from oncilla_tpu.utils.platform import honor_cpu_env
-
-    honor_cpu_env()
     ap = argparse.ArgumentParser(
         prog="python -m oncilla_tpu.elastic",
         description="elastic membership / live migration chaos smoke",

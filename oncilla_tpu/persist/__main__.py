@@ -184,9 +184,6 @@ def smoke(seed: int) -> int:
 
 
 def main(argv=None) -> int:
-    from oncilla_tpu.utils.platform import honor_cpu_env
-
-    honor_cpu_env()
     ap = argparse.ArgumentParser(
         prog="python -m oncilla_tpu.persist",
         description="FROZEN tier (disk-backed arenas + warm boot) smoke",

@@ -372,9 +372,6 @@ def run_soak(seed: int, tenants_n: int, rounds: int, chaos: bool,
 
 
 def main(argv=None) -> int:
-    from oncilla_tpu.utils.platform import honor_cpu_env
-
-    honor_cpu_env()
     ap = argparse.ArgumentParser(
         prog="python -m oncilla_tpu.qos",
         description="multi-tenant QoS soak harness",

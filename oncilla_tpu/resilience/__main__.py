@@ -869,9 +869,6 @@ def leader_smoke(seed: int, verbose: bool = False) -> int:
 
 
 def main(argv=None) -> int:
-    from oncilla_tpu.utils.platform import honor_cpu_env
-
-    honor_cpu_env()
     ap = argparse.ArgumentParser(
         prog="python -m oncilla_tpu.resilience",
         description="chaos/failover harness",

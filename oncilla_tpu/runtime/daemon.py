@@ -4780,9 +4780,6 @@ def main(argv=None) -> int:
     import signal
 
     from oncilla_tpu.runtime.membership import detect_rank, parse_nodefile
-    from oncilla_tpu.utils.platform import honor_cpu_env
-
-    honor_cpu_env()  # JAX_PLATFORMS=cpu must stick (see utils/platform.py)
 
     ap = argparse.ArgumentParser(description="oncilla-tpu daemon")
     ap.add_argument("nodefile")

@@ -1,7 +1,8 @@
 """``python -m oncilla_tpu.serving`` — the serving workload harness.
 
-``--smoke`` (CPU-only, in-process, the check.sh stage) proves the whole
-scenario end to end on a 3-daemon ``local_cluster`` with
+``--smoke`` (in-process, tiny model; the check.sh stage runs it with
+``JAX_PLATFORMS=cpu``, where its byte-for-byte gates hold) proves the
+whole scenario end to end on a 3-daemon ``local_cluster`` with
 ``OCM_REPLICAS=2``:
 
 - **paired cells**: the same tenant fleet (shared prompt prefix, two of
@@ -21,9 +22,9 @@ scenario end to end on a 3-daemon ``local_cluster`` with
 ``--bench`` runs the measured cells at a slightly larger scale and
 prints one JSON dict — ``bench.py`` records it as ``detail.serving``
 (tokens/s, cache-hit ratio, page-fault stall ms, per-tier occupancy,
-paired shared-vs-noshare deltas, chaos outcome). Cells run on the CPU
-backend; the 1-core-container caveat applies to every ratio (the PR-3
-precedent).
+paired shared-vs-noshare deltas, chaos outcome). The model is the tiny
+one, so its counts carry and its rates do not; the full-width run on the
+chip is ``chip_smoke.py`` at the repo root.
 """
 
 from __future__ import annotations
@@ -44,12 +45,15 @@ def _tiny_model():
 
 
 def _prompts(seed: int, tenants: int, shared_tokens: int,
-             suffix_tokens: int, vocab: int) -> list[list[int]]:
+             suffix_tokens, vocab: int) -> list[list[int]]:
     """Tenant prompts with a common prefix: tenants 0 and 1 are
     byte-identical (the CoW pair), the rest diverge after the shared
-    prefix."""
+    prefix. ``suffix_tokens`` is one length for every tenant or a
+    length per tenant."""
     import numpy as np
 
+    if isinstance(suffix_tokens, int):
+        suffix_tokens = [suffix_tokens] * tenants
     rng = np.random.default_rng(seed)
     shared = rng.integers(1, vocab, shared_tokens).tolist()
     prompts = []
@@ -57,7 +61,7 @@ def _prompts(seed: int, tenants: int, shared_tokens: int,
         if t == 1:
             prompts.append(list(prompts[0]))
             continue
-        suffix = rng.integers(1, vocab, suffix_tokens).tolist()
+        suffix = rng.integers(1, vocab, suffix_tokens[t]).tolist()
         prompts.append(shared + suffix)
     return prompts
 
@@ -78,7 +82,7 @@ def _build_engine(cfg, params, *, page_tokens: int, hot: int, warm: int,
                   prefetch_workers: int, max_active: int = 4,
                   batched: bool | None = None,
                   max_batch: int | None = None,
-                  frozen_backend=None):
+                  frozen_backend=None, keep_logits: bool = False):
     import oncilla_tpu as ocm
 
     from oncilla_tpu.serving.engine import ServingEngine
@@ -102,6 +106,7 @@ def _build_engine(cfg, params, *, page_tokens: int, hot: int, warm: int,
         params, cfg, store, prefix, page_tokens=page_tokens,
         max_active=max_active, prefetch_workers=prefetch_workers,
         name=name, batched=batched, max_batch=max_batch,
+        keep_logits=keep_logits,
     )
     return ctx, store, engine
 
@@ -342,7 +347,7 @@ def run_batched_sweep(seed: int, *, tenants: int = 8,
             and inter["tok_s"]
         },
         "note": (
-            "1-core CPU container: the axis shows dispatch-overhead "
+            "tiny model: the axis shows dispatch-overhead "
             "amortization, not MXU batching; jit-warm second runs"
         ),
     }
@@ -628,8 +633,8 @@ def run_warmboot(seed: int, *, tenants: int = 3, shared_tokens: int = 20,
         "deterministic_replay": True,
         "chaos_log": [list(t) for t in r1["log"]],
         "note": (
-            "1-core CPU container: TTFT deltas show prefill work "
-            "skipped via restored extents, not chip latency"
+            "tiny model: TTFT deltas show prefill work skipped via "
+            "restored extents, not a deployment's latency"
         ),
     }
 
@@ -750,23 +755,24 @@ def run_bench(seed: int = 1234, *, chaos: bool = True,
         out["warmboot"] = run_warmboot(seed)
     out["warmboot"]["audit"] = rec.summary()
     out["note"] = (
-        "1-core CPU container: tok/s is relative evidence, not a chip "
-        "number; remote tier is a loopback daemon pair"
+        "tiny model: tok/s is relative evidence, not a deployment's "
+        "rate; remote tier is a loopback daemon pair"
     )
     return out
 
 
 def main(argv=None) -> int:
-    from oncilla_tpu.utils.platform import honor_cpu_env
+    from oncilla_tpu.utils.platform import enable_compile_cache
 
-    honor_cpu_env()
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m oncilla_tpu.serving",
         description="disaggregated LLM serving harness (tiered paged KV "
                     "+ cross-tenant prefix sharing)",
     )
     ap.add_argument("--smoke", action="store_true",
-                    help="CPU-only end-to-end proof (check.sh stage)")
+                    help="end-to-end proof on the tiny model (check.sh "
+                         "stage)")
     ap.add_argument("--bench", action="store_true",
                     help="measured paired cells + chaos leg, one JSON "
                          "dict on stdout")

@@ -24,11 +24,16 @@ Key mechanics:
   (``OCM_MUX=1``). When the prefetch loses the race the wait is
   recorded as page-fault stall time (``prefetch_stall`` journal event +
   the stall counters).
-- **Determinism** — greedy decode (temperature 0) over float32-exact
-  page round-trips: the emitted token ids are a pure function of
-  (params, prompt), whatever tier a page happens to live in and however
-  a chaos schedule reshuffles the remote owners mid-decode. That is
-  what the chaos leg's byte-exactness assertion leans on.
+- **Determinism** — greedy decode (temperature 0) over exact page
+  round-trips (a page is cast to ``store_dtype``, at least as wide as
+  the model dtype, and back): a page's bytes never depend on the tier it
+  lives in or on how a chaos schedule reshuffles the remote owners
+  mid-decode. On the CPU backend in float32 the emitted token ids are
+  then a pure function of (params, prompt), which the chaos and pairing
+  gates assert byte for byte. On an accelerator the matmul tiling — and
+  with it the last bit of a logit — may change with the batch shape, so
+  there the check is logit-level agreement with the unpaged forward
+  (``chip_smoke.py``, via ``keep_logits``).
 """
 
 from __future__ import annotations
@@ -80,6 +85,9 @@ class SessionResult:
     out_tokens: list[int]
     stall_s: float
     prefix_tokens_reused: int
+    #: With ``keep_logits``: the float32 logits row each emitted token
+    #: was picked from, for logit-level checks against a reference.
+    out_logits: list[np.ndarray] | None = None
 
 
 class Prefetcher:
@@ -222,6 +230,7 @@ class _Session:
         self.entries: list[_Entry] = []
         self.shared_refs: list[SharedExtent] = []
         self.out: list[int] = []
+        self.logits: list[np.ndarray] = []
         self.pos = 0
         self.prompt_consumed = 0
         self.tail_len = 0
@@ -270,6 +279,7 @@ class ServingEngine:
         step_budget_ms: int | None = None,
         batched: bool | None = None,
         max_batch: int | None = None,
+        keep_logits: bool = False,
     ):
         self.params = params
         self.cfg = cfg
@@ -307,6 +317,7 @@ class ServingEngine:
         if max_batch is None:
             max_batch = int(os.environ.get("OCM_SERVING_MAX_BATCH", "8"))
         self.max_batch = max(1, int(max_batch))
+        self.keep_logits = bool(keep_logits)
         # Per-tick page-pool stacking cache: (key, pool_k, pool_v) —
         # rebuilt only when the resident page set changes (page
         # boundaries), not every token.
@@ -636,6 +647,8 @@ class ServingEngine:
                     or sess.prompt_consumed == len(sess.prompt))
             if emit:
                 sess.out.append(int(jnp.argmax(logits[0])))
+                if self.keep_logits:
+                    sess.logits.append(np.asarray(logits[0]))
                 self._note_first_token(sess)
                 if not prefill:
                     self.stats.note_tokens(1)
@@ -766,6 +779,8 @@ class ServingEngine:
                            tokens=P, pos=sess.pos)
         if sess.prompt_consumed == len(sess.prompt):
             sess.out.append(int(jnp.argmax(logits[0, -1])))
+            if self.keep_logits:
+                sess.logits.append(np.asarray(logits[0, -1]))
             self._note_first_token(sess)
             if len(sess.out) == sess.req.max_new_tokens:
                 sess.done = True
@@ -954,6 +969,7 @@ class ServingEngine:
         # (row b is bitwise jnp.argmax(logits[b]) — same bits, same
         # first-max tie-break); doubles as the step's device sync.
         best = np.asarray(jnp.argmax(logits, axis=-1))
+        kept = np.asarray(logits) if self.keep_logits else None
         if obs_journal.enabled():
             obs_journal.phase(
                 "jit_step", time.perf_counter() - j0,
@@ -979,6 +995,8 @@ class ServingEngine:
                     or sess.prompt_consumed == len(sess.prompt))
             if emit:
                 sess.out.append(int(best[b]))
+                if kept is not None:
+                    sess.logits.append(kept[b])
                 self._note_first_token(sess)
                 if not prefill:
                     self.stats.note_tokens(1)
@@ -1078,6 +1096,7 @@ class ServingEngine:
                 out_tokens=list(sess.out),
                 stall_s=round(sess.stall_s, 6),
                 prefix_tokens_reused=sess.prefix_tokens_reused,
+                out_logits=list(sess.logits) if self.keep_logits else None,
             ))
 
     # -- introspection ----------------------------------------------------

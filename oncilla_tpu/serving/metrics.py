@@ -44,6 +44,13 @@ class ServingStats:
         self.hits = 0
         self.promotes = 0
         self.demotes = 0
+        # The same moves by hop ("hbm>host", "remote>hbm", ...).
+        self.hops: dict[str, int] = {}
+        # Allocations / moves a tier's arena refused: ``capacity_free``
+        # = refused while the tier held fewer pages than its configured
+        # capacity (the arena is not doing what the capacity promised);
+        # ``pressure`` = refused at or past capacity (legitimate).
+        self.degraded = {"capacity_free": 0, "pressure": 0}
         self.cow_copies = 0
         # Prefix sharing.
         self.prefix_hits = 0
@@ -57,6 +64,7 @@ class ServingStats:
         # Live per-tier occupancy (set absolutely by the page store).
         self.tier_bytes: dict[str, int] = {}
         self.tier_pages: dict[str, int] = {}
+        self.tier_pages_peak: dict[str, int] = {}
         # Cold-tier (remote) data-plane traffic.
         self.remote_bytes_in = 0
         self.remote_bytes_out = 0
@@ -99,12 +107,20 @@ class ServingStats:
             if hit:
                 self.hits += 1
 
-    def note_move(self, promote: bool) -> None:
+    def note_move(self, promote: bool, src: str, dst: str) -> None:
         with self._mu:
             if promote:
                 self.promotes += 1
             else:
                 self.demotes += 1
+            hop = f"{src}>{dst}"
+            self.hops[hop] = self.hops.get(hop, 0) + 1
+
+    def note_degrade(self, capacity_free: bool) -> None:
+        with self._mu:
+            self.degraded[
+                "capacity_free" if capacity_free else "pressure"
+            ] += 1
 
     def note_cow(self) -> None:
         with self._mu:
@@ -183,6 +199,9 @@ class ServingStats:
         with self._mu:
             self.tier_pages = dict(tier_pages)
             self.tier_bytes = dict(tier_bytes)
+            for tier, n in tier_pages.items():
+                if n > self.tier_pages_peak.get(tier, 0):
+                    self.tier_pages_peak[tier] = n
 
     # -- export -----------------------------------------------------------
 
@@ -205,6 +224,7 @@ class ServingStats:
                 "hit_ratio": round(hits / lookups, 4) if lookups else 0.0,
                 "tier_bytes": dict(self.tier_bytes),
                 "tier_pages": dict(self.tier_pages),
+                "tier_pages_peak": dict(self.tier_pages_peak),
                 "prefix": {
                     "hits": self.prefix_hits,
                     "shared_bytes": max(self.prefix_shared_bytes, 0),
@@ -220,7 +240,9 @@ class ServingStats:
                 "moves": {
                     "promote": self.promotes,
                     "demote": self.demotes,
+                    "hops": dict(self.hops),
                 },
+                "degraded": dict(self.degraded),
                 "remote_bytes": {
                     "in": self.remote_bytes_in,
                     "out": self.remote_bytes_out,

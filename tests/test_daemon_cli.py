@@ -23,7 +23,11 @@ def test_daemon_cli_cluster_and_sigterm(tmp_path, rng):
     nodefile.write_text(
         "".join(f"{r} 127.0.0.1 {p}\n" for r, p in enumerate(ports))
     )
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # A platform that does not exist: a daemon only books device extents
+    # (ArenaAllocator), so it must never initialise a JAX backend — if it
+    # did, it would raise here, and on a TPU host it would take the chip
+    # from the one process that may hold it.
+    env = dict(os.environ, JAX_PLATFORMS="no_such_platform")
     logs = [open(tmp_path / f"daemon{r}.log", "wb") for r in range(2)]
     procs = [
         subprocess.Popen(
@@ -59,6 +63,9 @@ def test_daemon_cli_cluster_and_sigterm(tmp_path, rng):
             np.asarray(client.get(h, 64 << 10, 0)), data
         )
         client.free(h)
+        hd = client.alloc(64 << 10, OcmKind.REMOTE_DEVICE)
+        assert hd.rank == 1
+        client.free(hd)
         client.close()
     finally:
         for p in procs:

@@ -77,7 +77,7 @@ def test_small_arena_still_flat():
 
 def test_dma_row_kernels_interpret(rng):
     """The Pallas row-granular read/write/move kernels that serve aligned
-    multi-MiB extents on TPU (VERDICT r3: GB-scale reads must run at DMA
+    multi-MiB extents on TPU (GB-scale reads must run at DMA
     speed, not XLA dynamic-slice speed), executed here under the interpret
     machine on both arena layouts."""
     from oncilla_tpu.ops import pallas_ici as pi

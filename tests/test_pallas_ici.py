@@ -127,7 +127,7 @@ def test_local_copy_kernel(rng):
 
 def test_mib_scale_rows_and_transfer(mesh, rng):
     """MiB-scale arena rows + a 1 MiB transfer — the sizes that starved the
-    interpret machine before the windowed path (VERDICT r3 weak #4): the
+    interpret machine before the windowed path: the
     whole-arena kernel cannot hold a >=128 KiB ref off-TPU, so the copy
     runs as chunked <=96 KiB windows through the identical remote-DMA
     kernel semantics."""

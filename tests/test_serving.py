@@ -9,6 +9,8 @@ stand-in (``cold_sim``) unless a test spins its own cluster.
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import pytest
 
@@ -508,3 +510,134 @@ def test_free_ladder_survives_dead_primary():
                        and time.monotonic() < deadline):
                     time.sleep(0.1)
                 assert d.registry.live_count() == 0, d.rank
+
+
+# -- chip_smoke.py: the serving path's on-chip proof, rehearsed on the CPU --
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CHIP_SMOKE = os.path.join(_ROOT, "chip_smoke.py")
+
+
+def _chip_smoke(tmp_path, *args):
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    return subprocess.run(
+        [sys.executable, _CHIP_SMOKE, *args], env=env, cwd=_ROOT,
+        capture_output=True, text=True, timeout=300,
+    )
+
+
+def test_chip_smoke_refuses_cpu_and_rehearses(tmp_path):
+    """Without a TPU chip_smoke.py exits non-zero before doing any work
+    and prints no result; --cpu-rehearsal drives the same legs (memory
+    plane, chip-to-chip, serving over a local_cluster COLD tier, the
+    unpaged reference) at tiny size and says which platform it ran on."""
+    import json
+
+    refused = _chip_smoke(tmp_path)
+    assert refused.returncode == 2, refused.stderr[-2000:]
+    assert refused.stdout == ""
+    assert "no CPU path" in refused.stderr
+
+    run = _chip_smoke(tmp_path, "--cpu-rehearsal")
+    assert run.returncode == 0, run.stderr[-4000:]
+    line = json.loads(run.stdout.strip().splitlines()[-1])
+    assert line["ok"] is True and "failed" not in line
+    assert line["device"]["platform"] == "cpu"
+    assert line["sizing"] == "rehearsal"
+    assert list(line["phases"]) == [
+        "memory_plane", "multichip", "weights", "serving", "reference"
+    ]
+    serving = line["phases"]["serving"]
+    assert serving["requests"] > serving["max_batch"]
+    assert serving["prefix_hits"] > 0 and serving["cow"] > 0
+    assert all(serving["hops"][h] > 0 for h in
+               ("hbm>host", "host>remote", "remote>hbm", "host>hbm"))
+    assert serving["degraded"]["capacity_free"] == 0
+    assert line["phases"]["reference"]["argmax_share"] == 1.0
+
+
+@pytest.mark.parametrize(
+    "broken", ["memory_plane", "multichip", "weights", "serving",
+               "reference", None],
+)
+def test_chip_smoke_fails_on_any_failed_phase(monkeypatch, capsys, tmp_path,
+                                              broken):
+    """A failure in any one phase stops the run, names the phase in the
+    JSON line and makes the exit code non-zero (legs stubbed: this pins
+    the driver, the rehearsal above runs the legs)."""
+    import importlib
+    import json
+
+    import oncilla_tpu.models as models
+
+    monkeypatch.syspath_prepend(_ROOT)
+    cs = importlib.import_module("chip_smoke")
+
+    def leg(name):
+        def run(*args):
+            if name == broken:
+                raise AssertionError(f"injected into {name}")
+            return []
+        return run
+
+    for name in ("memory_plane", "multichip", "serving", "reference"):
+        monkeypatch.setattr(cs, name, leg(name))
+    real_init = models.init_params_host
+    monkeypatch.setattr(
+        models, "init_params_host",
+        lambda *a: leg("weights")() or real_init(*a),
+    )
+    # Set: the helper then touches no jax config in this test process.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    rc = cs.main(["--cpu-rehearsal"])
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    if broken is None:
+        assert rc == 0 and line["ok"] is True
+    else:
+        assert rc == 1 and line["ok"] is False
+        assert line["failed"] == broken
+        assert f"injected into {broken}" in line["error"]
+
+
+def test_compile_cache_dir_is_the_env_or_a_fixed_checkout_path(
+        monkeypatch, tmp_path):
+    """utils.platform.enable_compile_cache: with JAX_COMPILATION_CACHE_DIR
+    set nothing is set in code (JAX reads the variable itself); unset, the
+    cache is <checkout>/.jax_cache — the same string in two calls, in two
+    processes and from two working directories, because the path is part
+    of the cache key."""
+    import subprocess
+    import sys
+
+    import jax
+
+    from oncilla_tpu.utils import platform as plat
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, val: updates.append((key, val)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert plat.enable_compile_cache() == str(tmp_path)
+    assert updates == []
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(_ROOT, ".jax_cache")
+    assert plat.enable_compile_cache() == want == plat.enable_compile_cache()
+    assert updates == [("jax_compilation_cache_dir", want)] * 2
+
+    code = ("import jax; from oncilla_tpu.utils.platform import "
+            "enable_compile_cache as e; print(e()); "
+            "print(jax.config.jax_compilation_cache_dir)")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = _ROOT
+    seen = {
+        subprocess.run([sys.executable, "-c", code], env=env, cwd=cwd,
+                       capture_output=True, text=True, timeout=120).stdout
+        for cwd in (_ROOT, str(tmp_path))
+    }
+    assert seen == {f"{want}\n{want}\n"}
