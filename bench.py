@@ -775,7 +775,10 @@ def bench_serving(errors: dict) -> dict:
     """Serving workload harness (oncilla_tpu/serving/): paired
     shared-vs-noshare cells, the owner-kill chaos leg, and the
     batched-vs-interleaved tokens/s sweep (``batched_sweep`` key), in
-    this process on the tiny model."""
+    this process on the tiny model. Its token gates are byte-for-byte:
+    run on a v5e (PR 21) the sweep raised ``batched@2 diverged from
+    interleaved output``, so this stage — and with it ``main()`` — fails
+    on the chip until the benchmark PR gives it a logit-level check."""
     try:
         from oncilla_tpu.serving.__main__ import run_bench
 

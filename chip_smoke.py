@@ -10,20 +10,22 @@ calls, at the full width of the ~1.1B bf16 flagship
    ``LOCAL_DEVICE`` byte-exact at an unaligned sub-MiB size (XLA slice path)
    and at 1 MiB and 64 MiB aligned (the Pallas DMA row kernels), plus the
    typed-error probes;
-3. **multichip** (only with >= 2 devices) — one arena per chip on its own
+3. **weights** — the flagship's seeded weights, on the first chip only;
+4. **multichip** (only with >= 2 devices, after the weights so the chips'
+   allocators have different histories) — one arena per chip on its own
    device, and put/get/copy between every ordered pair of chips byte-exact
    with guard blocks, over per-chip ``DeviceArena``s and over the SPMD
    arena's two transports (CollectivePermute and the compiled Pallas
    remote-DMA kernel), bounded by a timeout that fails loudly;
-4. **serving** — ``ServingEngine`` + ``TieredPageStore`` + ``PrefixCache``
+5. **serving** — ``ServingEngine`` + ``TieredPageStore`` + ``PrefixCache``
    over an in-process ``local_cluster`` COLD tier: more requests than
    ``max_batch`` sharing a system prefix, HOT/WARM smaller than the working
    set so pages cross HOT -> WARM -> COLD and back;
-5. **reference** — the plain unpaged ``llama.forward`` teacher-forced on
+6. **reference** — the plain unpaged ``llama.forward`` teacher-forced on
    prompt + generated tokens on the same device: max |dlogit| and the share
    of generated tokens that are the reference's arg-max, against the
    thresholds in :class:`Sizing`;
-6. **kernels** — the Pallas kernels the legs relied on were built with
+7. **kernels** — the Pallas kernels the legs relied on were built with
    ``interpret=False`` and were dispatched.
 
 The last stdout line is one JSON object. Any failed phase stops the run:
@@ -91,8 +93,10 @@ class Sizing:
     # so logits of O(1) drift by a few 1e-2 over 16 layers and the arg-max
     # of near-flat random-weight logits flips on near-ties: measured on a
     # v5e (PR 21) max |dlogit| 0.058 on logits up to 5.2, arg-max share
-    # 0.972, the same in two runs. A page corrupted on a tier hop moves
-    # logits by O(1). float32 on the CPU agrees to 1e-6.
+    # 0.972 (140 of 144), the same in seven runs; the flagship's limits
+    # are about twice that drift. float32 on the CPU agrees to 1e-6, and
+    # tests/test_serving.py shows the rehearsal failing here when one page
+    # comes back from COLD with its words permuted.
     logit_tol: float
     argmax_floor: float
 
@@ -110,8 +114,8 @@ FLAGSHIP = Sizing(
     hot=16,
     warm=3,
     max_batch=4,
-    logit_tol=0.25,
-    argmax_floor=0.75,
+    logit_tol=0.12,
+    argmax_floor=0.9,
 )
 
 REHEARSAL = Sizing(
@@ -212,7 +216,7 @@ class CompileMeter:
         return {k: round(v - then[k], 1) for k, v in self.totals.items()}
 
 
-# -- 6. kernel evidence ------------------------------------------------------
+# -- 7. kernel evidence ------------------------------------------------------
 
 
 class KernelLedger:
@@ -334,7 +338,7 @@ def _expect(exc: type, probe) -> None:
     raise AssertionError(f"expected {exc.__name__}")
 
 
-# -- 3. several chips --------------------------------------------------------
+# -- 4. several chips --------------------------------------------------------
 
 
 def multichip(sz: Sizing, devices, on_tpu: bool, ledger: KernelLedger,
@@ -492,7 +496,7 @@ def multichip(sz: Sizing, devices, on_tpu: bool, ledger: KernelLedger,
               f"copies: {report['kernels']['ici_copy']}")
 
 
-# -- 4. serving --------------------------------------------------------------
+# -- 5. serving --------------------------------------------------------------
 
 
 def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
@@ -504,15 +508,10 @@ def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
         _build_engine,
         _cluster_cfg,
         _cold_client,
-        _prompts,
     )
     from oncilla_tpu.serving.engine import Request, ServingEngine
-    from oncilla_tpu.utils.debug import GLOBAL_TRACER
 
-    # One system prefix, then each request diverges; request 1 repeats
-    # request 0 (the copy-on-write pair).
-    prompts = _prompts(1234, len(sz.suffix_tokens), sz.shared_tokens,
-                       sz.suffix_tokens, cfg.vocab)
+    prompts = _prompts(sz, cfg.vocab)
     page_bytes = ServingEngine.page_nbytes(cfg, sz.page_tokens)
     # COLD holds whatever HOT and WARM cannot: size each daemon for all of it.
     pages_bound = sum(
@@ -522,7 +521,6 @@ def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
         host_arena_bytes=max(32 << 20, 2 * pages_bound * page_bytes),
     )
     ledger.mark()
-    spans_before = GLOBAL_TRACER.snapshot()
     with local_cluster(3, config=cluster_cfg) as cl:
         cold = _cold_client(cl, 0)
         ctx, store, engine = _build_engine(
@@ -539,7 +537,6 @@ def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
             results = engine.run()
             run_s = time.perf_counter() - t0
             meta = engine.metrics_meta()
-            spans = _span_seconds(spans_before, GLOBAL_TRACER.snapshot())
             arena_shape = ctx.device_arenas[0].buffer.shape
         finally:
             engine.close()
@@ -573,9 +570,6 @@ def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
         "stalls": meta["stalls"],
         "drained_ranks": drained,
         "engine_run_wall_s": round(run_s, 2),
-        # Host wall seconds inside each tracer span during engine.run()
-        # (spans nest: put/get/alloc/free/copy run inside the serve_* ones).
-        "span_wall_s": spans,
     })
     check(len(results) == len(prompts), "a request was lost")
     for res in results:
@@ -609,24 +603,27 @@ def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
     return [(p, r) for p, r in zip(prompts, _by_tenant(results))]
 
 
-def _span_seconds(before: dict, after: dict) -> dict:
-    """{op: [count, seconds]} spent in each tracer span between two
-    ``Tracer.snapshot()``s."""
-    out = {}
-    for op, now in after.items():
-        then = before.get(op, {"count": 0, "hist": {"sum_s": 0.0}})
-        count = now["count"] - then["count"]
-        if count:
-            out[op] = [count, round(now["hist"]["sum_s"]
-                                    - then["hist"]["sum_s"], 2)]
-    return out
+def _prompts(sz: Sizing, vocab: int) -> list[list[int]]:
+    """One system prefix, then each request diverges by its own suffix
+    length; request 1 repeats request 0 (the copy-on-write pair)."""
+    import numpy as np
+
+    rng = np.random.default_rng(1234)
+    shared = rng.integers(1, vocab, sz.shared_tokens).tolist()
+    prompts: list[list[int]] = []
+    for r, n in enumerate(sz.suffix_tokens):
+        if r == 1:
+            prompts.append(list(prompts[0]))
+        else:
+            prompts.append(shared + rng.integers(1, vocab, n).tolist())
+    return prompts
 
 
 def _by_tenant(results) -> list:
     return sorted(results, key=lambda r: int(r.tenant[1:]))
 
 
-# -- 5. reference ------------------------------------------------------------
+# -- 6. reference ------------------------------------------------------------
 
 
 def reference(sz: Sizing, cfg, params, served: list, report: dict) -> None:
@@ -712,14 +709,6 @@ def run(sz: Sizing, line: dict) -> None:
     ledger = KernelLedger()
     with phase("memory_plane") as report:
         memory_plane(sz, on_tpu, ledger, report)
-    devices = jax.local_devices()[:MAX_MESH]
-    if len(devices) >= 2:
-        # Before the weights load: the remote-DMA kernel addresses the
-        # peer's arena row by its local HBM address, so the rows are
-        # allocated while every chip's allocator has the same history.
-        with phase("multichip") as report, deadline(
-                MULTICHIP_DEADLINE_S, "multichip", line):
-            multichip(sz, devices, on_tpu, ledger, report)
     cfg = sz.model()
     with phase("weights") as report:
         params = init_params_host(0, cfg)
@@ -730,6 +719,13 @@ def run(sz: Sizing, line: dict) -> None:
             kv_heads=cfg.n_kv_heads, ffn=cfg.ffn_hidden, vocab=cfg.vocab,
             dtype=cfg.dtype, param_gb=round(nbytes / 1e9, 3),
         )
+    devices = jax.local_devices()[:MAX_MESH]
+    if len(devices) >= 2:
+        # After the weights, as in a deployment: chip 0's allocator has a
+        # history the other chips' do not when the first copy is posted.
+        with phase("multichip") as report, deadline(
+                MULTICHIP_DEADLINE_S, "multichip", line):
+            multichip(sz, devices, on_tpu, ledger, report)
     with phase("serving") as report:
         served = serving(sz, cfg, params, on_tpu, ledger, report)
     with phase("reference") as report:

@@ -343,9 +343,12 @@ def _cached_ici_copy(
     XLA keeps the (1, row_bytes) uint8 shard in its own tiling, so the
     block view the kernel wants is materialised in a program temporary and
     copied back: two whole-row relayouts per transfer (compiled HLO on
-    v5e, PR 21). The remote DMA therefore lands in that temporary, which
-    every chip holds at the same program offset, not in the caller's
-    buffer."""
+    v5e, PR 21), and the remote DMA lands in that temporary, not in the
+    caller's buffer. Run on a 2x2 v5e with 2.3 GB / 0 / 737 MiB / 100 MiB
+    of other data on the four chips (PR 21): every ordered pair byte-exact
+    with the temporary in VMEM (8 MiB rows) and in HBM (256 MiB rows),
+    for arenas made before and after that data. Lowering and compiling
+    the 256 MiB-row program took about ten minutes there."""
     row_blocks = row_bytes // BLOCK
 
     def shard_fn(arena_shard, s_dev, d_dev, s_blk, d_blk):

@@ -45,15 +45,12 @@ def _tiny_model():
 
 
 def _prompts(seed: int, tenants: int, shared_tokens: int,
-             suffix_tokens, vocab: int) -> list[list[int]]:
+             suffix_tokens: int, vocab: int) -> list[list[int]]:
     """Tenant prompts with a common prefix: tenants 0 and 1 are
     byte-identical (the CoW pair), the rest diverge after the shared
-    prefix. ``suffix_tokens`` is one length for every tenant or a
-    length per tenant."""
+    prefix."""
     import numpy as np
 
-    if isinstance(suffix_tokens, int):
-        suffix_tokens = [suffix_tokens] * tenants
     rng = np.random.default_rng(seed)
     shared = rng.integers(1, vocab, shared_tokens).tolist()
     prompts = []
@@ -61,7 +58,7 @@ def _prompts(seed: int, tenants: int, shared_tokens: int,
         if t == 1:
             prompts.append(list(prompts[0]))
             continue
-        suffix = rng.integers(1, vocab, suffix_tokens[t]).tolist()
+        suffix = rng.integers(1, vocab, suffix_tokens).tolist()
         prompts.append(shared + suffix)
     return prompts
 

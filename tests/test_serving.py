@@ -549,7 +549,7 @@ def test_chip_smoke_refuses_cpu_and_rehearses(tmp_path):
     assert line["device"]["platform"] == "cpu"
     assert line["sizing"] == "rehearsal"
     assert list(line["phases"]) == [
-        "memory_plane", "multichip", "weights", "serving", "reference"
+        "memory_plane", "weights", "multichip", "serving", "reference"
     ]
     serving = line["phases"]["serving"]
     assert serving["requests"] > serving["max_batch"]
@@ -601,6 +601,52 @@ def test_chip_smoke_fails_on_any_failed_phase(monkeypatch, capsys, tmp_path,
         assert rc == 1 and line["ok"] is False
         assert line["failed"] == broken
         assert f"injected into {broken}" in line["error"]
+
+
+_CORRUPT_ONE_COLD_PAGE = """
+import sys
+import numpy as np
+import chip_smoke
+from oncilla_tpu.serving.tiers import Tier, TieredPageStore
+
+real_get, hit = TieredPageStore._get, []
+
+def get(self, tier, handle, nbytes, out):
+    raw = real_get(self, tier, handle, nbytes, out)
+    if tier == Tier.COLD and not hit:
+        hit.append(handle)
+        raw = np.array(raw).reshape(-1, 4)[::-1].reshape(-1)
+        if out is not None:
+            out[:nbytes] = raw
+    return raw
+
+TieredPageStore._get = get
+rc = chip_smoke.main(["--cpu-rehearsal"])
+print("corrupted pages:", len(hit), file=sys.stderr)
+sys.exit(rc)
+"""
+
+
+def test_chip_smoke_reference_catches_a_corrupted_page(tmp_path):
+    """The reference phase is what notices a page that changed on a tier
+    hop: permute the float32 words of the first page promoted back from
+    COLD (finite values, wrong places) and the rehearsal fails there, on
+    the logit tolerance. In a process of its own, like a real run."""
+    import json
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_ROOT,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    run = subprocess.run(
+        [sys.executable, "-c", _CORRUPT_ONE_COLD_PAGE], env=env, cwd=_ROOT,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert "corrupted pages: 1" in run.stderr, run.stderr[-2000:]
+    line = json.loads(run.stdout.strip().splitlines()[-1])
+    assert run.returncode == 1 and line["ok"] is False
+    assert line["failed"] == "reference", line.get("error")
+    assert "max |dlogit|" in line["error"]
 
 
 def test_compile_cache_dir_is_the_env_or_a_fixed_checkout_path(
