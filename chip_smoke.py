@@ -28,9 +28,12 @@ calls, at the full width of the ~1.1B bf16 flagship
 7. **kernels** — the Pallas kernels the legs relied on were built with
    ``interpret=False`` and were dispatched.
 
-The last stdout line is one JSON object. Any failed phase stops the run:
-the line then carries ``"ok": false`` and the exit code is 1. Wall seconds
-per phase are wall time with compilation included, not a benchmark.
+Standard output is two JSON lines: the report (what every phase saw, its
+wall seconds and compile counts), then the verdict the driver reads,
+``{"ok": ..., "device": {"platform", "kind", "count"}}`` and nothing else.
+Any failed phase stops the run: both lines then carry ``"ok": false``, the
+report names the phase, and the exit code is 1. Wall seconds per phase are
+wall time with compilation included, not a benchmark.
 
 ``--cpu-rehearsal`` runs the same legs at tiny size on the CPU backend (what
 ``tests/`` calls, and the dry run before spending chip time); its line says
@@ -142,11 +145,19 @@ def check(cond: bool, msg: str) -> None:
         raise AssertionError(msg)
 
 
+def emit(report: dict) -> None:
+    """The report, then as the LAST stdout line the verdict: exactly
+    ``ok`` and ``device``, which is all the driver's check accepts there."""
+    print(json.dumps(report))
+    print(json.dumps({"ok": report["ok"], "device": report["device"]}),
+          flush=True)
+
+
 @contextlib.contextmanager
 def deadline(seconds: float, label: str, line: dict):
     """Fail loudly if the block outlives ``seconds``: a kernel hung on the
     chip blocks inside a C call no exception can reach, so a thread prints
-    the failure line and hard-exits."""
+    the failure lines and hard-exits."""
     done = threading.Event()
 
     def watch() -> None:
@@ -154,7 +165,7 @@ def deadline(seconds: float, label: str, line: dict):
             return
         line.update(ok=False, failed=label,
                     error=f"no result after {seconds:.0f} s (hung?)")
-        print(json.dumps(line), flush=True)
+        emit(line)
         os._exit(1)
 
     threading.Thread(target=watch, daemon=True, name=f"deadline-{label}").start()
@@ -784,7 +795,7 @@ def main(argv=None) -> int:
         traceback.print_exc()
         line["error"] = f"{type(e).__name__}: {e}"
     line["wall_s"]["total"] = round(time.perf_counter() - t0, 1)
-    print(json.dumps(line), flush=True)
+    emit(line)
     return 0 if line["ok"] else 1
 
 

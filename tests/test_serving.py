@@ -530,13 +530,26 @@ def _chip_smoke(tmp_path, *args):
     )
 
 
+def _smoke_lines(stdout):
+    """chip_smoke.py's standard output: the report, then as the last line
+    the verdict the driver reads, which holds exactly ``ok`` and
+    ``device`` (platform, kind, count)."""
+    import json
+
+    report, verdict = map(json.loads, stdout.strip().splitlines()[-2:])
+    assert set(verdict) == {"ok", "device"}
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert verdict == {"ok": report["ok"], "device": report["device"]}
+    assert isinstance(verdict["ok"], bool)
+    assert isinstance(verdict["device"]["count"], int)
+    return report
+
+
 def test_chip_smoke_refuses_cpu_and_rehearses(tmp_path):
     """Without a TPU chip_smoke.py exits non-zero before doing any work
     and prints no result; --cpu-rehearsal drives the same legs (memory
     plane, chip-to-chip, serving over a local_cluster COLD tier, the
     unpaged reference) at tiny size and says which platform it ran on."""
-    import json
-
     refused = _chip_smoke(tmp_path)
     assert refused.returncode == 2, refused.stderr[-2000:]
     assert refused.stdout == ""
@@ -544,7 +557,7 @@ def test_chip_smoke_refuses_cpu_and_rehearses(tmp_path):
 
     run = _chip_smoke(tmp_path, "--cpu-rehearsal")
     assert run.returncode == 0, run.stderr[-4000:]
-    line = json.loads(run.stdout.strip().splitlines()[-1])
+    line = _smoke_lines(run.stdout)
     assert line["ok"] is True and "failed" not in line
     assert line["device"]["platform"] == "cpu"
     assert line["sizing"] == "rehearsal"
@@ -567,10 +580,9 @@ def test_chip_smoke_refuses_cpu_and_rehearses(tmp_path):
 def test_chip_smoke_fails_on_any_failed_phase(monkeypatch, capsys, tmp_path,
                                               broken):
     """A failure in any one phase stops the run, names the phase in the
-    JSON line and makes the exit code non-zero (legs stubbed: this pins
+    report line and makes the exit code non-zero (legs stubbed: this pins
     the driver, the rehearsal above runs the legs)."""
     import importlib
-    import json
 
     import oncilla_tpu.models as models
 
@@ -594,7 +606,7 @@ def test_chip_smoke_fails_on_any_failed_phase(monkeypatch, capsys, tmp_path,
     # Set: the helper then touches no jax config in this test process.
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     rc = cs.main(["--cpu-rehearsal"])
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    line = _smoke_lines(capsys.readouterr().out)
     if broken is None:
         assert rc == 0 and line["ok"] is True
     else:
@@ -632,7 +644,6 @@ def test_chip_smoke_reference_catches_a_corrupted_page(tmp_path):
     hop: permute the float32 words of the first page promoted back from
     COLD (finite values, wrong places) and the rehearsal fails there, on
     the logit tolerance. In a process of its own, like a real run."""
-    import json
     import subprocess
     import sys
 
@@ -643,7 +654,7 @@ def test_chip_smoke_reference_catches_a_corrupted_page(tmp_path):
         capture_output=True, text=True, timeout=300,
     )
     assert "corrupted pages: 1" in run.stderr, run.stderr[-2000:]
-    line = json.loads(run.stdout.strip().splitlines()[-1])
+    line = _smoke_lines(run.stdout)
     assert run.returncode == 1 and line["ok"] is False
     assert line["failed"] == "reference", line.get("error")
     assert "max |dlogit|" in line["error"]
