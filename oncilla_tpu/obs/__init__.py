@@ -29,6 +29,9 @@ always-on trace-context propagation:
   STATUS_PROM protocol request (no extra listening port).
 - :mod:`~.watchdog` — ``OCM_SLOWOP_US``: a thread that flags spans
   exceeding the threshold into the journal with their trace context.
+- :mod:`~.devgaps` — the join of those spans with a profiler trace of the
+  device: idle time by the innermost ``ocm:*`` span open on the host
+  (``python -m oncilla_tpu.obs gaps <trace>``; needs JAX, imported on use).
 - ``python -m oncilla_tpu.obs`` — the cluster CLI (status table,
   ``--prom``, ``--trace``; see :mod:`~.__main__`).
 

@@ -31,7 +31,11 @@ no extra listener) and renders:
   ``.seg`` files / journal JSONL dumps into cross-rank op trees and
   print per-phase critical-path latency attribution
   (:mod:`~oncilla_tpu.obs.critpath`), with ``--min-attrib`` /
-  ``--require-cross-rank`` gates for CI.
+  ``--require-cross-rank`` gates for CI;
+- ``gaps <trace dir or .xplane.pb[.gz]>``: the device's idle time in a
+  profiler trace by the innermost ``ocm:*`` span open on the scheduler's
+  thread, with the device programs each span dispatched
+  (:mod:`~oncilla_tpu.obs.devgaps`): "why is the chip idle".
 
 Membership comes from ``--nodefile`` or ``$OCM_NODEFILE`` (the same file
 the daemons were started with).
@@ -509,6 +513,35 @@ def _critpath_cmd(argv: list[str]) -> int:
     return 0
 
 
+def _gaps_cmd(argv: list[str]) -> int:
+    """``python -m oncilla_tpu.obs gaps <trace>`` — device idle time by
+    host span (obs/devgaps.py)."""
+    from oncilla_tpu.obs import devgaps
+
+    ap = argparse.ArgumentParser(
+        prog="python -m oncilla_tpu.obs gaps",
+        description="device idle time by the host span it fell in",
+    )
+    ap.add_argument("trace", help="a profiler trace directory, or an "
+                                  ".xplane.pb / .xplane.pb.gz file")
+    ap.add_argument("--chip", type=int, default=0, metavar="N",
+                    help="the device plane /device:TPU:N (default 0)")
+    ap.add_argument("--json", action="store_true", dest="as_json",
+                    help="machine-readable result on stdout")
+    args = ap.parse_args(argv)
+    try:
+        result = devgaps.gaps(args.trace, chip=args.chip)
+    except (OSError, ValueError) as e:
+        print(f"gaps: {e}", file=sys.stderr)
+        return 2
+    if args.as_json:
+        json.dump(result, sys.stdout, indent=2)
+        print()
+    else:
+        sys.stdout.write(devgaps.render(result))
+    return 0
+
+
 def _slo_table(result: dict, history_meta: dict) -> None:
     cols = ["objective", "kind", "prio", "target", "ok", "active",
             "burn_fast", "burn_slow", "err_fast", "n_fast"]
@@ -727,6 +760,8 @@ def main(argv: list[str] | None = None) -> int:
         return _critpath_cmd(argv[1:])
     if argv and argv[0] == "slo":
         return _slo_cmd(argv[1:])
+    if argv and argv[0] == "gaps":
+        return _gaps_cmd(argv[1:])
     ap = argparse.ArgumentParser(
         prog="python -m oncilla_tpu.obs",
         description="oncilla-tpu cluster observability",

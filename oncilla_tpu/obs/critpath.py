@@ -13,8 +13,8 @@ tree's wall time is decomposed:
   cross-host clock skew);
 * ``phase`` journal events (``journal.phase``) carve named slices out
   of the span they bind to — client queue, mux in-flight window wait,
-  daemon dispatch queue, replica fan-out, KV residency, the fused jit
-  step;
+  daemon dispatch queue, replica fan-out (the serving tick needs none:
+  its residency / pool / dispatch / sync split is child spans);
 * whatever self time no phase claims is attributed to the span's own op
   name (the handler actually doing the work), so 100% of a tree's wall
   time lands on a *named* phase — "unattributed" is a bug in this

@@ -88,6 +88,10 @@ class SessionResult:
     #: With ``keep_logits``: the float32 logits row each emitted token
     #: was picked from, for logit-level checks against a reference.
     out_logits: list[np.ndarray] | None = None
+    #: Where the time to the first token went (``queue_s``, ``chunk_s``,
+    #: ``tail_s``: they sum to the TTFT :meth:`ServingStats.note_ttft`
+    #: recorded; ``unseated_ticks``). None when no first token was timed.
+    ttft_parts: dict | None = None
 
 
 class Prefetcher:
@@ -243,6 +247,13 @@ class _Session:
         self.priority = int(getattr(req, "priority", PRIO_NORMAL))
         self.submit_t = float(getattr(req, "_submit_t", 0.0) or 0.0)
         self.ttft_noted = False
+        # Request anatomy, on submit_t's clock: admission, and the first
+        # moment no whole page of prompt remains (the sub-page remainder
+        # then rides the fused step one token a tick).
+        self.admit_t = 0.0
+        self.pages_done_t: float | None = None
+        self.unseated_ticks = 0
+        self.ttft_parts: dict | None = None
         self._tail_shape = (cfg.n_layers, 1, cfg.n_kv_heads, page_tokens,
                             cfg.head_dim)
         self._tail_dt = jnp.dtype(dtype)
@@ -430,7 +441,17 @@ class ServingEngine:
         # every page boundary), not an admission-time lookup: sessions
         # admitted simultaneously still dedup against pages a sibling
         # publishes one turn later.
-        return _Session(req, self.cfg, self.page_tokens, self.cfg.dtype)
+        sess = _Session(req, self.cfg, self.page_tokens, self.cfg.dtype)
+        sess.admit_t = time.perf_counter()
+        self._note_pages_done(sess)
+        return sess
+
+    def _note_pages_done(self, sess: _Session) -> None:
+        """Stamp the first moment after admission at which no whole page
+        of prompt remains (``_bulk_prefill`` turns false once and stays
+        false): called after admission, every adoption and every chunk."""
+        if sess.pages_done_t is None and not self._bulk_prefill(sess):
+            sess.pages_done_t = time.perf_counter()
 
     def _match_more(self, sess: _Session) -> None:
         """At a page boundary during prefill, adopt any shared extent
@@ -681,61 +702,93 @@ class ServingEngine:
         return done
 
     def _tick(self) -> None:
-        # Admission is priority-aware: PRIO_HIGH requests seat first
-        # when the queue outruns max_active (stable within a class, so
-        # equal-priority arrival order is preserved).
-        if self.queue and len(self.active) < self.max_active:
-            self.queue.sort(
-                key=lambda r: -getattr(r, "priority", PRIO_NORMAL)
-            )
-            while self.queue and len(self.active) < self.max_active:
-                self.active.append(self._admit(self.queue.pop(0)))
-        if self.step_budget_ms:
-            from oncilla_tpu.resilience import timebudget
+        """One scheduler tick under one root span. Its six children
+        (``tick.admit``, ``tick.match``, ``serve_prefill_chunk``,
+        ``tick.select``, ``serve_batch_step``, ``tick.finish``) cover it
+        without remainder and every span name has exactly one parent, so
+        self time falls out of per-name totals (docs/OBSERVABILITY.md,
+        "Serving tick anatomy")."""
+        span = GLOBAL_TRACER.span
+        with span("tick"):
+            with span("tick.admit"):
+                # Admission is priority-aware: PRIO_HIGH requests seat
+                # first when the queue outruns max_active (stable within
+                # a class, so equal-priority arrival order is preserved).
+                if self.queue and len(self.active) < self.max_active:
+                    self.queue.sort(
+                        key=lambda r: -getattr(r, "priority", PRIO_NORMAL)
+                    )
+                    while (self.queue
+                           and len(self.active) < self.max_active):
+                        self.active.append(self._admit(self.queue.pop(0)))
+                if self.step_budget_ms:
+                    from oncilla_tpu.resilience import timebudget
 
-            self._step_budget = timebudget.Budget.from_ms(
-                self.step_budget_ms
-            )
-        prefetch_on = self.prefetcher.mode != "off"
-        for sess in self.active:
-            self._match_more(sess)
-            if prefetch_on:
-                self._prefetch_for(sess)
-        # Chunked prefill: a long prompt admits one page-sized slice per
-        # tick (one paged_decode_page_jit dispatch) instead of streaming
-        # its tokens through the shared batch — the batch never stalls
-        # behind a prompt, and the slice is bitwise the token-wise path.
-        chunked = False
-        for sess in self.active:
-            if not self._bulk_prefill(sess):
-                continue
-            # Re-probe the prefix cache first: a session earlier in this
-            # same tick may have shipped (and registered) exactly the
-            # page this one is about to compute — matching here is what
-            # lets identical prompts converge on shared pages (and CoW
-            # partial adoption) instead of prefilling in lockstep.
-            self._match_more(sess)
-            if self._bulk_prefill(sess):
-                # Span per chunk: phases inside (and any cold-tier dcn
-                # fetch spans the chunk faults on) tree under it.
-                with GLOBAL_TRACER.span("serve_prefill_chunk"):
-                    self._prefill_chunk(sess)
-                chunked = True
-        batch = self._select_batch(allow_force=not chunked)
-        if batch:
-            with GLOBAL_TRACER.span("serve_batch_step"):
+                    self._step_budget = timebudget.Budget.from_ms(
+                        self.step_budget_ms
+                    )
+            with span("tick.match"):
+                prefetch_on = self.prefetcher.mode != "off"
+                for sess in self.active:
+                    self._match_more(sess)
+                    self._note_pages_done(sess)
+                    if prefetch_on:
+                        self._prefetch_for(sess)
+            # Chunked prefill: a long prompt admits one page-sized slice
+            # per tick (one paged_decode_page_jit dispatch) instead of
+            # streaming its tokens through the shared batch — the batch
+            # never stalls behind a prompt, and the slice is bitwise the
+            # token-wise path.
+            chunked = False
+            for sess in self.active:
+                if not self._bulk_prefill(sess):
+                    continue
+                # Span per chunk: its phases (and any cold-tier dcn fetch
+                # spans the chunk faults on) tree under it.
+                with span("serve_prefill_chunk"):
+                    # Re-probe the prefix cache first: a session earlier
+                    # in this same tick may have shipped (and registered)
+                    # exactly the page this one is about to compute —
+                    # matching here is what lets identical prompts
+                    # converge on shared pages (and CoW partial adoption)
+                    # instead of prefilling in lockstep.
+                    self._match_more(sess)
+                    if self._bulk_prefill(sess):
+                        self._prefill_chunk(sess)
+                        chunked = True
+                    self._note_pages_done(sess)
+            with span("tick.select"):
+                batch = self._select_batch(allow_force=not chunked)
+            if batch:
                 self._batch_step(batch)
-        for sess in self.active:
-            if sess.done:
-                self._finish(sess)
-        self.active = [s for s in self.active if not s.done]
+            with span("tick.finish"):
+                for sess in self.active:
+                    if sess.done:
+                        self._finish(sess)
+                self.active = [s for s in self.active if not s.done]
 
     def _note_first_token(self, sess: _Session) -> None:
         """TTFT: observed once per session, on its first emitted token
-        (submit -> first visible output)."""
-        if len(sess.out) == 1 and sess.submit_t and not sess.ttft_noted:
-            sess.ttft_noted = True
-            self.stats.note_ttft(time.perf_counter() - sess.submit_t)
+        (submit -> first visible output), split where it was spent:
+        waiting for ``max_active``, whole pages (adoption and one chunk a
+        tick), and the sub-page remainder riding the fused step. A
+        whole-page prompt gets its token from its last chunk: no tail."""
+        if len(sess.out) != 1 or not sess.submit_t or sess.ttft_noted:
+            return
+        sess.ttft_noted = True
+        now = time.perf_counter()
+        if sess.pages_done_t is None:
+            sess.pages_done_t = now
+        sess.ttft_parts = {
+            "queue_s": sess.admit_t - sess.submit_t,
+            "chunk_s": sess.pages_done_t - sess.admit_t,
+            "tail_s": now - sess.pages_done_t,
+            "unseated_ticks": sess.unseated_ticks,
+        }
+        ttft_s = now - sess.submit_t
+        self.stats.note_ttft(ttft_s, **sess.ttft_parts)
+        obs_journal.record("ttft", tenant=sess.req.tenant,
+                           ttft_s=round(ttft_s, 6), **sess.ttft_parts)
 
     def _bulk_prefill(self, sess: _Session) -> bool:
         """True while >= one whole page of prompt remains and the tail is
@@ -747,27 +800,18 @@ class ServingEngine:
     def _prefill_chunk(self, sess: _Session) -> None:
         """Teacher-force one full page of prompt in one fused dispatch,
         ship it, and emit the seed token when the prompt completes."""
+        span = GLOBAL_TRACER.span
         P = self.page_tokens
-        r0 = time.perf_counter()
-        self._ensure_resident(sess)
-        k_ctx, v_ctx = self._context(sess)
-        if obs_journal.enabled():
-            obs_journal.phase(
-                "residency", time.perf_counter() - r0,
-                priority=sess.priority,
-            )
-        pc = sess.prompt_consumed
-        chunk = sess.prompt[pc:pc + P]
-        meta = jnp.asarray([sess.pos, 0], jnp.int32)
-        j0 = time.perf_counter()
-        logits, sess.tail_k, sess.tail_v = paged_decode_page_jit(
-            self.params, jnp.asarray([chunk], jnp.int32), meta,
-            k_ctx, v_ctx, sess.tail_k, sess.tail_v, self.cfg,
-        )
-        if obs_journal.enabled():
-            obs_journal.phase(
-                "jit_step", time.perf_counter() - j0,
-                priority=sess.priority,
+        with span("prefill.residency"):
+            self._ensure_resident(sess)
+            k_ctx, v_ctx = self._context(sess)
+        with span("prefill.dispatch"):
+            pc = sess.prompt_consumed
+            chunk = sess.prompt[pc:pc + P]
+            meta = jnp.asarray([sess.pos, 0], jnp.int32)
+            logits, sess.tail_k, sess.tail_v = paged_decode_page_jit(
+                self.params, jnp.asarray([chunk], jnp.int32), meta,
+                k_ctx, v_ctx, sess.tail_k, sess.tail_v, self.cfg,
             )
         sess.pos += P
         sess.tail_len = P
@@ -778,14 +822,16 @@ class ServingEngine:
         obs_journal.record("prefill_chunk", tenant=sess.req.tenant,
                            tokens=P, pos=sess.pos)
         if sess.prompt_consumed == len(sess.prompt):
-            sess.out.append(int(jnp.argmax(logits[0, -1])))
-            if self.keep_logits:
-                sess.logits.append(np.asarray(logits[0, -1]))
+            with span("prefill.sync"):
+                sess.out.append(int(jnp.argmax(logits[0, -1])))
+                if self.keep_logits:
+                    sess.logits.append(np.asarray(logits[0, -1]))
             self._note_first_token(sess)
             if len(sess.out) == sess.req.max_new_tokens:
                 sess.done = True
-        self._ship(sess)
-        self._match_more(sess)
+        with span("prefill.ship"):
+            self._ship(sess)
+            self._match_more(sess)
 
     def _yields_cold(self, sess: _Session) -> bool:
         """True when a seat should be given up this tick: some context
@@ -821,7 +867,13 @@ class ServingEngine:
         ready.sort(key=lambda s: -s.priority)
         for sess in ready[self.max_batch:]:
             self.stats.note_preempt("slot")
-        return ready[:self.max_batch]
+        batch = ready[:self.max_batch]
+        for sess in runnable:
+            # Request anatomy: a tick a runnable session spent without a
+            # seat while still waiting for its first token.
+            if not sess.out and sess not in batch:
+                sess.unseated_ticks += 1
+        return batch
 
     def _ensure_resident_batch(self, batch: list[_Session]) -> None:
         """Residency for one fused tick: every miss's bytes are obtained
@@ -907,113 +959,113 @@ class ServingEngine:
     def _batch_step(self, batch: list[_Session]) -> None:
         """ONE fused jit dispatch advancing every seated session by one
         token, then per-session scatter of logits/tails/bookkeeping —
-        bitwise the interleaved per-session step."""
-        t0 = time.perf_counter()
-        self._ensure_resident_batch(batch)
-        if obs_journal.enabled():
-            # Residency vs compute: the two halves of a tick the "where
-            # did the step budget go" question needs split. Bound to the
-            # serve_batch_step span (ambient, installed by _tick).
-            obs_journal.phase(
-                "residency", time.perf_counter() - t0,
-                priority=max(s.priority for s in batch),
-            )
+        bitwise the interleaved per-session step. Runs under the
+        ``serve_batch_step`` span; its ``step.*`` children cover it."""
+        span = GLOBAL_TRACER.span
         P = self.page_tokens
         cfg = self.cfg
-        pool_k, pool_v, table, tables = self._batch_pool(batch)
-        b_pad = _pow2(len(batch))
-        toks, metas, prefills = [], [], []
-        for sess, trow in zip(batch, tables):
-            if sess.prompt_consumed < len(sess.prompt):
-                tok = sess.prompt[sess.prompt_consumed]
-                sess.prompt_consumed += 1
-                prefill = True
-                self.stats.note_tokens(1, phase="prefill")
-            else:
-                tok = sess.out[-1] if sess.out else sess.prompt[-1]
-                prefill = False
-            toks.append(tok)
-            prefills.append(prefill)
-            metas.append([sess.pos, sess.tail_len, len(trow) * P, 0])
-        pad_b = b_pad - len(batch)
-        toks += [0] * pad_b
-        metas += [[0, 0, 0, 0]] * pad_b
-        st = self._tail_stack
-        if (st is not None and st[0] == batch
-                and all(s.tail_k is None for s in batch)):
-            # Same seated sessions as last step and nobody shipped: the
-            # previous step's stacked tails ARE this step's inputs —
-            # no per-session slices, no concat (they get donated).
-            tail_k, tail_v = st[1], st[2]
-            self._tail_stack = None
-        else:
-            self._flush_tail_stack()
-            tshape = (cfg.n_layers, 1, cfg.n_kv_heads, P, cfg.head_dim)
-            ztail = jnp.zeros(tshape, jnp.dtype(cfg.dtype))
-            tail_k = jnp.concatenate(
-                [s.tail_k for s in batch] + [ztail] * pad_b, axis=1)
-            tail_v = jnp.concatenate(
-                [s.tail_v for s in batch] + [ztail] * pad_b, axis=1)
-        tab = np.zeros((b_pad, table.shape[1]), np.int32)
-        tab[:len(batch)] = table
-        tab_key = (tab.shape, tab.tobytes())
-        if self._tab_cache[0] != tab_key:
-            self._tab_cache = (tab_key, jnp.asarray(tab))
-        j0 = time.perf_counter()
-        logits, ntk, ntv = paged_decode_batch_step_jit(
-            self.params, jnp.asarray(toks, jnp.int32),
-            jnp.asarray(metas, jnp.int32), pool_k, pool_v,
-            self._tab_cache[1], tail_k, tail_v, cfg,
-        )
-        # One fused greedy argmax + host transfer for the whole batch
-        # (row b is bitwise jnp.argmax(logits[b]) — same bits, same
-        # first-max tie-break); doubles as the step's device sync.
-        best = np.asarray(jnp.argmax(logits, axis=-1))
-        kept = np.asarray(logits) if self.keep_logits else None
-        if obs_journal.enabled():
-            obs_journal.phase(
-                "jit_step", time.perf_counter() - j0,
-                priority=max(s.priority for s in batch),
+        with span("serve_batch_step") as step:
+            with span("step.residency"):
+                self._ensure_resident_batch(batch)
+            with span("step.pool"):
+                pool_k, pool_v, table, tables = self._batch_pool(batch)
+            with span("step.args"):
+                b_pad = _pow2(len(batch))
+                toks, metas, prefills = [], [], []
+                for sess, trow in zip(batch, tables):
+                    if sess.prompt_consumed < len(sess.prompt):
+                        tok = sess.prompt[sess.prompt_consumed]
+                        sess.prompt_consumed += 1
+                        prefill = True
+                        self.stats.note_tokens(1, phase="prefill")
+                    else:
+                        tok = sess.out[-1] if sess.out else sess.prompt[-1]
+                        prefill = False
+                    toks.append(tok)
+                    prefills.append(prefill)
+                    metas.append([sess.pos, sess.tail_len, len(trow) * P, 0])
+                pad_b = b_pad - len(batch)
+                toks += [0] * pad_b
+                metas += [[0, 0, 0, 0]] * pad_b
+                st = self._tail_stack
+                if (st is not None and st[0] == batch
+                        and all(s.tail_k is None for s in batch)):
+                    # Same seated sessions as last step and nobody
+                    # shipped: the previous step's stacked tails ARE this
+                    # step's inputs — no per-session slices, no concat
+                    # (they get donated).
+                    tail_k, tail_v = st[1], st[2]
+                    self._tail_stack = None
+                else:
+                    self._flush_tail_stack()
+                    tshape = (cfg.n_layers, 1, cfg.n_kv_heads, P,
+                              cfg.head_dim)
+                    ztail = jnp.zeros(tshape, jnp.dtype(cfg.dtype))
+                    tail_k = jnp.concatenate(
+                        [s.tail_k for s in batch] + [ztail] * pad_b, axis=1)
+                    tail_v = jnp.concatenate(
+                        [s.tail_v for s in batch] + [ztail] * pad_b, axis=1)
+                tab = np.zeros((b_pad, table.shape[1]), np.int32)
+                tab[:len(batch)] = table
+                tab_key = (tab.shape, tab.tobytes())
+            with span("step.dispatch"):
+                if self._tab_cache[0] != tab_key:
+                    self._tab_cache = (tab_key, jnp.asarray(tab))
+                logits, ntk, ntv = paged_decode_batch_step_jit(
+                    self.params, jnp.asarray(toks, jnp.int32),
+                    jnp.asarray(metas, jnp.int32), pool_k, pool_v,
+                    self._tab_cache[1], tail_k, tail_v, cfg,
+                )
+            with span("step.sync"):
+                # One fused greedy argmax + host transfer for the whole
+                # batch (row b is bitwise jnp.argmax(logits[b]) — same
+                # bits, same first-max tie-break); doubles as the step's
+                # device sync: the host waits on the device here.
+                best = np.asarray(jnp.argmax(logits, axis=-1))
+                kept = np.asarray(logits) if self.keep_logits else None
+            dt = time.perf_counter() - step.t0
+            self.stats.note_batch_step(len(batch), dt)
+            obs_journal.record(
+                "batch_step", size=len(batch), pad=b_pad,
+                pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
             )
-        dt = time.perf_counter() - t0
-        self.stats.note_batch_step(len(batch), dt)
-        obs_journal.record(
-            "batch_step", size=len(batch), pad=b_pad,
-            pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
-        )
-        self._tail_stack = (list(batch), ntk, ntv)
-        for b, (sess, tok, prefill) in enumerate(
-                zip(batch, toks, prefills)):
-            # Tails stay stacked (see _tail_stack); a session only pays
-            # for its two slices when something reads them this tick.
-            sess.tail_k = None
-            sess.tail_v = None
-            sess.pos += 1
-            sess.tail_len += 1
-            sess.page_toks.append(int(tok))
-            emit = (not prefill
-                    or sess.prompt_consumed == len(sess.prompt))
-            if emit:
-                sess.out.append(int(best[b]))
-                if kept is not None:
-                    sess.logits.append(kept[b])
-                self._note_first_token(sess)
-                if not prefill:
-                    self.stats.note_tokens(1)
-            if sess.tail_len == P:
-                sess.tail_k = ntk[:, b:b + 1]
-                sess.tail_v = ntv[:, b:b + 1]
-                self._ship(sess)
-                self._match_more(sess)
-            elif (self.share_partials and prefill
-                  and sess.prompt_consumed == len(sess.prompt)):
-                sess.tail_k = ntk[:, b:b + 1]
-                sess.tail_v = ntv[:, b:b + 1]
-                self._publish_partial(sess)
-            if len(sess.out) > sess.req.max_new_tokens:
-                raise AssertionError("overran max_new_tokens")
-            if len(sess.out) == sess.req.max_new_tokens:
-                sess.done = True
+            self._tail_stack = (list(batch), ntk, ntv)
+            with span("step.scatter"):
+                for b, (sess, tok, prefill) in enumerate(
+                        zip(batch, toks, prefills)):
+                    # Tails stay stacked (see _tail_stack); a session only
+                    # pays for its two slices when something reads them
+                    # this tick.
+                    sess.tail_k = None
+                    sess.tail_v = None
+                    sess.pos += 1
+                    sess.tail_len += 1
+                    sess.page_toks.append(int(tok))
+                    emit = (not prefill
+                            or sess.prompt_consumed == len(sess.prompt))
+                    if emit:
+                        sess.out.append(int(best[b]))
+                        if kept is not None:
+                            sess.logits.append(kept[b])
+                        self._note_first_token(sess)
+                        if not prefill:
+                            self.stats.note_tokens(1)
+                    if sess.tail_len == P:
+                        with span("step.ship"):
+                            sess.tail_k = ntk[:, b:b + 1]
+                            sess.tail_v = ntv[:, b:b + 1]
+                            self._ship(sess)
+                            self._match_more(sess)
+                    elif (self.share_partials and prefill
+                          and sess.prompt_consumed == len(sess.prompt)):
+                        with span("step.publish"):
+                            sess.tail_k = ntk[:, b:b + 1]
+                            sess.tail_v = ntv[:, b:b + 1]
+                            self._publish_partial(sess)
+                    if len(sess.out) > sess.req.max_new_tokens:
+                        raise AssertionError("overran max_new_tokens")
+                    if len(sess.out) == sess.req.max_new_tokens:
+                        sess.done = True
 
     def _flush_tail_stack(self) -> None:
         """Materialize the deferred per-session tail slices out of the
@@ -1097,6 +1149,7 @@ class ServingEngine:
                 stall_s=round(sess.stall_s, 6),
                 prefix_tokens_reused=sess.prefix_tokens_reused,
                 out_logits=list(sess.logits) if self.keep_logits else None,
+                ttft_parts=sess.ttft_parts,
             ))
 
     # -- introspection ----------------------------------------------------

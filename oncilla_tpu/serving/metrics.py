@@ -87,6 +87,12 @@ class ServingStats:
         self.ttft_count = 0
         self.ttft_s_sum = 0.0
         self.ttft_s_hist = {b: 0 for b in self.TTFT_BUCKETS}
+        # Where that time went, summed over the same sessions: waiting
+        # for max_active, whole prompt pages (adoption, one chunk a
+        # tick), the sub-page remainder riding the fused step; and the
+        # ticks of that remainder a runnable session spent unseated.
+        self.ttft_parts = {"queue_s": 0.0, "chunk_s": 0.0, "tail_s": 0.0,
+                           "unseated_ticks": 0}
 
     BATCH_BUCKETS = (1, 2, 4, 8, 16, 32)
     STEP_BUCKETS = (0.001, 0.005, 0.025, 0.1, 0.5, 2.5)
@@ -174,14 +180,22 @@ class ServingStats:
                 if seconds <= b:
                     self.step_s_hist[b] += 1
 
-    def note_ttft(self, seconds: float) -> None:
-        """One session's time-to-first-token."""
+    def note_ttft(self, seconds: float, queue_s: float = 0.0,
+                  chunk_s: float = 0.0, tail_s: float = 0.0,
+                  unseated_ticks: int = 0) -> None:
+        """One session's time-to-first-token and its parts
+        (``queue_s + chunk_s + tail_s == seconds`` from the engine)."""
         with self._mu:
             self.ttft_count += 1
             self.ttft_s_sum += seconds
             for b in self.TTFT_BUCKETS:
                 if seconds <= b:
                     self.ttft_s_hist[b] += 1
+            parts = self.ttft_parts
+            parts["queue_s"] += queue_s
+            parts["chunk_s"] += chunk_s
+            parts["tail_s"] += tail_s
+            parts["unseated_ticks"] += unseated_ticks
 
     def note_preempt(self, reason: str) -> None:
         """A session lost (or yielded) its batch slot this tick:
@@ -262,6 +276,8 @@ class ServingStats:
                     "count": self.ttft_count,
                     "sum_s": round(self.ttft_s_sum, 6),
                     "hist": dict(self.ttft_s_hist),
+                    "parts": {k: round(v, 6)
+                              for k, v in self.ttft_parts.items()},
                 },
             }
 

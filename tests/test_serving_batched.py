@@ -252,3 +252,197 @@ def test_batched_recompilations_bounded_by_shape_buckets(tiny_model):
     outs2, _, _ = workload()
     assert kern._cache_size() - before == first
     assert outs2 == outs
+
+
+# -- 5. tick anatomy: one span tree, every name under one parent ------------
+
+# docs/OBSERVABILITY.md, "Serving tick anatomy": span -> its one parent.
+TICK_SPANS = {
+    "tick": None,
+    "tick.admit": "tick",
+    "tick.match": "tick",
+    "serve_prefill_chunk": "tick",
+    "prefill.residency": "serve_prefill_chunk",
+    "prefill.dispatch": "serve_prefill_chunk",
+    "prefill.sync": "serve_prefill_chunk",
+    "prefill.ship": "serve_prefill_chunk",
+    "tick.select": "tick",
+    "serve_batch_step": "tick",
+    "step.residency": "serve_batch_step",
+    "step.pool": "serve_batch_step",
+    "step.args": "serve_batch_step",
+    "step.dispatch": "serve_batch_step",
+    "step.sync": "serve_batch_step",
+    "step.scatter": "serve_batch_step",
+    "step.ship": "step.scatter",
+    "step.publish": "step.scatter",
+    "tick.finish": "tick",
+}
+
+
+def span_totals():
+    from oncilla_tpu.utils.debug import GLOBAL_TRACER
+
+    return {op: (v["count"], v["hist"]["sum_s"])
+            for op, v in GLOBAL_TRACER.snapshot().items()}
+
+
+def anatomy_prompts(cfg, seed):
+    """A shared three-page prefix, then remainders that reach every span:
+    sub-page tails (``step.publish``), a whole-page prompt (its first
+    token comes from ``prefill.sync``) and enough new tokens to cross a
+    page boundary while decoding (``step.ship``)."""
+    rng = np.random.default_rng(seed)
+    base = rng.integers(1, cfg.vocab, 3 * P).tolist()
+    return [base + rng.integers(1, cfg.vocab, n).tolist()
+            for n in (5, 11, 2 * P, 3, P, 13)]
+
+
+def test_tick_span_tree_covers_the_tick_with_one_parent_a_name(tiny_model):
+    from oncilla_tpu.obs import journal
+
+    cfg, _ = tiny_model
+    kw = dict(share=True, hot=48, warm=8, new_tokens=10, max_active=3,
+              max_batch=2, batched=True)
+    run_prompts(tiny_model, anatomy_prompts(cfg, 50), **kw)  # compiles
+    was = journal.enabled()
+    journal.set_enabled(True)
+    try:
+        # Coverage is a statement about wall time: a descheduled worker can
+        # stretch one gap between two spans, so the best of three counts.
+        for attempt in range(3):
+            journal.clear()
+            before = span_totals()
+            run_prompts(tiny_model, anatomy_prompts(cfg, 51 + attempt), **kw)
+            after = span_totals()
+            events = journal.events()
+            count = {op: after[op][0] - before.get(op, (0, 0.0))[0]
+                     for op in TICK_SPANS if op in after}
+            total = {op: after[op][1] - before.get(op, (0, 0.0))[1]
+                     for op in TICK_SPANS if op in after}
+            shares = {
+                parent: sum(total[c] for c, p in TICK_SPANS.items()
+                            if p == parent) / total[parent]
+                for parent in ("tick", "serve_batch_step",
+                               "serve_prefill_chunk", "step.scatter")}
+            if min(shares.values()) >= 0.9:
+                break
+    finally:
+        journal.set_enabled(was)
+        journal.clear()
+    assert set(count) == set(TICK_SPANS)
+    assert all(n > 0 for n in count.values()), count
+    # children never exceed their parent, and leave under a tenth unnamed
+    assert all(0.9 <= s <= 1.0 for s in shares.values()), shares
+    # the journal's parentage: every span of a name hangs under the one
+    # parent the table gives, the tick itself under nothing
+    spans = [e for e in events if e["ev"] == "span"]
+    op_of = {e["span_id"]: e["op"] for e in spans}
+    seen = {}
+    for e in spans:
+        if e["op"] in TICK_SPANS:
+            parent = op_of.get(e["parent_span_id"])
+            assert parent == TICK_SPANS[e["op"]], (e["op"], parent)
+        seen.setdefault(e["op"], set()).add(op_of.get(e["parent_span_id"]))
+    assert set(TICK_SPANS) <= set(seen)
+    # the memory plane's spans nest under them and stay leaves
+    assert not set(TICK_SPANS.values()) & {"alloc", "put", "get", "copy"}
+    assert all(parents <= set(TICK_SPANS) for op, parents in seen.items()
+               if op in ("alloc", "put", "get", "copy"))
+    # so the journal's critical-path attribution gets the tick's split from
+    # the spans alone: no phase events are left to carve it
+    from oncilla_tpu.obs import critpath
+
+    assert not [e for e in events if e["ev"] == "phase"]
+    trees = [t for t in critpath.assemble(events) if t["root_op"] == "tick"]
+    assert len(trees) == count["tick"]
+    named = set().union(*(t["attribution"] for t in trees))
+    assert {"step.residency", "step.pool", "step.dispatch", "step.sync",
+            "prefill.residency", "prefill.dispatch"} <= named
+    assert min(t["attributed_frac"] for t in trees) > 0.99
+    # one ttft event per request, found by its tenant
+    ttft = [e for e in events if e["ev"] == "ttft"]
+    assert sorted(e["tenant"] for e in ttft) == [f"t{i}" for i in range(6)]
+    for e in ttft:
+        assert e["queue_s"] + e["chunk_s"] + e["tail_s"] == pytest.approx(
+            e["ttft_s"], abs=2e-6)
+
+
+# -- 6. request anatomy: TTFT split where it is spent -----------------------
+
+
+def run_with_ttft_log(tiny_model, prompts, **kw):
+    """Run to completion; returns (results by tenant, the (seconds, parts)
+    pairs ``note_ttft`` was handed, the stats snapshot)."""
+    from oncilla_tpu.serving.engine import Request
+
+    ctx, store, eng = build_engine(tiny_model, **kw)
+    noted = []
+    note = eng.stats.note_ttft
+
+    def logging_note(seconds, **parts):
+        noted.append((seconds, parts))
+        note(seconds, **parts)
+
+    eng.stats.note_ttft = logging_note
+    try:
+        for i, p in enumerate(prompts):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=6))
+        results = {r.tenant: r for r in eng.run()}
+        snap = eng.stats.snapshot()
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    return results, noted, snap
+
+
+def test_ttft_parts_sum_to_the_recorded_ttft(tiny_model):
+    cfg, _ = tiny_model
+    # Six requests on three seats of max_active: the last three queue.
+    results, noted, snap = run_with_ttft_log(
+        tiny_model, anatomy_prompts(cfg, 60), share=True, hot=48, warm=8,
+        max_active=3, max_batch=3)
+    assert len(noted) == len(results) == 6
+    for seconds, parts in noted:
+        assert set(parts) == {"queue_s", "chunk_s", "tail_s",
+                              "unseated_ticks"}
+        assert parts["queue_s"] + parts["chunk_s"] + parts["tail_s"] == (
+            pytest.approx(seconds, abs=1e-6))
+        assert min(parts["queue_s"], parts["chunk_s"], parts["tail_s"]) >= 0
+    assert [r.ttft_parts for r in results.values()] == [p for _, p in noted]
+    queue = [results[f"t{i}"].ttft_parts["queue_s"] for i in range(6)]
+    # admitted at once: a tick's start away from submit; the rest waited
+    # for a finished session's place, which takes whole ticks
+    assert 0 < max(queue[:3]) < min(queue[3:])
+    # t2 is whole pages (its last chunk emits the token): no tail at all;
+    # t0's 5-token remainder rides the fused step
+    assert results["t2"].ttft_parts["tail_s"] == 0
+    assert results["t2"].ttft_parts["chunk_s"] > 0
+    assert results["t0"].ttft_parts["tail_s"] > 0
+    ttft = snap["ttft"]
+    assert ttft["count"] == 6
+    parts = ttft["parts"]
+    assert parts["queue_s"] + parts["chunk_s"] + parts["tail_s"] == (
+        pytest.approx(ttft["sum_s"], abs=1e-5))
+    assert parts["unseated_ticks"] == sum(
+        p["unseated_ticks"] for _, p in noted)
+
+
+def test_unseated_ticks_count_runnable_sessions_without_a_seat(tiny_model):
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(61)
+    # Four sub-page prompts, all runnable from the first tick, two seats.
+    prompts = [rng.integers(1, cfg.vocab, 6).tolist() for _ in range(4)]
+    results, noted, snap = run_with_ttft_log(
+        tiny_model, prompts, share=False, max_active=4, max_batch=2)
+    ticks = [results[f"t{i}"].ttft_parts["unseated_ticks"] for i in range(4)]
+    # the first two keep their seats; the others wait for them, a tick
+    # each time: 6 prompt tokens + 5 more outputs of the seated pair
+    assert ticks[:2] == [0, 0] and min(ticks[2:]) > 0
+    assert snap["ttft"]["parts"]["unseated_ticks"] == sum(ticks)
+    assert snap["preempts"]["slot"] >= sum(ticks)
+    # nothing queued and no whole page: all of it is the tail
+    for _, parts in noted:
+        assert parts["chunk_s"] < 1e-3 < parts["tail_s"]
