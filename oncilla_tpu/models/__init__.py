@@ -34,6 +34,7 @@ _EXPORTS = {
     "paged_decode_step": "kv_paging",
     "paged_decode_step_jit": "kv_paging",
     "paged_decode_batch_step_jit": "kv_paging",
+    "paged_pool_write_row_jit": "kv_paging",
     "paged_decode_page_jit": "kv_paging",
     "paged_generate_page_jit": "kv_paging",
 }

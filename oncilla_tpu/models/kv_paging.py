@@ -430,6 +430,26 @@ def paged_decode_batch_step_jit(
     return logits[:, 0], tail_k, tail_v
 
 
+@partial(jax.jit, donate_argnums=(0, 1))
+def paged_pool_write_row_jit(
+    pool_k: jax.Array,     # (N, L, KV, P, Hd) resident page pool (donated)
+    pool_v: jax.Array,
+    page_k: jax.Array,     # (L, 1, KV, P, Hd) one page's decode arrays
+    page_v: jax.Array,
+    slot: jax.Array,       # () int32 pool row to overwrite
+):
+    """Write one page into row ``slot`` of the fused step's page pool, in
+    place: both pools are donated and ``slot`` is traced, so one compiled
+    program per pool shape serves every row. A row is a byte copy of
+    the page (``dynamic_update_slice``), so a pool kept up to date this
+    way is bitwise the pool stacked from the same pages."""
+    at = (slot, 0, 0, 0, 0)
+    return (
+        jax.lax.dynamic_update_slice(pool_k, page_k[None, :, 0], at),
+        jax.lax.dynamic_update_slice(pool_v, page_v[None, :, 0], at),
+    )
+
+
 @partial(
     jax.jit,
     static_argnames=("cfg", "layer_params_fn", "mlp_of"),

@@ -50,6 +50,7 @@ from oncilla_tpu.models import (
     paged_decode_batch_step_jit,
     paged_decode_page_jit,
     paged_decode_step_jit,
+    paged_pool_write_row_jit,
 )
 from oncilla_tpu.obs import journal as obs_journal
 from oncilla_tpu.qos.policy import PRIO_NORMAL
@@ -329,10 +330,16 @@ class ServingEngine:
             max_batch = int(os.environ.get("OCM_SERVING_MAX_BATCH", "8"))
         self.max_batch = max(1, int(max_batch))
         self.keep_logits = bool(keep_logits)
-        # Per-tick page-pool stacking cache: (key, pool_k, pool_v) —
-        # rebuilt only when the resident page set changes (page
-        # boundaries), not every token.
-        self._pool_cache: tuple = (None, None, None)
+        # The fused step's page pool, kept on the device between ticks
+        # (see _batch_pool): K and V rows (capacity, L, KV, P, Hd), the
+        # row of every (page_id, version) it holds, least recently
+        # seated first, and the rows nothing was written to yet.
+        self._pool_k = None
+        self._pool_v = None
+        self._pool_slots: dict[tuple, int] = {}
+        self._pool_free: list[int] = []
+        # Pool capacities whose row-write program has already run.
+        self._pool_write_ready: set[int] = set()
         # Steady-state fused-step fast path: the kernel's stacked tail
         # outputs feed the next step directly while batch membership is
         # unchanged; per-session slices materialize lazily (ship /
@@ -416,6 +423,7 @@ class ServingEngine:
         for sess in self.active:
             self._finish(sess, abandon=True)
         self.active = []
+        self._pool_k = self._pool_v = None
         # Persist the prefix trie into the frozen tier (if one backs
         # the store) BEFORE the prefetcher drains: the pages are still
         # readable, and the next incarnation's __init__ restores them.
@@ -913,15 +921,21 @@ class ServingEngine:
                 self.prefetcher.recycle(got[2])
 
     def _batch_pool(self, batch: list[_Session]):
-        """The tick's page pool + per-session block table: every distinct
-        resident page stacked ONCE as a (N_pad, L, KV, P, Hd) pool (a
-        shared prefix page is one row however many sessions reference
-        it), table[b] listing session b's rows. N/MP snap to power-of-
-        two buckets; the stacked pool is cached across ticks on the
-        (page_id, version) set, so steady-state decode restacks nothing
-        until a page boundary."""
-        index: dict[tuple, int] = {}
-        rows = []
+        """The tick's page pool + per-session block table. The pool is
+        device state that outlives the tick: every distinct resident
+        page of the batch holds one row of a (capacity, L, KV, P, Hd)
+        pool (a shared prefix page is one row however many sessions
+        reference it) under its (page_id, version), and table[b] lists
+        session b's rows. A page that has a row keeps it, with no device
+        work; a page without one takes a free row, or the row of the
+        page seated longest ago that this batch does not reference, and
+        ONE :func:`paged_pool_write_row_jit` dispatch writes its K and V
+        there in place. So a session that loses its seat for a tick
+        finds its rows again. ``capacity`` and MP snap to power-of-two
+        buckets of this batch's rows; when the capacity bucket changes,
+        the batch's pages are written into a fresh pool
+        (:meth:`_new_pool`)."""
+        rows: dict[tuple, tuple] = {}
         tables = []
         for sess in batch:
             trow = []
@@ -929,32 +943,61 @@ class ServingEngine:
                 if e.pending_fill:
                     continue
                 key = (e.page.page_id, e.version)
-                if key not in index:
-                    index[key] = len(rows)
-                    rows.append(e.arrays)
-                trow.append(index[key])
+                rows.setdefault(key, e.arrays)
+                trow.append(key)
             tables.append(trow)
         max_pages = max((len(t) for t in tables), default=0)
         mp = _pow2(max_pages) if max_pages else 0
-        n_pad = _pow2(len(rows)) if rows else 1
-        cache_key = (tuple(index), n_pad)
-        if self._pool_cache[0] == cache_key:
-            pool_k, pool_v = self._pool_cache[1], self._pool_cache[2]
-        else:
-            cfg = self.cfg
-            zrow = jnp.zeros(
-                (cfg.n_layers, cfg.n_kv_heads, self.page_tokens,
-                 cfg.head_dim), jnp.dtype(cfg.dtype))
-            krows = [a[0][:, 0] for a in rows]
-            vrows = [a[1][:, 0] for a in rows]
-            pad = n_pad - len(rows)
-            pool_k = jnp.stack(krows + [zrow] * pad)
-            pool_v = jnp.stack(vrows + [zrow] * pad)
-            self._pool_cache = (cache_key, pool_k, pool_v)
+        capacity = _pow2(len(rows)) if rows else 1
+        rebuilt = self._pool_k is None or self._pool_k.shape[0] != capacity
+        if rebuilt:
+            self._new_pool(capacity)
+        slots = self._pool_slots
+        fresh = []
+        for key in rows:
+            if key in slots:
+                slots[key] = slots.pop(key)  # seated now: reclaimed last
+            else:
+                fresh.append(key)
+        for key in fresh:
+            # Every seated key is behind the unseated ones by now, and the
+            # batch has at most `capacity` keys: with no row free, the
+            # oldest key is one this batch does not reference.
+            slot = (self._pool_free.pop() if self._pool_free
+                    else slots.pop(next(iter(slots))))
+            self._pool_k, self._pool_v = paged_pool_write_row_jit(
+                self._pool_k, self._pool_v, *rows[key], np.int32(slot))
+            slots[key] = slot
+        self.stats.note_pool(reused=len(rows) - len(fresh),
+                             written=len(fresh), rebuilt=rebuilt)
         table = np.zeros((len(batch), mp), np.int32)
         for b, trow in enumerate(tables):
-            table[b, :len(trow)] = trow
-        return pool_k, pool_v, table, tables
+            table[b, :len(trow)] = [slots[key] for key in trow]
+        return self._pool_k, self._pool_v, table, tables
+
+    def _new_pool(self, capacity: int) -> None:
+        """Replace the pool by zeros of ``capacity`` rows, all free. The
+        rare path: the first pool, and a crossing of a power-of-two row
+        count. No program may be built when a page is first written in
+        place, at whatever tick that is: the row write runs once here on
+        scratch zeros of this capacity and of the next one up."""
+        dt = jnp.dtype(self.cfg.dtype)
+        page = self.page_shape[1:]      # (L, 1, KV, P, Hd)
+
+        def zeros(n: int) -> tuple:
+            shape = (n, page[0]) + page[2:]
+            return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+        # The old pool's memory goes before the new pool is made.
+        self._pool_k = self._pool_v = None
+        for n in (capacity, 2 * capacity):
+            if n not in self._pool_write_ready:
+                zpage = jnp.zeros(page, dt)
+                paged_pool_write_row_jit(*zeros(n), zpage, zpage, np.int32(0))
+                self._pool_write_ready.add(n)
+        self._pool_k, self._pool_v = zeros(capacity)
+        self._pool_slots = {}
+        self._pool_free = list(range(capacity - 1, -1, -1))
 
     def _batch_step(self, batch: list[_Session]) -> None:
         """ONE fused jit dispatch advancing every seated session by one

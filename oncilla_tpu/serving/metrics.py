@@ -80,6 +80,13 @@ class ServingStats:
         self.step_s_sum = 0.0
         self.step_s_hist = {b: 0 for b in self.STEP_BUCKETS}
         self.prefill_chunks = 0
+        # The fused step's page pool, kept on the device between ticks:
+        # rows a tick found in their slot, rows written (in place, or
+        # into a new pool), and pools made (the first; the row bucket
+        # changed).
+        self.pool_rows_reused = 0
+        self.pool_rows_written = 0
+        self.pool_rebuilds = 0
         self.preempts: dict[str, int] = {}
         # Time-to-first-token per session (submit -> first emitted
         # token), same cumulative prom-style bucket shape as the step
@@ -204,6 +211,16 @@ class ServingStats:
         with self._mu:
             self.preempts[reason] = self.preempts.get(reason, 0) + 1
 
+    def note_pool(self, reused: int = 0, written: int = 0,
+                  rebuilt: bool = False) -> None:
+        """One tick's page pool: ``reused`` rows kept their slot,
+        ``written`` rows went to the device; ``rebuilt`` when they went
+        into a new pool (the first, or the row bucket changed)."""
+        with self._mu:
+            self.pool_rows_reused += reused
+            self.pool_rows_written += written
+            self.pool_rebuilds += int(rebuilt)
+
     def note_prefill_chunk(self) -> None:
         with self._mu:
             self.prefill_chunks += 1
@@ -270,6 +287,11 @@ class ServingStats:
                     "step_s": round(self.step_s_sum, 6),
                     "step_s_hist": dict(self.step_s_hist),
                     "prefill_chunks": self.prefill_chunks,
+                },
+                "pool": {
+                    "rows_reused": self.pool_rows_reused,
+                    "rows_written": self.pool_rows_written,
+                    "rebuilds": self.pool_rebuilds,
                 },
                 "preempts": dict(self.preempts),
                 "ttft": {

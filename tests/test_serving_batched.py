@@ -446,3 +446,195 @@ def test_unseated_ticks_count_runnable_sessions_without_a_seat(tiny_model):
     # nothing queued and no whole page: all of it is the tail
     for _, parts in noted:
         assert parts["chunk_s"] < 1e-3 < parts["tail_s"]
+
+
+# -- 7. the page pool kept on the device between ticks ----------------------
+
+
+def watch_pool(eng):
+    """Wrap ``eng._batch_pool``: after every call, check each pool row the
+    table names against the entries it stands for, and log (keys the pool
+    held before, capacity before, this batch's distinct keys, capacity
+    after). A row is bitwise the ``arrays`` of the entry first seated under
+    its key. Entries that share a key hold the same page and almost always
+    the same bits; the exception is a CoW adopter whose finished page was
+    folded onto the leader's at publish (its last token came from the fused
+    step, the leader's from the page program): such an entry agrees with
+    the row to rounding, and their keys are returned beside the log."""
+    calls = []
+    inexact = set()
+    seated: dict = {}
+    inner = eng._batch_pool
+
+    def watched(batch):
+        held = set(eng._pool_slots)
+        cap0 = None if eng._pool_k is None else eng._pool_k.shape[0]
+        pool_k, pool_v, table, tables = inner(batch)
+        pk, pv = np.asarray(pool_k), np.asarray(pool_v)
+        if cap0 != pk.shape[0]:
+            held = set()
+        keys = []
+        for b, sess in enumerate(batch):
+            live = [e for e in sess.entries if not e.pending_fill]
+            assert len(live) == len(tables[b])
+            for i, e in enumerate(live):
+                key = (e.page.page_id, e.version)
+                mine = [np.asarray(a)[:, 0] for a in e.arrays]
+                if key not in keys:
+                    keys.append(key)
+                    if key not in held:
+                        seated[key] = mine
+                row = table[b, i]
+                assert eng._pool_slots[key] == row
+                assert np.array_equal(pk[row], seated[key][0])
+                assert np.array_equal(pv[row], seated[key][1])
+                if not (np.array_equal(pk[row], mine[0])
+                        and np.array_equal(pv[row], mine[1])):
+                    inexact.add(key)
+                    assert e.extent is not None
+                    np.testing.assert_allclose(pk[row], mine[0], atol=1e-5)
+                    np.testing.assert_allclose(pv[row], mine[1], atol=1e-5)
+        assert len(set(eng._pool_slots.values())) == len(eng._pool_slots)
+        assert not set(eng._pool_slots.values()) & set(eng._pool_free)
+        calls.append((held, cap0, keys, pk.shape[0]))
+        return pool_k, pool_v, table, tables
+
+    eng._batch_pool = watched
+    return calls, inexact
+
+
+def expected_pool_counters(calls):
+    """The counters the logged calls must have produced: a key is written
+    when the pool did not hold it (a new pool holds nothing), reused when
+    it did; a pool is built when the row bucket changes."""
+    want = {"rows_reused": 0, "rows_written": 0, "rebuilds": 0}
+    for held, cap0, keys, cap1 in calls:
+        want["rebuilds"] += cap0 != cap1
+        new = [k for k in keys if k not in held]
+        want["rows_written"] += len(new)
+        want["rows_reused"] += len(keys) - len(new)
+    return want
+
+
+def test_pool_rows_are_the_entries_arrays_after_every_tick(tiny_model):
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    # The churned, prefix-sharing, CoW workload of the paired gate above.
+    prompts = seeded_prompts(cfg, 11, n=5, shared=20, suffix=4)
+    ctx, store, eng = build_engine(tiny_model, share=True, hot=2, warm=2,
+                                  max_active=4)
+    calls, inexact = watch_pool(eng)
+    try:
+        for i, p in enumerate(prompts):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=8))
+        eng.run()
+        meta = eng.metrics_meta()
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    assert len(calls) == meta["batch"]["steps"] > 0
+    assert meta["moves"]["promote"] > 0 and meta["prefix"]["cow"] >= 1
+    # rows written = keys new to the pool; a pool is built only when the
+    # bucket of distinct rows changes
+    assert meta["pool"] == expected_pool_counters(calls)
+    assert meta["pool"]["rows_reused"] > meta["pool"]["rows_written"] > 0
+    buckets = [cap for _, _, _, cap in calls]
+    assert meta["pool"]["rebuilds"] == 1 + sum(
+        a != b for a, b in zip(buckets, buckets[1:]))
+    # every entry agreed with its row bit for bit, but for the one folded
+    # CoW page (t1's last prompt page)
+    assert len(inexact) <= 1
+
+
+def test_steady_decode_writes_one_row_a_shipped_page(tiny_model):
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(71)
+    # Five whole pages and three tokens of prompt, then three pages' length
+    # of decode: 5, 6, 7, 8 distinct rows, one bucket (8) all the way.
+    prompt = rng.integers(1, cfg.vocab, 5 * P + 3).tolist()
+    outs, meta, _ = run_prompts(tiny_model, [prompt], new_tokens=3 * P,
+                                share=False, hot=16, warm=4, batched=True)
+    assert len(outs["t0"]) == 3 * P
+    steps = meta["batch"]["steps"]
+    assert steps == 3 + 3 * P - 1
+    pool = meta["pool"]
+    # one pool, built at the first step with the five prompt pages; then
+    # one row for each of the three pages the decode shipped
+    assert pool["rebuilds"] == 1
+    assert pool["rows_written"] == 5 + 3
+    # rows referenced over the run, less the eight written
+    assert pool["rows_reused"] == (5 * P + 6 * P + 7 * P + 8 * 2) - 8
+
+
+def test_promoted_page_gets_its_row_rewritten(tiny_model):
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(73)
+    prompt = rng.integers(1, cfg.vocab, 2 * P + 2).tolist()
+    ctx, store, eng = build_engine(tiny_model, share=False, hot=4, warm=4,
+                                  batched=True)
+    calls, _ = watch_pool(eng)
+    try:
+        eng.submit(Request(tenant="t0", tokens=list(prompt),
+                           max_new_tokens=5))
+        while not calls:
+            eng._tick()
+        eng._tick()
+        sess = eng.active[0]
+        entry = sess.entries[0]
+        old_key = (entry.page.page_id, entry.version)
+        before = eng.stats.snapshot()["pool"]
+        assert old_key in eng._pool_slots
+        # Demoted under the seated session: the next step faults it back
+        # with a new version, i.e. a new key for the same page.
+        store.demote(entry.page, Tier.WARM)
+        eng._tick()
+        new_key = (entry.page.page_id, entry.version)
+        after = eng.stats.snapshot()["pool"]
+        assert new_key != old_key and new_key[0] == old_key[0]
+        assert new_key in eng._pool_slots
+        # two rows, two slots: the stale key gave its row up
+        assert old_key not in eng._pool_slots
+        assert after["rows_written"] == before["rows_written"] + 1
+        assert after["rows_reused"] == before["rows_reused"] + 1
+        assert after["rebuilds"] == before["rebuilds"] == 1
+        outs = {r.tenant: list(r.out_tokens) for r in eng.run()}
+        assert eng.stats.snapshot()["moves"]["promote"] >= 1
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    outs_il, _, _ = run_prompts(tiny_model, [prompt], new_tokens=5,
+                                share=False, hot=4, warm=4, batched=False)
+    assert outs == outs_il
+
+
+def test_pool_write_program_compiles_once_a_capacity(tiny_model):
+    from oncilla_tpu.models import paged_decode_batch_step_jit as step
+    from oncilla_tpu.models import paged_pool_write_row_jit as write
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(79)
+    prompts = [rng.integers(1, cfg.vocab, ln).tolist()
+               for ln in (5, 9, 17, 25, 30)]
+
+    def workload():
+        return run_prompts(tiny_model, prompts, new_tokens=12,
+                           share=False, hot=8, warm=8, max_active=5,
+                           batched=True)
+
+    outs, meta, _ = workload()
+    built = (step._cache_size(), write._cache_size())
+    # one write program a pool capacity: those the run reached (1..16 rows)
+    # and the next one up, never one a row or a tick
+    assert meta["pool"]["rebuilds"] >= 2
+    assert 0 < built[1] <= 6 < meta["pool"]["rows_written"]
+    # a second identical workload builds nothing more, for the fused step
+    # and for the row write
+    outs2, meta2, _ = workload()
+    assert (step._cache_size(), write._cache_size()) == built
+    assert outs2 == outs and meta2["pool"] == meta["pool"]
