@@ -7,6 +7,10 @@ of the prefill program. Shapes follow token counts and capacities, not
 widths, so the tiny model reaches the shapes the real one will. It counts;
 it measures nothing.
 
+A tool of the ``dense_gqa`` family: it builds the tiny ``LlamaConfig`` itself
+and wraps the dense paged path's two programs. A family with other programs
+brings a census of its own beside its warmer.
+
     JAX_PLATFORMS=cpu python3 benchmark/census.py --traffic agent-shared --seeds 1,2 --requests 300
 """
 

@@ -3,10 +3,14 @@ traffic for ``--seconds``, check the outputs, print the contract's line.
 
 Nothing here names a cell, a model or a metric. A cell is an entry of
 ``BENCHMARK.json``'s ``workloads``; its configuration is the file the
-``configs`` entry names, its traffic mix is ``traffic/<traffic>.json``,
-which names its generator (``generators/<name>.py``) and its warmer
-(``warmers/<name>.py``); each per-layer metric is ``layer_metrics/<name>.py``.
-See ``README.md`` beside this file.
+``configs`` entry names, which names its model family
+(``families/<family>.py``: the adapter to the program's model module, which
+in turn names the family's plain reference, ``references/<name>.py``, and
+its bytes model, ``bytes_models/<name>.py``); its traffic mix is
+``traffic/<traffic>.json``, which names its generator
+(``generators/<name>.py``) and its warmer (``warmers/<name>.py``); each
+per-layer metric is ``layer_metrics/<name>.py``. See ``README.md`` beside
+this file.
 
 From the program this takes the system under test (``ServingEngine`` over
 ``TieredPageStore``, ``PrefixCache``, ``Ocm`` and an in-process COLD
@@ -34,8 +38,11 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
-# Requests the reference is compared on, after the window.
+# Requests the reference is compared on, after the window: further ones run
+# through the same engine with its logits kept, and a sample of those the
+# window itself finished (the longest among them), by their tokens.
 CHECK_REQUESTS = 2
+SERVED_REQUESTS = 8
 # Profiled slice of a traced run's window: long enough for some tens of
 # ticks, short enough for the trace to stay small.
 TRACE_AFTER_S = 2.0
@@ -112,21 +119,40 @@ def load_cell(workload: str) -> Cell:
     )
 
 
-def model_config(conf: dict):
-    """The configuration file's published keys as the program's config."""
-    from oncilla_tpu.models import LlamaConfig
+@dataclasses.dataclass
+class Family:
+    """What depends on the architecture, found through the configuration's
+    ``family`` key."""
 
-    return LlamaConfig(
-        vocab=conf["vocab_size"], dim=conf["hidden_size"],
-        n_layers=conf["num_hidden_layers"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        ffn_hidden=conf["intermediate_size"],
-        max_seq=conf["max_position_embeddings"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        dtype=conf["torch_dtype"], window=conf.get("sliding_window"),
-    )
+    name: str
+    adapter: object      # families/<family>.py
+    reference: object    # references/<adapter.REFERENCE>.py
+    bytes_model: object  # bytes_models/<adapter.BYTES_MODEL>.py
+
+
+def load_family(conf: dict) -> Family:
+    """The configuration's model family. A configuration that names none, or
+    one with no adapter, is refused: no family is the default."""
+    name = conf.get("family")
+    if not name:
+        raise Refused(f"configuration {conf.get('name')!r} names no model "
+                      "family (its file needs a \"family\" key)")
+    adapter = load_plugin("families", name)
+    return Family(
+        name=name, adapter=adapter,
+        reference=load_plugin("references", adapter.REFERENCE),
+        bytes_model=load_plugin("bytes_models", adapter.BYTES_MODEL))
+
+
+def seeded_weights(family: Family, conf: dict, seed: int):
+    """The program's config and the weights of a run: made on the device in
+    one jitted call from the seed's key, in the type they are served in."""
+    import jax
+
+    cfg = family.adapter.program_config(conf)
+    params = jax.jit(lambda key: family.adapter.init_params(key, cfg))(
+        jax.random.key(seed))
+    return cfg, jax.block_until_ready(params)
 
 
 class CompileMeter:
@@ -445,11 +471,12 @@ def span_totals() -> dict:
 # -- correctness --------------------------------------------------------------------
 
 
-def check_reference(loop: Loop, cfg, params, conf: dict, problems: list) -> dict:
+def check_reference(loop: Loop, reference, params, conf: dict,
+                    problems: list) -> dict:
     """After the window: the schedule's next requests through the same
-    engine with its logits kept, against the plain float32 forward,
-    teacher-forced on the engine's own tokens."""
-    reference = load_plugin("", "reference")
+    engine with its logits kept, against the family's plain float32 forward,
+    teacher-forced on the engine's own tokens. Returns the numbers;
+    ``compared_numbers`` holds them to the configuration's ``tolerance``."""
     engine = loop.engine
     engine.keep_logits = True
     loop.clients, loop.accepting = 0, False
@@ -463,7 +490,6 @@ def check_reference(loop: Loop, cfg, params, conf: dict, problems: list) -> dict
         loop.tick()
     engine.keep_logits = False
     recs = sorted(loop.done[first:], key=lambda r: r.index)
-    tol = conf["tolerance"]
     dmax, agree, total, absmax = 0.0, 0, 0, 0.0
     for req, rec in zip(reqs, recs):
         out = rec.result.out_tokens
@@ -477,7 +503,7 @@ def check_reference(loop: Loop, cfg, params, conf: dict, problems: list) -> dict
                             "arg-max of its own logits")
         seq = np.asarray([req["tokens"] + out[:-1]], np.int32)
         rows = np.arange(len(req["tokens"]) - 1, seq.shape[1])
-        ref = reference.logits_at(params, seq, rows, reference.dims_of(conf))[0]
+        ref = reference.logits_at(params, seq, rows, conf)[0]
         if not (np.isfinite(ref).all() and np.isfinite(eng).all()):
             problems.append(f"check request {rec.index}: non-finite logits")
             continue
@@ -486,41 +512,95 @@ def check_reference(loop: Loop, cfg, params, conf: dict, problems: list) -> dict
         agree += int((ref.argmax(-1) == np.asarray(out)).sum())
         total += len(out)
     share = agree / total if total else 0.0
-    if total == 0:
-        problems.append("no token was compared with the reference")
-    if dmax > tol["max_abs_dlogit"]:
-        problems.append(f"max |dlogit| {dmax:.4g} over the tolerance "
-                        f"{tol['max_abs_dlogit']}")
-    if share < tol["argmax_share"]:
-        problems.append(f"arg-max share {share:.3f} under "
-                        f"{tol['argmax_share']}")
     return {"max_abs_dlogit": dmax, "argmax_share": share,
             "tokens_compared": total, "ref_logit_absmax": absmax}
 
 
-def check_guarantees(cell: Cell, stats_win: dict, stats_end: dict, win: dict,
-                     problems: list) -> None:
-    """The configuration's guarantees and the traffic file's ``expect``."""
+def check_served(loop: Loop, t0: float, t1: float, seed: int, reference,
+                 params, conf: dict, problems: list) -> dict:
+    """What the timed path itself produced: a sample, drawn from the seed, of
+    the requests that finished inside the window, the longest of them in it.
+    The reference runs once over each prompt with its served tokens; the
+    number compared is the widest gap by which a served token's reference
+    logit lies below the reference's best at its position (greedy decoding:
+    0 wherever the program and the reference agree on the arg-max), held
+    under ``tolerance_served`` by ``compared_numbers``."""
+    done = [r for r in loop.done if r.out and t0 <= r.stamps[-1] < t1]
+    if not done:
+        return {"served_logit_gap": 0.0, "served_tokens": 0,
+                "served_requests": 0}
+    longest = max(done, key=lambda r: (r.prompt_len + len(r.out), -r.index))
+    rest = [r for r in done if r is not longest]
+    order = np.random.default_rng(seed).permutation(len(rest))
+    sample = [longest] + [rest[i] for i in order[:SERVED_REQUESTS - 1]]
+    gap, tokens = 0.0, 0
+    for rec in sample:
+        prompt = loop.nth(rec.index)["tokens"]
+        seq = np.asarray([prompt + rec.out[:-1]], np.int32)
+        rows = np.arange(len(prompt) - 1, seq.shape[1])
+        ref = reference.logits_at(params, seq, rows, conf)[0]
+        if not np.isfinite(ref).all():
+            problems.append(f"request {rec.index}: non-finite reference "
+                            "logits over its served tokens")
+            continue
+        served = ref[np.arange(len(rec.out)), np.asarray(rec.out)]
+        gap = max(gap, float((ref.max(-1) - served).max()))
+        tokens += len(rec.out)
+    return {"served_logit_gap": gap, "served_tokens": tokens,
+            "served_requests": len(sample)}
+
+
+def check_guarantees(stats_end: dict, problems: list) -> None:
+    """The configuration's guarantees that are no number of the window."""
     if stats_end["degraded"].get("capacity_free", 0):
         problems.append("a tier with free capacity refused a page: "
                         f"{stats_end['degraded']}")
     if stats_end.get("cold_sim"):
         problems.append("COLD was simulated, not on the daemons")
+
+
+def compared_numbers(cell: Cell, check: dict, stats_win: dict, win: dict,
+                     problems: list) -> dict:
+    """Every number ``correct`` holds to a limit, beside that limit: the
+    reference's (``tolerance``, ``tolerance_served``), the window's requests
+    and the mix's ``expect``. ``want`` says on which side of the limit the
+    value holds; one that does not is checked into ``problems``. This is
+    what a record of a run that was not correct has to show."""
+    def entry(value, want, limit):
+        return {"value": value, "want": want, "limit": limit}
+
+    tol = cell.config["tolerance"]
+    out = {
+        "max_abs_dlogit": entry(check["max_abs_dlogit"], "<=",
+                                tol["max_abs_dlogit"]),
+        "argmax_share": entry(check["argmax_share"], ">=",
+                              tol["argmax_share"]),
+        "tokens_compared": entry(check["tokens_compared"], ">=", 1),
+        "served_logit_gap": entry(
+            check["served_logit_gap"], "<=",
+            cell.config["tolerance_served"]["max_logit_gap"]),
+        "served_tokens": entry(check["served_tokens"], ">=", 1),
+        "requests_failed": entry(win["failed"], "<=", 0),
+        "requests_attempted": entry(win["attempted"], ">=", 1),
+    }
     exp = cell.traffic.get("expect", {})
-    hops = stats_win["moves"]["hops"]
     for hop in exp.get("window_hops_nonzero", []):
-        if hops.get(hop, 0) <= 0:
-            problems.append(f"no page moved {hop} inside the window: {hops}")
+        out[f"hops.{hop}"] = entry(stats_win["moves"]["hops"].get(hop, 0),
+                                   ">=", 1)
     if "window_promotes_max" in exp:
-        if stats_win["moves"]["promote"] > exp["window_promotes_max"]:
-            problems.append(
-                f"{stats_win['moves']['promote']} pages were promoted inside "
-                f"the window (at most {exp['window_promotes_max']} expected)")
+        out["window_promotes"] = entry(stats_win["moves"]["promote"], "<=",
+                                       exp["window_promotes_max"])
     if "prefix_reused_share_min" in exp and win["prompt_tokens"]:
-        share = win["reused_tokens"] / win["prompt_tokens"]
-        if share < exp["prefix_reused_share_min"]:
-            problems.append(f"prefix reuse {share:.3f} under "
-                            f"{exp['prefix_reused_share_min']}")
+        out["prefix_reused_share"] = entry(
+            win["reused_tokens"] / win["prompt_tokens"], ">=",
+            exp["prefix_reused_share_min"])
+    for name, c in out.items():
+        holds = (c["value"] <= c["limit"] if c["want"] == "<="
+                 else c["value"] >= c["limit"])
+        if not holds:
+            problems.append(f"{name} {c['value']:.6g}, has to be "
+                            f"{c['want']} {c['limit']}")
+    return out
 
 
 # -- one run ------------------------------------------------------------------------
@@ -536,11 +616,17 @@ def peak_of(device_kind: str) -> dict:
     return peaks[device_kind]
 
 
-def device_line(devices, trace: dict | None, window_s: float | None) -> dict:
+def memory_peak(devices) -> int:
+    """Peak bytes in use on the fullest chip, so far in this process."""
     peak = 0
     for d in devices:
         stats = d.memory_stats() or {}
         peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return peak
+
+
+def device_line(devices, peak: int, trace: dict | None,
+                window_s: float | None) -> dict:
     out = {"platform": devices[0].platform, "kind": devices[0].device_kind,
            "count": len(devices), "memory_peak_bytes": peak}
     if trace is not None:
@@ -557,6 +643,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     ``tpu`` for every measurement; the CPU tests pass ``cpu`` with a tiny
     configuration."""
     cell = load_cell(workload)
+    family = load_family(cell.config)
     import jax
 
     if jax.default_backend() != platform:
@@ -568,7 +655,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                       f"{len(devices)}")
     devices = devices[:cell.chips]
 
-    from oncilla_tpu.models import llama
     from oncilla_tpu.utils.platform import enable_compile_cache
 
     cache_dir = enable_compile_cache()
@@ -586,14 +672,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         mark[0] = now
 
     phases["import"] = round(mark[0] - t_start, 2)
-    cfg = model_config(cell.config)
-    params = jax.jit(lambda key: llama.init_params(key, cfg))(
-        jax.random.key(seed))
-    jax.block_until_ready(params)
+    cfg, params = seeded_weights(family, cell.config, seed)
+    vocab = int(cell.config["vocab_size"])
     phase("weights")
 
     gen = load_plugin("generators", cell.traffic["generator"])
-    sched = gen.schedule(seed, cell.traffic["params"], cfg.vocab)
+    sched = gen.schedule(seed, cell.traffic["params"], vocab)
     problems: list[str] = []
     trace_dir = os.path.join(ROOT, ".bench_trace", workload)
     reduced = None
@@ -652,20 +736,22 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         loop.drain()
 
         recs = loop.done + list(loop.inflight.values())
-        win = window_numbers(recs, t0, t1, cfg.vocab)
+        win = window_numbers(recs, t0, t1, vocab)
         win["reused_tokens"] = sum(
             r.result.prefix_tokens_reused for r in loop.done
             if t0 <= r.submit_t < t1)
         win["compiles"] = window_compiles
         win["ticks"] = window_ticks
         stats_win = delta(stats1, stats0)
-        check = check_reference(loop, cfg, params, cell.config, problems)
-        check_guarantees(cell, stats_win, engine.metrics_meta(), win, problems)
-        if win["attempted"] == 0:
-            problems.append("no request was submitted inside the window")
-        if win["failed"]:
-            problems.append(f"{win['failed']} of {win['attempted']} requests "
-                            "did not return all their tokens")
+        # The program's peak: a process's peak never falls again, so it is
+        # read before the reference puts its float32 layers on the chip.
+        peak = memory_peak(devices)
+        check = check_served(loop, t0, t1, seed, family.reference, params,
+                             cell.config, problems)
+        check.update(check_reference(loop, family.reference, params,
+                                     cell.config, problems))
+        check_guarantees(engine.metrics_meta(), problems)
+        compared = compared_numbers(cell, check, stats_win, win, problems)
     # serving_stack's exit checked the drain guarantees into `problems`.
 
     values = end_to_end_values(win, setup_s)
@@ -686,8 +772,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "traffic": cell.traffic, "page_bytes": page_bytes,
             "window": win, "traced_s": traced_s,
             "peak": peak_of(devices[0].device_kind),
-            "lib": {"trace_reduce": trace_reduce,
-                    "bytes_model": load_plugin("", "bytes_model")},
+            "lib": {"trace_reduce": trace_reduce, "family": family.adapter,
+                    "bytes_model": family.bytes_model,
+                    "bytes_shared": load_plugin("", "bytes_model")},
         }
         spans_win = delta(spans1, spans0)
         metrics = {}
@@ -710,14 +797,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         f"total compile {meter.lap()}")
     for p in problems:
         log(f"{workload}: NOT CORRECT: {p}")
+    for name, c in compared.items():
+        log(f"{workload}: compared {name} {c['value']:.6g} "
+            f"(holds {c['want']} {c['limit']})")
     line = {
         "correct": not problems,
         "attempted": win["attempted"],
         "failed": win["failed"],
         "metrics": metrics,
-        "device": device_line(devices, reduced, traced_s),
+        "device": device_line(devices, peak, reduced, traced_s),
     }
     if reduced is not None:
         line["breakdown"] = {"device_ops": reduced["top_ops"][:10],
                              "idle_gaps": reduced["idle_gaps"][:10]}
+    line["compared"] = compared     # last: a record keeps a line's end
     return line

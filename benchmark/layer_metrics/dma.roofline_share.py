@@ -13,6 +13,6 @@ def read(stats, spans, trace, cell):
     rec = trace["programs"].get(PROGRAM)
     if not rec or not rec["count"] or not rec["total_s"]:
         return None
-    moved = rec["count"] * cell["lib"]["bytes_model"].page_copy_bytes(
+    moved = rec["count"] * cell["lib"]["bytes_shared"].page_copy_bytes(
         cell["page_bytes"])
     return 100.0 * moved / rec["total_s"] / cell["peak"]["hbm_bytes_per_s"]

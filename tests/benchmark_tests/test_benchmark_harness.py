@@ -169,7 +169,10 @@ def test_every_entry_of_benchmark_json_resolves(harness, bench_json):
         for key in ("page_tokens", "max_active", "max_batch", "prefix_cache",
                     "hot_pages", "warm_pages", "cold_pages", "cold_daemons"):
             assert key in cell.traffic["engine"]
-        assert harness.model_config(cell.config).head_dim == 128
+        family = harness.load_family(cell.config)
+        assert family.adapter.program_config(cell.config).head_dim == 128
+        assert callable(family.reference.logits_at)
+        assert callable(family.bytes_model.decode_step_bytes)
         assert {m["name"] for m in cell.end_to_end} == e2e
         assert cell.per_layer
     for c in b["configs"]:
@@ -177,6 +180,7 @@ def test_every_entry_of_benchmark_json_resolves(harness, bench_json):
             conf = json.load(f)
         assert conf["source"] == c["source"] and conf["reduced"] == c["reduced"]
         assert set(conf["tolerance"]) >= {"max_abs_dlogit", "argmax_share", "why"}
+        assert set(conf["tolerance_served"]) >= {"max_logit_gap", "why"}
         assert conf["guarantees"]["cold_replicas"] == 2
     for m in b["per_layer"]:
         assert callable(harness.load_plugin("layer_metrics", m["name"]).read)
@@ -214,10 +218,84 @@ def test_an_unknown_name_is_refused_not_defaulted(harness):
     assert harness.peak_of("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
 
 
+@pytest.mark.parametrize("family", [None, "", "no_such_family"])
+def test_a_configuration_without_a_known_family_is_refused(harness, family):
+    """No family is the default: a configuration that names none, or one
+    with no adapter file, cannot run."""
+    conf = dict(TINY_CONFIG)
+    if family is None:
+        del conf["family"]
+    else:
+        conf["family"] = family
+    with pytest.raises(harness.Refused):
+        harness.load_family(conf)
+
+
+def config_files():
+    return sorted(os.listdir(os.path.join(BENCH, "configs")))
+
+
+@pytest.mark.parametrize("name", config_files())
+def test_every_configuration_names_a_family_that_loads(harness, name):
+    with open(os.path.join(BENCH, "configs", name)) as f:
+        conf = json.load(f)
+    family = harness.load_family(conf)
+    assert family.name == conf["family"]
+    assert callable(family.adapter.program_config)
+    assert callable(family.adapter.init_params)
+    assert family.adapter.DECODE_STEP_PROGRAM
+    assert family.reference.__file__ == os.path.join(
+        BENCH, "references", f"{family.adapter.REFERENCE}.py")
+    assert family.bytes_model.__file__ == os.path.join(
+        BENCH, "bytes_models", f"{family.adapter.BYTES_MODEL}.py")
+    cfg = family.adapter.program_config(conf)
+    # the generator's and the window's vocabulary is the file's
+    assert cfg.vocab == conf["vocab_size"]
+    assert family.bytes_model.decode_step_bytes(conf, 100) > 0
+
+
+def plain_files(kind):
+    return sorted(f for f in os.listdir(os.path.join(BENCH, kind))
+                  if f.endswith(".py"))
+
+
+@pytest.mark.parametrize("name", plain_files("references"))
+def test_a_reference_imports_nothing_of_the_program(name):
+    with open(os.path.join(BENCH, "references", name)) as f:
+        src = f.read()
+    code = [ln.split("#")[0] for ln in src.splitlines()]
+    assert not any(re.search(r"\b(import|from)\b.*oncilla_tpu", ln)
+                   for ln in code), name
+    assert "importlib" not in src and "__import__" not in src
+
+
+def test_only_the_adapters_import_a_model_module():
+    """``harness.py`` names no model; under ``benchmark/`` a model module of
+    the program is imported by the families' adapters (and by the warmers
+    and ``census.py``, which drive the program's own entry points)."""
+    pat = re.compile(r"oncilla_tpu\.models|LlamaConfig|\bllama\b")
+    with open(os.path.join(BENCH, "harness.py")) as f:
+        assert not pat.search(f.read())
+    hits = set()
+    for dirpath, dirs, files in os.walk(BENCH):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(dirpath, fn)) as f:
+                    code = "\n".join(ln for ln in f.read().splitlines()
+                                     if re.search(r"\b(import|from)\b", ln))
+                if re.search(r"oncilla_tpu\.models", code):
+                    hits.add(os.path.relpath(os.path.join(dirpath, fn), BENCH))
+    assert hits <= {"families/dense_gqa.py", "warmers/paged_dense.py",
+                    "census.py"}, hits
+    assert "families/dense_gqa.py" in hits
+
+
 # -- a temporary copy with a dummy of everything, and the tick loop on it -----------
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "tests", "hidden_size": 64, "num_hidden_layers": 2,
+    "name": "tiny", "source": "tests", "family": "tiny_family",
+    "hidden_size": 64, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "intermediate_size": 128,
     "vocab_size": 256, "max_position_embeddings": 128, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-5, "torch_dtype": "float32", "sliding_window": None,
@@ -225,6 +303,7 @@ TINY_CONFIG = {
     "tolerance": {"max_abs_dlogit": 1e-3, "argmax_share": 1.0,
                   "why": "float32 on the CPU: the paged path and the plain "
                          "forward differ by summation order alone"},
+    "tolerance_served": {"max_logit_gap": 1e-3, "why": "as tolerance"},
 }
 TINY_TRAFFIC = {
     "generator": "dummy_gen",
@@ -244,14 +323,37 @@ TINY_TRAFFIC = {
 }
 
 
+def snapshot(root) -> dict:
+    """Every file under ``root``/benchmark with its bytes."""
+    out = {}
+    for dirpath, _, files in os.walk(root / "benchmark"):
+        for fn in files:
+            path = os.path.join(dirpath, fn)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = f.read()
+    return out
+
+
 @pytest.fixture(scope="module")
 def tiny_copy(tmp_path_factory):
-    """A copy of the benchmark with a cell, a configuration, a mix, a
-    generator and a per-layer metric ADDED as files and entries of their
-    own: nothing that was there is edited."""
+    """A copy of the benchmark with a cell, a configuration, a model family
+    (its adapter, its reference and its bytes model, under names of their
+    own), a mix, a generator and a per-layer metric ADDED as files and
+    entries of their own: nothing that was there is edited."""
     tmp = tmp_path_factory.mktemp("bench_copy")
     shutil.copytree(BENCH, tmp / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__", "fixtures"))
+    before = snapshot(tmp)
+    adapter = (tmp / "benchmark/families/dense_gqa.py").read_text()
+    (tmp / "benchmark/families/tiny_family.py").write_text(
+        adapter.replace('REFERENCE = "dense_gqa"', 'REFERENCE = "tiny_ref"')
+        .replace('BYTES_MODEL = "dense_gqa"', 'BYTES_MODEL = "tiny_bytes"')
+        .replace('"paged_decode_batch_step"', '"tiny_step_program"'))
+    shutil.copy(tmp / "benchmark/references/dense_gqa.py",
+                tmp / "benchmark/references/tiny_ref.py")
+    (tmp / "benchmark/bytes_models/tiny_bytes.py").write_text(
+        "def decode_step_bytes(conf, context_tokens):\n"
+        "    return 1000 * conf['hidden_size'] + context_tokens\n")
     (tmp / "benchmark/configs/tiny.json").write_text(json.dumps(TINY_CONFIG))
     (tmp / "benchmark/traffic/tiny-mix.json").write_text(json.dumps(TINY_TRAFFIC))
     shutil.copy(tmp / "benchmark/generators/lognormal_turns.py",
@@ -270,6 +372,9 @@ def tiny_copy(tmp_path_factory):
                            "layer": "dummy", "moves": "out_tok_s",
                            "workloads": ["tiny.tiny-mix"]})
     (tmp / "BENCHMARK.json").write_text(json.dumps(b))
+    after = snapshot(tmp)
+    assert {k: after[k] for k in before} == before   # nothing edited
+    assert len(after) == len(before) + 7
     return tmp
 
 
@@ -289,12 +394,49 @@ def test_added_files_and_entries_resolve_in_a_copy(tiny_harness, tiny_copy):
     assert gen.__file__.startswith(str(tiny_copy))
     read = tiny_harness.load_plugin("layer_metrics", "dummy.ticks").read
     assert read({}, {}, None, {"window": {"ticks": 7}}) == 7
+    # the added family is found through the configuration's key, and brings
+    # its own reference, bytes model and program name
+    family = tiny_harness.load_family(cell.config)
+    assert family.name == "tiny_family"
+    for mod, rel in ((family.adapter, "families/tiny_family.py"),
+                     (family.reference, "references/tiny_ref.py"),
+                     (family.bytes_model, "bytes_models/tiny_bytes.py")):
+        assert mod.__file__ == str(tiny_copy / "benchmark" / rel)
+    assert family.adapter.DECODE_STEP_PROGRAM == "tiny_step_program"
+    assert family.bytes_model.decode_step_bytes(cell.config, 7) == 64007
     # and the cells that were there still resolve, untouched
-    assert tiny_harness.load_cell("internlm2-1.8b.agent-shared").chips == 1
+    old = tiny_harness.load_cell("internlm2-1.8b.agent-shared")
+    assert old.chips == 1
+    assert tiny_harness.load_family(old.config).name == "dense_gqa"
 
 
-@pytest.fixture(scope="module")
-def tiny_line(tiny_harness):
+def test_step_readers_take_program_and_bytes_from_the_family(tiny_harness):
+    """``step.device_ms`` and ``step.roofline_share`` hold no program name
+    and no bytes of their own: the added family's reach them."""
+    cell = tiny_harness.load_cell("tiny.tiny-mix")
+    family = tiny_harness.load_family(cell.config)
+    tr = tiny_harness.load_plugin("", "trace_reduce")
+    trace = {"programs": {"tiny_step_program": {"count": 4, "total_s": 0.02},
+                          "paged_decode_batch_step": {"count": 9, "total_s": 9.0}}}
+    info = {"config": cell.config, "window": {"context_tokens": 2000},
+            "peak": {"hbm_bytes_per_s": 1e9},
+            "lib": {"trace_reduce": tr, "family": family.adapter,
+                    "bytes_model": family.bytes_model}}
+    stats = {"batch": {"steps": 10}}
+    read = lambda name: tiny_harness.load_plugin("layer_metrics", name).read(  # noqa: E731
+        stats, {}, trace, info)
+    assert read("step.device_ms") == pytest.approx(5.0)
+    # least time (64000 + 200) B / 1e9 B/s over 5 ms
+    assert read("step.roofline_share") == pytest.approx(
+        100 * 64200e-9 / 5e-3)
+    trace["programs"].pop("tiny_step_program")
+    assert read("step.device_ms") is None
+    assert read("step.roofline_share") is None
+
+
+def run_tiny(tiny_harness, seed: int) -> dict:
+    """One whole run of the tiny cell, all of ``run_cell`` but its look for a
+    chip (``platform="cpu"``)."""
     import time
 
     import jax
@@ -307,11 +449,16 @@ def tiny_line(tiny_harness):
     saved = {k: getattr(jax.config, k) for k in keys}
     try:
         return tiny_harness.run_cell(
-            "tiny.tiny-mix", seed=2**31 + 5, seconds=2.0, trace=False,
+            "tiny.tiny-mix", seed=seed, seconds=2.0, trace=False,
             t_start=time.perf_counter(), platform="cpu")
     finally:
         for k, v in saved.items():
             jax.config.update(k, v)
+
+
+@pytest.fixture(scope="module")
+def tiny_line(tiny_harness):
+    return run_tiny(tiny_harness, 2**31 + 5)
 
 
 def test_tick_loop_returns_every_token_and_the_contracts_line(tiny_line):
@@ -324,6 +471,79 @@ def test_tick_loop_returns_every_token_and_the_contracts_line(tiny_line):
         assert m["value"] > 0 and UNIT.match(m["unit"])
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert line["device"]["platform"] == "cpu"   # and so never a measurement
+
+
+def test_the_line_ends_with_each_number_compared_beside_its_limit(tiny_line):
+    assert list(tiny_line)[-1] == "compared"
+    c = tiny_line["compared"]
+    assert set(c) == {"max_abs_dlogit", "argmax_share", "tokens_compared",
+                      "served_logit_gap", "served_tokens", "requests_failed", "requests_attempted",
+                      "hops.hbm>host", "hops.host>remote"}
+    tol = TINY_CONFIG["tolerance"]
+    assert c["max_abs_dlogit"]["limit"] == tol["max_abs_dlogit"]
+    assert 0 < c["max_abs_dlogit"]["value"] <= tol["max_abs_dlogit"]
+    assert c["argmax_share"] == {"value": 1.0, "want": ">=", "limit": 1.0}
+    assert c["tokens_compared"]["value"] >= 8
+    # the window's own requests: some tens of served tokens at this size
+    assert c["served_logit_gap"]["limit"] == 1e-3
+    assert 0 <= c["served_logit_gap"]["value"] <= 1e-3
+    assert c["served_tokens"]["value"] >= 8
+    assert c["requests_failed"] == {"value": 0, "want": "<=", "limit": 0}
+    assert c["hops.hbm>host"]["value"] >= 1
+    for entry in c.values():
+        ok = (entry["value"] <= entry["limit"] if entry["want"] == "<="
+              else entry["value"] >= entry["limit"])
+        assert ok, c
+
+
+def test_a_token_altered_where_it_is_made_is_not_correct(tiny_harness,
+                                                        monkeypatch):
+    """The timed path broken underneath a whole run: the fused step hands
+    back its logits shifted by one id, so every decoded token is another
+    one. ``correct`` comes out false, through the numbers compared."""
+    import jax.numpy as jnp
+
+    import oncilla_tpu.serving.engine as engine_mod
+
+    fused = engine_mod.paged_decode_batch_step_jit
+
+    def shifted(*args):
+        logits, tail_k, tail_v = fused(*args)
+        return jnp.roll(logits, 1, axis=-1), tail_k, tail_v
+
+    monkeypatch.setattr(engine_mod, "paged_decode_batch_step_jit", shifted)
+    line = run_tiny(tiny_harness, 2**31 + 6)
+    assert line["correct"] is False
+    c = line["compared"]
+    assert c["max_abs_dlogit"]["value"] > 100 * c["max_abs_dlogit"]["limit"]
+    assert c["argmax_share"]["value"] < c["argmax_share"]["limit"]
+    # and what the window itself served is far from the reference's best
+    assert c["served_logit_gap"]["value"] > 100 * c["served_logit_gap"]["limit"]
+    # every request still came back whole: the fault is in what they say
+    assert line["failed"] == 0 and line["attempted"] > 0
+
+
+@pytest.mark.parametrize("seed", [1, 2, 2**31 + 7])
+def test_the_control_one_precision_lower_is_not_correct(seed):
+    """``control.py`` at a size a test can hold: the reference over weights
+    rounded to bfloat16, put in the place of the float32 tiny model, fails
+    the tiny configuration's tolerance (by the widest logit gap; the
+    arg-maxes of 40 positions survive bfloat16)."""
+    control = load(os.path.join(BENCH, "control.py"), "bench_control_under_test")
+    conf = dict(TINY_CONFIG, family="dense_gqa")
+    out = control.control(conf, seed, tokens=40)
+    assert out["lower"] == "bfloat16" and out["tokens_compared"] == 40
+    assert out["correct"] is False
+    d = out["max_abs_dlogit"]
+    assert d["limit"] == 1e-3 and d["value"] > 3 * d["limit"]
+    assert out["served_logit_gap"]["limit"] == 1e-3
+    # and the same comparison lets the unrounded weights through
+    control.LOWER["float32"] = ("float32", 8, 23)
+    try:
+        same = control.control(conf, seed, tokens=40)
+    finally:
+        control.LOWER["float32"] = ("bfloat16", 8, 7)
+    assert same["correct"] is True and same["max_abs_dlogit"]["value"] == 0
 
 
 def test_readers_on_counters_of_the_tiny_run(harness):

@@ -18,9 +18,10 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-def load(name: str):
+def load(name: str, path: str | None = None):
     spec = importlib.util.spec_from_file_location(
-        f"bench_{name}_under_test", os.path.join(BENCH, f"{name}.py"))
+        "bench_%s_under_test" % name.replace("/", "_"),
+        path or os.path.join(BENCH, f"{name}.py"))
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
@@ -82,7 +83,9 @@ def test_reduction_of_the_trace_recorded_on_the_chip():
 
 
 def test_bytes_model_against_hand_arithmetic():
-    bm = load("bytes_model")
+    bm = load("bytes_models/dense_gqa")
+    shared = load("bytes_model")
+    assert bm.DTYPE_BYTES is not None and bm.DTYPE_BYTES == shared.DTYPE_BYTES
     i = config("internlm2-1.8b")
     m = config("mistral-7b-v0.1-d16")
     # one 16-token page in the float32 store
@@ -106,7 +109,7 @@ def test_bytes_model_against_hand_arithmetic():
     assert bm.kv_bytes_per_token(i) == 2 * 24 * 8 * 128 * 2 == 98304
     assert bm.kv_bytes_per_token(m) == 65536
     assert bm.decode_step_bytes(i, 2400) == want_i + 2400 * 98304
-    assert bm.page_copy_bytes(3 << 20) == 6 << 20
+    assert shared.page_copy_bytes(3 << 20) == 6 << 20
     # a step at 819 GB/s cannot take less than ~4.4 ms / ~9 ms
     assert bm.decode_step_bytes(i, 2400) / 819e9 == pytest.approx(4.44e-3, rel=0.01)
     assert bm.decode_step_bytes(m, 2400) / 819e9 == pytest.approx(9.03e-3, rel=0.01)
@@ -123,7 +126,7 @@ def test_reference_agrees_with_the_programs_unpaged_forward(window):
 
     from oncilla_tpu.models import LlamaConfig, llama
 
-    ref = load("reference")
+    ref = load("references/dense_gqa")
     cfg = dataclasses.replace(LlamaConfig.tiny(), window=window)
     params = llama.init_params(jax.random.key(3), cfg)
     tokens = np.random.default_rng(0).integers(1, cfg.vocab, (2, 23)).astype(np.int32)
@@ -131,12 +134,38 @@ def test_reference_agrees_with_the_programs_unpaged_forward(window):
             "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.norm_eps,
             "sliding_window": window}
     rows = np.arange(4, 23)
-    got = ref.logits_at(params, tokens, rows, ref.dims_of(conf))
+    got = ref.logits_at(params, tokens, rows, conf)
     want = np.asarray(llama.forward(params, tokens, cfg))[:, rows]
     assert got.shape == want.shape == (2, 19, cfg.vocab)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
     if window is not None:
         # the window is really applied: the full-attention answer differs
         full = ref.logits_at(params, tokens, rows,
-                             ref.dims_of(dict(conf, sliding_window=None)))
+                             dict(conf, sliding_window=None))
         assert np.abs(full - got).max() > 1e-3
+
+
+@pytest.mark.parametrize("window", [None, 5])
+def test_the_moved_reference_is_bit_equal_to_the_one_it_was(window):
+    """The yardstick did not move: ``references/dense_gqa.py`` against a
+    copy of ``benchmark/reference.py`` as PR 27 left it, kept beside this
+    test, on the tiny configuration."""
+    import jax
+
+    from oncilla_tpu.models import LlamaConfig, llama
+
+    new = load("references/dense_gqa")
+    old = load("reference_before_pr28",
+               os.path.join(os.path.dirname(__file__), "reference_before_pr28.py"))
+    cfg = LlamaConfig.tiny()
+    params = llama.init_params(jax.random.key(11), cfg)
+    tokens = np.random.default_rng(1).integers(1, cfg.vocab, (2, 37)).astype(np.int32)
+    conf = {"num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
+            "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.norm_eps,
+            "sliding_window": window}
+    rows = np.arange(3, 37)
+    got = new.logits_at(params, tokens, rows, conf)
+    want = old.logits_at(params, tokens, rows, old.dims_of(conf))
+    assert got.dtype == want.dtype == np.float32
+    assert np.array_equal(got, want)
+    assert new.LAYER_LEAVES == old.LAYER_LEAVES
