@@ -37,6 +37,9 @@ _EXPORTS = {
     "paged_pool_write_row_jit": "kv_paging",
     "paged_decode_page_jit": "kv_paging",
     "paged_generate_page_jit": "kv_paging",
+    "PagedFamily": "kv_paging",
+    # latent_moe: latent attention, routed experts, hyper-connections.
+    "LatentMoeConfig": "latent_moe",
 }
 
 __all__ = sorted(_EXPORTS)

@@ -26,6 +26,41 @@ from oncilla_tpu.models.llama import LlamaConfig
 from oncilla_tpu.utils.debug import GLOBAL_TRACER
 
 
+@dataclass(frozen=True)
+class PagedFamily:
+    """What :class:`~oncilla_tpu.serving.engine.ServingEngine` takes from a
+    model family: the leaves a page and a tail are made of, and the
+    programs it dispatches over them. A page is ``n_leaves`` arrays of ONE
+    shape ``(L, 1, KV, P, Hd)`` with ``(KV, Hd) = leaf_dims(cfg)`` (the
+    dense family: K and V; the latent family: one latent of KV 1); a tail
+    is the same with the batch on axis 1, a pool row the same without it.
+    The tier store sees a page's bytes and nothing of this.
+
+    ``step(params, tokens, meta, n_real, pool, table, tails, cfg)`` is the
+    fused batch step and ``page(params, tokens_page, meta, ctx, tails,
+    cfg)`` the page program; ``pool``, ``ctx`` and ``tails`` are tuples of
+    leaves, both return ``(logits, new tails, aux)``. ``aux`` is None, or
+    for a family with experts a () int32 on the device: the distinct
+    (layer, expert) pairs that received a real token. ``write_row(pool,
+    page, slot)`` writes one page into a pool row in place.
+    ``assignments_per_token(cfg)``, for a family whose programs hand an
+    ``aux`` back, is how many (layer, expert) pairs one token is routed
+    to. ``token``, the batch-of-1 step of the interleaved loop, is
+    optional."""
+
+    n_leaves: int
+    leaf_dims: object
+    step: object
+    page: object
+    write_row: object
+    assignments_per_token: object = None
+    token: object = None
+
+    def leaf_shape(self, cfg, page_tokens: int, batch: int = 1) -> tuple:
+        kv, hd = self.leaf_dims(cfg)
+        return (cfg.n_layers, batch, kv, page_tokens, hd)
+
+
 @dataclass
 class PagedKVCache:
     """KV pages for one decode session.

@@ -42,6 +42,7 @@ import os
 import time
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,6 +53,7 @@ from oncilla_tpu.models import (
     paged_decode_step_jit,
     paged_pool_write_row_jit,
 )
+from oncilla_tpu.models.kv_paging import PagedFamily
 from oncilla_tpu.obs import journal as obs_journal
 from oncilla_tpu.qos.policy import PRIO_NORMAL
 from oncilla_tpu.serving import metrics as serving_metrics
@@ -65,6 +67,51 @@ def _pow2(n: int) -> int:
     """Smallest power of two >= n (shape-bucket policy: padded batch /
     page-table dims snap up so XLA compiles O(log) programs)."""
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
+
+
+# The dense grouped-query family (``models/llama.py``): a page is a K and a
+# V of (L, 1, KV, P, Hd). Its programs are looked up in this module's
+# namespace at every call, with the arguments they have always had: tools
+# that count or perturb them (``benchmark/census.py``, the tests) replace
+# the names here.
+
+
+def _dense_leaf_dims(cfg) -> tuple:
+    return (cfg.n_kv_heads, cfg.head_dim)
+
+
+def _dense_step(params, tokens, meta, n_real, pool, table, tails, cfg):
+    logits, tail_k, tail_v = paged_decode_batch_step_jit(
+        params, tokens, meta, *pool, table, *tails, cfg)
+    return logits, (tail_k, tail_v), None
+
+
+def _dense_page(params, tokens_page, meta, ctx, tails, cfg):
+    logits, tail_k, tail_v = paged_decode_page_jit(
+        params, tokens_page, meta, *ctx, *tails, cfg)
+    return logits, (tail_k, tail_v), None
+
+
+def _dense_token(params, token, meta, ctx, tails, cfg):
+    logits, tail_k, tail_v = paged_decode_step_jit(
+        params, token, meta, *ctx, *tails, cfg)
+    return logits, (tail_k, tail_v), None
+
+
+def _dense_write_row(pool, page, slot):
+    return paged_pool_write_row_jit(*pool, *page, slot)
+
+
+DENSE_FAMILY = PagedFamily(
+    n_leaves=2, leaf_dims=_dense_leaf_dims, step=_dense_step,
+    page=_dense_page, write_row=_dense_write_row, token=_dense_token,
+)
+
+
+def family_of(cfg) -> PagedFamily:
+    """The model family of a config: its own ``paged_family``, or the dense
+    grouped-query one."""
+    return getattr(cfg, "paged_family", None) or DENSE_FAMILY
 
 
 @dataclass
@@ -224,12 +271,12 @@ class _Entry:
     #: (a CoW-adopted partial): storage-side only, excluded from the
     #: attention context.
     pending_fill: bool = False
-    arrays: tuple | None = None   # (k, v) decode-ready, cfg dtype
+    arrays: tuple | None = None   # the page's leaves, decode-ready, cfg dtype
     version: int = -1             # page.version the arrays were built at
 
 
 class _Session:
-    def __init__(self, req: Request, cfg, page_tokens: int, dtype):
+    def __init__(self, req: Request, tail_shape: tuple, n_leaves: int, dtype):
         self.req = req
         self.prompt = [int(t) for t in req.tokens]
         self.entries: list[_Entry] = []
@@ -255,11 +302,13 @@ class _Session:
         self.pages_done_t: float | None = None
         self.unseated_ticks = 0
         self.ttft_parts: dict | None = None
-        self._tail_shape = (cfg.n_layers, 1, cfg.n_kv_heads, page_tokens,
-                            cfg.head_dim)
+        self._tail_shape = tail_shape
+        self._n_leaves = n_leaves
         self._tail_dt = jnp.dtype(dtype)
-        self.tail_k = jnp.zeros(self._tail_shape, self._tail_dt)
-        self.tail_v = jnp.zeros(self._tail_shape, self._tail_dt)
+        #: The page being filled, one array a leaf of the family's page;
+        #: None while the last fused step's stacked tails hold it.
+        self.tails: tuple | None = None
+        self.reset_tail()
 
     def reset_tail(self) -> None:
         # FRESH zeros every page, for two reasons: published partial
@@ -267,8 +316,8 @@ class _Session:
         # and the decode step donates the tail buffers — a cached zeros
         # array would be consumed by the first donation and poison every
         # later page.
-        self.tail_k = jnp.zeros(self._tail_shape, self._tail_dt)
-        self.tail_v = jnp.zeros(self._tail_shape, self._tail_dt)
+        self.tails = tuple(jnp.zeros(self._tail_shape, self._tail_dt)
+                           for _ in range(self._n_leaves))
         self.tail_len = 0
         self.page_toks = []
 
@@ -330,12 +379,17 @@ class ServingEngine:
             max_batch = int(os.environ.get("OCM_SERVING_MAX_BATCH", "8"))
         self.max_batch = max(1, int(max_batch))
         self.keep_logits = bool(keep_logits)
+        # The model family of cfg: the leaves of a page and the programs
+        # dispatched over them.
+        self.family = family_of(cfg)
+        if not self.batched and self.family.token is None:
+            raise ValueError("this model family has no batch-of-1 step: "
+                             "it is served batched only")
         # The fused step's page pool, kept on the device between ticks
-        # (see _batch_pool): K and V rows (capacity, L, KV, P, Hd), the
-        # row of every (page_id, version) it holds, least recently
-        # seated first, and the rows nothing was written to yet.
-        self._pool_k = None
-        self._pool_v = None
+        # (see _batch_pool): one array of rows (capacity, L, KV, P, Hd) a
+        # leaf, the row of every (page_id, version) it holds, least
+        # recently seated first, and the rows nothing was written to yet.
+        self._pool: tuple | None = None
         self._pool_slots: dict[tuple, int] = {}
         self._pool_free: list[int] = []
         # Pool capacities whose row-write program has already run.
@@ -349,9 +403,9 @@ class ServingEngine:
         self.queue: list[Request] = []
         self.active: list[_Session] = []
         self.results: list[SessionResult] = []
-        self.page_shape = (2, cfg.n_layers, 1, cfg.n_kv_heads,
-                           self.page_tokens, cfg.head_dim)
-        expect = int(np.prod(self.page_shape)) * jnp.dtype(store_dtype).itemsize
+        self._tail_shape = self.family.leaf_shape(cfg, self.page_tokens)
+        self.page_shape = (self.family.n_leaves,) + self._tail_shape
+        expect = self.page_nbytes(cfg, self.page_tokens, store_dtype)
         if expect != store.page_bytes:
             raise ValueError(
                 f"store page_bytes {store.page_bytes} != model page "
@@ -370,12 +424,19 @@ class ServingEngine:
     @staticmethod
     def page_nbytes(cfg, page_tokens: int,
                     store_dtype: str = "float32") -> int:
-        """Size of one packed (K+V) page for ``cfg`` — what the
-        :class:`TieredPageStore` must be built with."""
+        """Size of one packed page (every leaf of ``cfg``'s family) — what
+        the :class:`TieredPageStore` must be built with."""
+        fam = family_of(cfg)
         return int(
-            2 * cfg.n_layers * 1 * cfg.n_kv_heads * page_tokens
-            * cfg.head_dim * jnp.dtype(store_dtype).itemsize
+            fam.n_leaves * np.prod(fam.leaf_shape(cfg, page_tokens))
+            * jnp.dtype(store_dtype).itemsize
         )
+
+    @property
+    def _pool_k(self):
+        """The pool's first leaf (the dense family's K rows), or None: its
+        row count is the pool's capacity."""
+        return None if self._pool is None else self._pool[0]
 
     # -- submission / driving --------------------------------------------
 
@@ -423,7 +484,7 @@ class ServingEngine:
         for sess in self.active:
             self._finish(sess, abandon=True)
         self.active = []
-        self._pool_k = self._pool_v = None
+        self._pool = None
         # Persist the prefix trie into the frozen tier (if one backs
         # the store) BEFORE the prefetcher drains: the pages are still
         # readable, and the next incarnation's __init__ restores them.
@@ -449,7 +510,8 @@ class ServingEngine:
         # every page boundary), not an admission-time lookup: sessions
         # admitted simultaneously still dedup against pages a sibling
         # publishes one turn later.
-        sess = _Session(req, self.cfg, self.page_tokens, self.cfg.dtype)
+        sess = _Session(req, self._tail_shape, self.family.n_leaves,
+                        self.cfg.dtype)
         sess.admit_t = time.perf_counter()
         self._note_pages_done(sess)
         return sess
@@ -509,12 +571,7 @@ class ServingEngine:
         self.prefix.acquire(ext)
         sess.shared_refs.append(ext)
         clone = self.store.cow(ext.page)
-        data = self.store.read_page(clone)
-        packed = from_bytes(jnp.asarray(np.array(data, copy=True)),
-                            self.page_shape, self.store_dtype)
-        dt = jnp.dtype(self.cfg.dtype)
-        sess.tail_k = packed[0].astype(dt)
-        sess.tail_v = packed[1].astype(dt)
+        sess.tails = self._unpack(self.store.read_page(clone))
         sess.tail_len = upto
         sess.page_toks = list(ext.tokens[:upto])
         sess.pos += upto
@@ -531,7 +588,8 @@ class ServingEngine:
         packed = from_bytes(jnp.asarray(np.array(data, copy=True)),
                             self.page_shape, self.store_dtype)
         dt = jnp.dtype(self.cfg.dtype)
-        return (packed[0].astype(dt), packed[1].astype(dt))
+        return tuple(packed[i].astype(dt)
+                     for i in range(self.family.n_leaves))
 
     def _resident(self, e: _Entry) -> bool:
         return (e.arrays is not None and e.version == e.page.version
@@ -640,21 +698,23 @@ class ServingEngine:
         return (data, version, None)
 
     def _context(self, sess: _Session) -> tuple:
-        ks = [e.arrays[0] for e in sess.entries if not e.pending_fill]
-        vs = [e.arrays[1] for e in sess.entries if not e.pending_fill]
-        cfg = self.cfg
-        if not ks:
-            shape = (cfg.n_layers, 1, cfg.n_kv_heads, 0, cfg.head_dim)
-            z = jnp.zeros(shape, jnp.dtype(cfg.dtype))
-            return z, z
-        return jnp.concatenate(ks, axis=3), jnp.concatenate(vs, axis=3)
+        """The session's paged context, a leaf at a time: its pages'
+        arrays joined along the token axis."""
+        pages = [e.arrays for e in sess.entries if not e.pending_fill]
+        n = self.family.n_leaves
+        if not pages:
+            shape = self._tail_shape[:3] + (0,) + self._tail_shape[4:]
+            z = jnp.zeros(shape, jnp.dtype(self.cfg.dtype))
+            return (z,) * n
+        return tuple(jnp.concatenate([a[i] for a in pages], axis=3)
+                     for i in range(n))
 
     # -- decode -----------------------------------------------------------
 
     def _turn(self, sess: _Session, budget: int) -> None:
         self._match_more(sess)
         self._ensure_resident(sess)
-        k_ctx, v_ctx = self._context(sess)
+        ctx = self._context(sess)
         for _ in range(budget):
             if sess.prompt_consumed < len(sess.prompt):
                 tok = sess.prompt[sess.prompt_consumed]
@@ -665,9 +725,9 @@ class ServingEngine:
                 tok = sess.out[-1] if sess.out else sess.prompt[-1]
                 prefill = False
             meta = jnp.asarray([sess.pos, sess.tail_len, 0], jnp.int32)
-            logits, sess.tail_k, sess.tail_v = paged_decode_step_jit(
+            logits, sess.tails, _ = self.family.token(
                 self.params, jnp.asarray([tok], jnp.int32), meta,
-                k_ctx, v_ctx, sess.tail_k, sess.tail_v, self.cfg,
+                ctx, sess.tails, self.cfg,
             )
             sess.pos += 1
             sess.tail_len += 1
@@ -687,7 +747,7 @@ class ServingEngine:
                 # chunk of this prompt since the last probe.
                 self._match_more(sess)
                 self._ensure_resident(sess)
-                k_ctx, v_ctx = self._context(sess)
+                ctx = self._context(sess)
             elif (self.share_partials and prefill
                   and sess.prompt_consumed == len(sess.prompt)):
                 self._publish_partial(sess)
@@ -812,14 +872,14 @@ class ServingEngine:
         P = self.page_tokens
         with span("prefill.residency"):
             self._ensure_resident(sess)
-            k_ctx, v_ctx = self._context(sess)
+            ctx = self._context(sess)
         with span("prefill.dispatch"):
             pc = sess.prompt_consumed
             chunk = sess.prompt[pc:pc + P]
             meta = jnp.asarray([sess.pos, 0], jnp.int32)
-            logits, sess.tail_k, sess.tail_v = paged_decode_page_jit(
+            logits, sess.tails, touched = self.family.page(
                 self.params, jnp.asarray([chunk], jnp.int32), meta,
-                k_ctx, v_ctx, sess.tail_k, sess.tail_v, self.cfg,
+                ctx, sess.tails, self.cfg,
             )
         sess.pos += P
         sess.tail_len = P
@@ -839,6 +899,9 @@ class ServingEngine:
                 sess.done = True
         with span("prefill.ship"):
             self._ship(sess)
+            if touched is not None:
+                # The ship has waited for the page program: no wait here.
+                self.stats.note_moe_page(int(touched))
             self._match_more(sess)
 
     def _yields_cold(self, sess: _Session) -> bool:
@@ -924,17 +987,18 @@ class ServingEngine:
         """The tick's page pool + per-session block table. The pool is
         device state that outlives the tick: every distinct resident
         page of the batch holds one row of a (capacity, L, KV, P, Hd)
-        pool (a shared prefix page is one row however many sessions
-        reference it) under its (page_id, version), and table[b] lists
-        session b's rows. A page that has a row keeps it, with no device
-        work; a page without one takes a free row, or the row of the
-        page seated longest ago that this batch does not reference, and
-        ONE :func:`paged_pool_write_row_jit` dispatch writes its K and V
+        pool, one such array a leaf of the family's page (a shared prefix
+        page is one row however many sessions reference it) under its
+        (page_id, version), and table[b] lists session b's rows. A page
+        that has a row keeps it, with no device work; a page without one
+        takes a free row, or the row of the page seated longest ago that
+        this batch does not reference, and ONE dispatch of the family's
+        row write (:func:`paged_pool_write_row_jit`) writes its leaves
         there in place. So a session that loses its seat for a tick
         finds its rows again. ``capacity`` and MP snap to power-of-two
         buckets of this batch's rows; when the capacity bucket changes,
         the batch's pages are written into a fresh pool
-        (:meth:`_new_pool`)."""
+        (:meth:`_new_pool`). Returns ``(*pool leaves, table, tables)``."""
         rows: dict[tuple, tuple] = {}
         tables = []
         for sess in batch:
@@ -949,7 +1013,7 @@ class ServingEngine:
         max_pages = max((len(t) for t in tables), default=0)
         mp = _pow2(max_pages) if max_pages else 0
         capacity = _pow2(len(rows)) if rows else 1
-        rebuilt = self._pool_k is None or self._pool_k.shape[0] != capacity
+        rebuilt = self._pool is None or self._pool[0].shape[0] != capacity
         if rebuilt:
             self._new_pool(capacity)
         slots = self._pool_slots
@@ -965,15 +1029,15 @@ class ServingEngine:
             # oldest key is one this batch does not reference.
             slot = (self._pool_free.pop() if self._pool_free
                     else slots.pop(next(iter(slots))))
-            self._pool_k, self._pool_v = paged_pool_write_row_jit(
-                self._pool_k, self._pool_v, *rows[key], np.int32(slot))
+            self._pool = self.family.write_row(
+                self._pool, rows[key], np.int32(slot))
             slots[key] = slot
         self.stats.note_pool(reused=len(rows) - len(fresh),
                              written=len(fresh), rebuilt=rebuilt)
         table = np.zeros((len(batch), mp), np.int32)
         for b, trow in enumerate(tables):
             table[b, :len(trow)] = [slots[key] for key in trow]
-        return self._pool_k, self._pool_v, table, tables
+        return (*self._pool, table, tables)
 
     def _new_pool(self, capacity: int) -> None:
         """Replace the pool by zeros of ``capacity`` rows, all free. The
@@ -982,20 +1046,22 @@ class ServingEngine:
         place, at whatever tick that is: the row write runs once here on
         scratch zeros of this capacity and of the next one up."""
         dt = jnp.dtype(self.cfg.dtype)
-        page = self.page_shape[1:]      # (L, 1, KV, P, Hd)
+        page = self._tail_shape         # (L, 1, KV, P, Hd)
+        leaves = self.family.n_leaves
 
         def zeros(n: int) -> tuple:
             shape = (n, page[0]) + page[2:]
-            return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+            return tuple(jnp.zeros(shape, dt) for _ in range(leaves))
 
         # The old pool's memory goes before the new pool is made.
-        self._pool_k = self._pool_v = None
+        self._pool = None
         for n in (capacity, 2 * capacity):
             if n not in self._pool_write_ready:
                 zpage = jnp.zeros(page, dt)
-                paged_pool_write_row_jit(*zeros(n), zpage, zpage, np.int32(0))
+                self.family.write_row(zeros(n), (zpage,) * leaves,
+                                      np.int32(0))
                 self._pool_write_ready.add(n)
-        self._pool_k, self._pool_v = zeros(capacity)
+        self._pool = zeros(capacity)
         self._pool_slots = {}
         self._pool_free = list(range(capacity - 1, -1, -1))
 
@@ -1011,7 +1077,7 @@ class ServingEngine:
             with span("step.residency"):
                 self._ensure_resident_batch(batch)
             with span("step.pool"):
-                pool_k, pool_v, table, tables = self._batch_pool(batch)
+                *pool, table, tables = self._batch_pool(batch)
             with span("step.args"):
                 b_pad = _pow2(len(batch))
                 toks, metas, prefills = [], [], []
@@ -1032,39 +1098,45 @@ class ServingEngine:
                 metas += [[0, 0, 0, 0]] * pad_b
                 st = self._tail_stack
                 if (st is not None and st[0] == batch
-                        and all(s.tail_k is None for s in batch)):
+                        and all(s.tails is None for s in batch)):
                     # Same seated sessions as last step and nobody
                     # shipped: the previous step's stacked tails ARE this
                     # step's inputs — no per-session slices, no concat
                     # (they get donated).
-                    tail_k, tail_v = st[1], st[2]
+                    tails = st[1]
                     self._tail_stack = None
                 else:
                     self._flush_tail_stack()
-                    tshape = (cfg.n_layers, 1, cfg.n_kv_heads, P,
-                              cfg.head_dim)
-                    ztail = jnp.zeros(tshape, jnp.dtype(cfg.dtype))
-                    tail_k = jnp.concatenate(
-                        [s.tail_k for s in batch] + [ztail] * pad_b, axis=1)
-                    tail_v = jnp.concatenate(
-                        [s.tail_v for s in batch] + [ztail] * pad_b, axis=1)
+                    ztail = jnp.zeros(self._tail_shape, jnp.dtype(cfg.dtype))
+                    tails = tuple(
+                        jnp.concatenate([s.tails[i] for s in batch]
+                                        + [ztail] * pad_b, axis=1)
+                        for i in range(self.family.n_leaves))
                 tab = np.zeros((b_pad, table.shape[1]), np.int32)
                 tab[:len(batch)] = table
                 tab_key = (tab.shape, tab.tobytes())
             with span("step.dispatch"):
                 if self._tab_cache[0] != tab_key:
                     self._tab_cache = (tab_key, jnp.asarray(tab))
-                logits, ntk, ntv = paged_decode_batch_step_jit(
+                logits, new_tails, touched = self.family.step(
                     self.params, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray(metas, jnp.int32), pool_k, pool_v,
-                    self._tab_cache[1], tail_k, tail_v, cfg,
+                    jnp.asarray(metas, jnp.int32), len(batch), pool,
+                    self._tab_cache[1], tails, cfg,
                 )
             with span("step.sync"):
                 # One fused greedy argmax + host transfer for the whole
                 # batch (row b is bitwise jnp.argmax(logits[b]) — same
                 # bits, same first-max tie-break); doubles as the step's
-                # device sync: the host waits on the device here.
-                best = np.asarray(jnp.argmax(logits, axis=-1))
+                # device sync: the host waits on the device here. A family
+                # with experts hands its count back in the same transfer.
+                if touched is None:
+                    best = np.asarray(jnp.argmax(logits, axis=-1))
+                else:
+                    best, touched = jax.device_get(
+                        (jnp.argmax(logits, axis=-1), touched))
+                    self.stats.note_moe_step(
+                        int(touched),
+                        len(batch) * self.family.assignments_per_token(cfg))
                 kept = np.asarray(logits) if self.keep_logits else None
             dt = time.perf_counter() - step.t0
             self.stats.note_batch_step(len(batch), dt)
@@ -1072,15 +1144,14 @@ class ServingEngine:
                 "batch_step", size=len(batch), pad=b_pad,
                 pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
             )
-            self._tail_stack = (list(batch), ntk, ntv)
+            self._tail_stack = (list(batch), new_tails)
             with span("step.scatter"):
                 for b, (sess, tok, prefill) in enumerate(
                         zip(batch, toks, prefills)):
                     # Tails stay stacked (see _tail_stack); a session only
-                    # pays for its two slices when something reads them
-                    # this tick.
-                    sess.tail_k = None
-                    sess.tail_v = None
+                    # pays for its slices when something reads them this
+                    # tick.
+                    sess.tails = None
                     sess.pos += 1
                     sess.tail_len += 1
                     sess.page_toks.append(int(tok))
@@ -1095,15 +1166,15 @@ class ServingEngine:
                             self.stats.note_tokens(1)
                     if sess.tail_len == P:
                         with span("step.ship"):
-                            sess.tail_k = ntk[:, b:b + 1]
-                            sess.tail_v = ntv[:, b:b + 1]
+                            sess.tails = tuple(
+                                t[:, b:b + 1] for t in new_tails)
                             self._ship(sess)
                             self._match_more(sess)
                     elif (self.share_partials and prefill
                           and sess.prompt_consumed == len(sess.prompt)):
                         with span("step.publish"):
-                            sess.tail_k = ntk[:, b:b + 1]
-                            sess.tail_v = ntv[:, b:b + 1]
+                            sess.tails = tuple(
+                                t[:, b:b + 1] for t in new_tails)
                             self._publish_partial(sess)
                     if len(sess.out) > sess.req.max_new_tokens:
                         raise AssertionError("overran max_new_tokens")
@@ -1118,21 +1189,20 @@ class ServingEngine:
         if st is None:
             return
         self._tail_stack = None
-        sessions, ntk, ntv = st
+        sessions, stacked = st
         for b, sess in enumerate(sessions):
-            if sess.tail_k is None:
-                sess.tail_k = ntk[:, b:b + 1]
-                sess.tail_v = ntv[:, b:b + 1]
+            if sess.tails is None:
+                sess.tails = tuple(t[:, b:b + 1] for t in stacked)
 
     def _ship(self, sess: _Session) -> None:
         """Page boundary: the full tail becomes a stored page — the
         pending CoW clone when one is open, a published shared extent
         for prompt-only pages, a private page otherwise."""
-        packed = jnp.stack([sess.tail_k, sess.tail_v]).astype(
+        packed = jnp.stack(list(sess.tails)).astype(
             jnp.dtype(self.store_dtype)
         )
         raw = np.asarray(to_bytes(packed))
-        arrays = (sess.tail_k, sess.tail_v)
+        arrays = sess.tails
         prompt_only = sess.pos <= len(sess.prompt)
         pending = next((e for e in sess.entries if e.pending_fill), None)
         if pending is not None:
@@ -1169,7 +1239,7 @@ class ServingEngine:
         prompt_toks = sess.page_toks[:sess.tail_len]
         if sess.pos > len(sess.prompt):
             return
-        packed = jnp.stack([sess.tail_k, sess.tail_v]).astype(
+        packed = jnp.stack(list(sess.tails)).astype(
             jnp.dtype(self.store_dtype)
         )
         raw = np.asarray(to_bytes(packed))

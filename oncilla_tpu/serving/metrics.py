@@ -87,6 +87,15 @@ class ServingStats:
         self.pool_rows_reused = 0
         self.pool_rows_written = 0
         self.pool_rebuilds = 0
+        # What a step's experts cost (a family with routed experts; the
+        # programs hand the counts back): distinct (layer, expert) pairs
+        # that received a real token, over fused steps and over prefill
+        # pages, the (token, layer, expert) assignments the fused steps
+        # routed, and the pages counted. Fused steps are batch_steps.
+        self.moe_step_expert_rows = 0
+        self.moe_step_assignments = 0
+        self.moe_page_expert_rows = 0
+        self.moe_page_count = 0
         self.preempts: dict[str, int] = {}
         # Time-to-first-token per session (submit -> first emitted
         # token), same cumulative prom-style bucket shape as the step
@@ -221,6 +230,20 @@ class ServingStats:
             self.pool_rows_written += written
             self.pool_rebuilds += int(rebuilt)
 
+    def note_moe_step(self, expert_rows: int, assignments: int) -> None:
+        """One fused step of a family with experts: ``expert_rows``
+        distinct (layer, expert) pairs were read for ``assignments``
+        (token, layer, expert) routings of real rows."""
+        with self._mu:
+            self.moe_step_expert_rows += expert_rows
+            self.moe_step_assignments += assignments
+
+    def note_moe_page(self, expert_rows: int) -> None:
+        """One prefill page of a family with experts."""
+        with self._mu:
+            self.moe_page_expert_rows += expert_rows
+            self.moe_page_count += 1
+
     def note_prefill_chunk(self) -> None:
         with self._mu:
             self.prefill_chunks += 1
@@ -292,6 +315,12 @@ class ServingStats:
                     "rows_reused": self.pool_rows_reused,
                     "rows_written": self.pool_rows_written,
                     "rebuilds": self.pool_rebuilds,
+                },
+                "moe": {
+                    "step_expert_rows": self.moe_step_expert_rows,
+                    "step_assignments": self.moe_step_assignments,
+                    "page_expert_rows": self.moe_page_expert_rows,
+                    "page_count": self.moe_page_count,
                 },
                 "preempts": dict(self.preempts),
                 "ttft": {

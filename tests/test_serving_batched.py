@@ -613,7 +613,8 @@ def test_promoted_page_gets_its_row_rewritten(tiny_model):
     assert outs == outs_il
 
 
-def test_pool_write_program_compiles_once_a_capacity(tiny_model):
+def test_pool_write_program_compiles_once_a_capacity(tiny_model, monkeypatch):
+    import oncilla_tpu.serving.engine as engine_mod
     from oncilla_tpu.models import paged_decode_batch_step_jit as step
     from oncilla_tpu.models import paged_pool_write_row_jit as write
 
@@ -621,6 +622,16 @@ def test_pool_write_program_compiles_once_a_capacity(tiny_model):
     rng = np.random.default_rng(79)
     prompts = [rng.integers(1, cfg.vocab, ln).tolist()
                for ln in (5, 9, 17, 25, 30)]
+    # The programs' own cache is process-wide (a whole run's other tests
+    # have filled it), so count what THIS workload hands the row write: one
+    # program a distinct pool shape.
+    shapes = []
+
+    def recording(pool_k, pool_v, *rest):
+        shapes.append(pool_k.shape)
+        return write(pool_k, pool_v, *rest)
+
+    monkeypatch.setattr(engine_mod, "paged_pool_write_row_jit", recording)
 
     def workload():
         return run_prompts(tiny_model, prompts, new_tokens=12,
@@ -632,7 +643,8 @@ def test_pool_write_program_compiles_once_a_capacity(tiny_model):
     # one write program a pool capacity: those the run reached (1..16 rows)
     # and the next one up, never one a row or a tick
     assert meta["pool"]["rebuilds"] >= 2
-    assert 0 < built[1] <= 6 < meta["pool"]["rows_written"]
+    assert 0 < len(set(shapes)) <= 6 < meta["pool"]["rows_written"]
+    assert {s[0] for s in shapes} <= {1, 2, 4, 8, 16, 32}
     # a second identical workload builds nothing more, for the fused step
     # and for the row write
     outs2, meta2, _ = workload()
