@@ -382,6 +382,8 @@ def paged_decode_batch_step_jit(
     validity is masked per row (padded context slots and empty tail
     slots attend to nothing), positions/rope are per row, and the tail
     insertion scatters each session's new K/V at its own ``tail_len``.
+    A row whose ``tail_len`` is 0 reads its tail as zeros whatever the
+    buffer holds, so the caller may leave a shipped page where it lies.
     Sessions shorter than the padded shapes see extra masked keys whose
     softmax weight is exactly 0, so padding changes no sum's terms. On
     the CPU backend in float32 the emitted logits are bitwise those of
@@ -439,13 +441,20 @@ def paged_decode_batch_step_jit(
     # twin of the step path's dynamic_update_slice).
     slot = jnp.arange(P)[None, :] == tail_len[:, None]  # (B, P)
     slot4 = slot[:, None, :, None]
+    # A row that enters with tail_len 0 starts a page: whatever its seat
+    # of the tail stack still holds (the page its session shipped, another
+    # session's tail) reads as zeros, so a page is zeros beyond its fill
+    # without anybody writing them.
+    live4 = (tail_len > 0)[:, None, None, None]
 
     for i in range(cfg.n_layers):
         state = {}
 
         def attend(q, kn, vn, i=i, state=state):
-            tk = jnp.where(slot4, kn.astype(tail_k.dtype), tail_k[i])
-            tv = jnp.where(slot4, vn.astype(tail_v.dtype), tail_v[i])
+            tk = jnp.where(slot4, kn.astype(tail_k.dtype),
+                           jnp.where(live4, tail_k[i], 0))
+            tv = jnp.where(slot4, vn.astype(tail_v.dtype),
+                           jnp.where(live4, tail_v[i], 0))
             state["tk"], state["tv"] = tk, tv
             k_all = jnp.concatenate(
                 [k_ctx[i].astype(q.dtype), tk.astype(q.dtype)], axis=2

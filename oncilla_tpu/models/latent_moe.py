@@ -534,7 +534,8 @@ def latent_decode_batch_step_jit(
     """ONE fused decode step for a batch of paged sessions: the latent
     family's twin of ``kv_paging.paged_decode_batch_step_jit`` (same block
     table, same masking of padded context and empty tail slots, same
-    per-row tail insertion). Rows at and past ``n_real`` are padding: they
+    per-row tail insertion, a row with ``tail_len`` 0 reading its tail as
+    zeros). Rows at and past ``n_real`` are padding: they
     are routed to no expert and counted nowhere. Returns (logits (B, V)
     float32, new tail, () int32 distinct (layer, expert) pairs that
     received a real token)."""
@@ -551,6 +552,9 @@ def latent_decode_batch_step_jit(
         [jnp.arange(C)[None, :] < ctx_len[:, None],
          jnp.arange(P)[None, :] <= tail_len[:, None]], axis=1)
     slot = (jnp.arange(P)[None, :] == tail_len[:, None])[:, :, None]
+    # A row that enters with tail_len 0 reads its tail as zeros, as in the
+    # dense step: the engine leaves a shipped page in its seat.
+    live = (tail_len > 0)[:, None, None]
     X = _embed_streams(params, tokens, cfg)
     touched = jnp.int32(0)
     for i in range(L):
@@ -560,7 +564,7 @@ def latent_decode_batch_step_jit(
             with jax.named_scope("mla"):
                 qn, qr, entry = latent_qkv(h, params, i, pos, cfg)
                 t = jnp.where(slot, entry[:, None, :].astype(tail.dtype),
-                              tail[i, :, 0])
+                              jnp.where(live, tail[i, :, 0], 0))
                 state["tail"] = t
                 latent = jnp.concatenate([ctx[i].astype(dt), t.astype(dt)],
                                          axis=1)

@@ -41,6 +41,7 @@ from __future__ import annotations
 import os
 import time
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +107,36 @@ DENSE_FAMILY = PagedFamily(
     n_leaves=2, leaf_dims=_dense_leaf_dims, step=_dense_step,
     page=_dense_page, write_row=_dense_write_row, token=_dense_token,
 )
+
+
+# The tails of the seated sessions live in ONE stack, a leaf of the family's
+# page each (L, b_pad, KV, P, Hd), that the engine owns between ticks
+# (:meth:`ServingEngine._seat_batch`). A seat changes hands through these
+# three programs, all leaves in one dispatch, the seat a traced index: one
+# executable a stack shape serves every seat.
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _seat_write_jit(stack: tuple, tail: tuple, seat: jax.Array) -> tuple:
+    """Write one session's tail, (L, 1, KV, P, Hd) a leaf, into ``seat``."""
+    return tuple(jax.lax.dynamic_update_slice_in_dim(s, t, seat, axis=1)
+                 for s, t in zip(stack, tail))
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _seat_move_jit(stack: tuple, src: jax.Array, dst: jax.Array) -> tuple:
+    """Copy seat ``src`` over seat ``dst`` (the last seat fills a hole)."""
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(
+            s, jax.lax.dynamic_slice_in_dim(s, src, 1, axis=1), dst, axis=1)
+        for s in stack)
+
+
+@jax.jit
+def _seat_read_jit(stack: tuple, seat: jax.Array) -> tuple:
+    """The tail in ``seat``, (L, 1, KV, P, Hd) a leaf, as arrays of its own."""
+    return tuple(jax.lax.dynamic_slice_in_dim(s, seat, 1, axis=1)
+                 for s in stack)
 
 
 def family_of(cfg) -> PagedFamily:
@@ -305,8 +336,11 @@ class _Session:
         self._tail_shape = tail_shape
         self._n_leaves = n_leaves
         self._tail_dt = jnp.dtype(dtype)
+        #: The seat of the engine's tail stack that holds this session's
+        #: tail, or None while the session holds it itself.
+        self.seat: int | None = None
         #: The page being filled, one array a leaf of the family's page;
-        #: None while the last fused step's stacked tails hold it.
+        #: None while a seat holds it.
         self.tails: tuple | None = None
         self.reset_tail()
 
@@ -394,11 +428,14 @@ class ServingEngine:
         self._pool_free: list[int] = []
         # Pool capacities whose row-write program has already run.
         self._pool_write_ready: set[int] = set()
-        # Steady-state fused-step fast path: the kernel's stacked tail
-        # outputs feed the next step directly while batch membership is
-        # unchanged; per-session slices materialize lazily (ship /
-        # publish / membership change). See _batch_step.
-        self._tail_stack: tuple | None = None
+        # The seated sessions' tails, kept on the device between ticks
+        # (see _seat_batch): one stack (L, b_pad, KV, P, Hd) a leaf, the
+        # session in every seat (seats [0, len) are taken, a vacated one
+        # is None until the next step closes it), and the stack widths
+        # whose seat programs have already run.
+        self._tails: tuple | None = None
+        self._seats: list[_Session | None] = []
+        self._seat_ready: set[int] = set()
         self._tab_cache: tuple = (None, None)
         self.queue: list[Request] = []
         self.active: list[_Session] = []
@@ -485,6 +522,8 @@ class ServingEngine:
             self._finish(sess, abandon=True)
         self.active = []
         self._pool = None
+        self._tails = None
+        self._seats = []
         # Persist the prefix trie into the frozen tier (if one backs
         # the store) BEFORE the prefetcher drains: the pages are still
         # readable, and the next incarnation's __init__ restores them.
@@ -873,6 +912,8 @@ class ServingEngine:
         with span("prefill.residency"):
             self._ensure_resident(sess)
             ctx = self._context(sess)
+            if sess.seat is not None:
+                self._unseat(sess)
         with span("prefill.dispatch"):
             pc = sess.prompt_consumed
             chunk = sess.prompt[pc:pc + P]
@@ -1076,6 +1117,10 @@ class ServingEngine:
         with span("serve_batch_step") as step:
             with span("step.residency"):
                 self._ensure_resident_batch(batch)
+            with span("step.args"):
+                # Rows in seat order from here on: row b is seat b.
+                self._seat_batch(batch)
+                batch = list(self._seats)
             with span("step.pool"):
                 *pool, table, tables = self._batch_pool(batch)
             with span("step.args"):
@@ -1096,33 +1141,27 @@ class ServingEngine:
                 pad_b = b_pad - len(batch)
                 toks += [0] * pad_b
                 metas += [[0, 0, 0, 0]] * pad_b
-                st = self._tail_stack
-                if (st is not None and st[0] == batch
-                        and all(s.tails is None for s in batch)):
-                    # Same seated sessions as last step and nobody
-                    # shipped: the previous step's stacked tails ARE this
-                    # step's inputs — no per-session slices, no concat
-                    # (they get donated).
-                    tails = st[1]
-                    self._tail_stack = None
-                else:
-                    self._flush_tail_stack()
-                    ztail = jnp.zeros(self._tail_shape, jnp.dtype(cfg.dtype))
-                    tails = tuple(
-                        jnp.concatenate([s.tails[i] for s in batch]
-                                        + [ztail] * pad_b, axis=1)
-                        for i in range(self.family.n_leaves))
                 tab = np.zeros((b_pad, table.shape[1]), np.int32)
                 tab[:len(batch)] = table
                 tab_key = (tab.shape, tab.tobytes())
             with span("step.dispatch"):
                 if self._tab_cache[0] != tab_key:
                     self._tab_cache = (tab_key, jnp.asarray(tab))
-                logits, new_tails, touched = self.family.step(
-                    self.params, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray(metas, jnp.int32), len(batch), pool,
-                    self._tab_cache[1], tails, cfg,
-                )
+                # The stack is donated; the step hands it back with every
+                # row's token in place. A step that raises hands nothing
+                # back: nobody holds a seat of a stack that is gone.
+                try:
+                    logits, self._tails, touched = self.family.step(
+                        self.params, jnp.asarray(toks, jnp.int32),
+                        jnp.asarray(metas, jnp.int32), len(batch), pool,
+                        self._tab_cache[1], self._tails, cfg,
+                    )
+                except BaseException:
+                    for sess in batch:
+                        sess.seat = None
+                    self._tails = None
+                    self._seats = []
+                    raise
             with span("step.sync"):
                 # One fused greedy argmax + host transfer for the whole
                 # batch (row b is bitwise jnp.argmax(logits[b]) — same
@@ -1144,14 +1183,9 @@ class ServingEngine:
                 "batch_step", size=len(batch), pad=b_pad,
                 pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
             )
-            self._tail_stack = (list(batch), new_tails)
             with span("step.scatter"):
                 for b, (sess, tok, prefill) in enumerate(
                         zip(batch, toks, prefills)):
-                    # Tails stay stacked (see _tail_stack); a session only
-                    # pays for its slices when something reads them this
-                    # tick.
-                    sess.tails = None
                     sess.pos += 1
                     sess.tail_len += 1
                     sess.page_toks.append(int(tok))
@@ -1166,43 +1200,125 @@ class ServingEngine:
                             self.stats.note_tokens(1)
                     if sess.tail_len == P:
                         with span("step.ship"):
-                            sess.tails = tuple(
-                                t[:, b:b + 1] for t in new_tails)
+                            # One read of the seat; the seat stays the
+                            # session's, empty.
                             self._ship(sess)
                             self._match_more(sess)
                     elif (self.share_partials and prefill
                           and sess.prompt_consumed == len(sess.prompt)):
                         with span("step.publish"):
-                            sess.tails = tuple(
-                                t[:, b:b + 1] for t in new_tails)
                             self._publish_partial(sess)
                     if len(sess.out) > sess.req.max_new_tokens:
                         raise AssertionError("overran max_new_tokens")
                     if len(sess.out) == sess.req.max_new_tokens:
                         sess.done = True
 
-    def _flush_tail_stack(self) -> None:
-        """Materialize the deferred per-session tail slices out of the
-        last fused step's stacked outputs (membership changed, or a
-        session needs its tail outside the steady state)."""
-        st = self._tail_stack
-        if st is None:
-            return
-        self._tail_stack = None
-        sessions, stacked = st
-        for b, sess in enumerate(sessions):
-            if sess.tails is None:
-                sess.tails = tuple(t[:, b:b + 1] for t in stacked)
+    def _seat_batch(self, batch: list[_Session]) -> None:
+        """Seat ``batch`` for one fused step: afterwards ``self._seats``
+        is the batch, seat by seat, and ``self._tails`` holds every
+        session's tail in its seat. The stack is device state that
+        outlives the tick, and a session that was seated in the last step
+        and still is costs nothing, whoever else shipped, published,
+        finished or joined. A seat changes hands at one dispatch: a
+        session that leaves alive reads its tail out (:meth:`_unseat`), one
+        that joins with tokens in its tail has it written into its seat
+        (one that joins empty just sits down: the step reads a row that
+        enters with tail_len 0 as zeros), and the step counts rows
+        [0, n_real) as sessions, so a seat vacated in the middle is taken
+        by a joiner or by the last seat, moved. A change of ``b_pad``
+        unseats everybody into a fresh stack (:meth:`_new_tails`)."""
+        b_pad = _pow2(len(batch))
+        rebuilt = self._tails is None or self._tails[0].shape[1] != b_pad
+        staying = set() if rebuilt else {id(sess) for sess in batch}
+        for sess in self._seats:
+            if sess is not None and id(sess) not in staying:
+                self._unseat(sess)
+        if rebuilt:
+            self._new_tails(b_pad)
+        seats = self._seats
+        holes = [b for b, sess in enumerate(seats) if sess is None]
+        written = 0
+        for sess in batch:
+            if sess.seat is not None:
+                continue                # in its seat since the last step
+            if holes:
+                sess.seat = holes.pop(0)
+                seats[sess.seat] = sess
+            else:
+                sess.seat = len(seats)
+                seats.append(sess)
+            if sess.tail_len:
+                self._tails = _seat_write_jit(
+                    self._tails, sess.tails, np.int32(sess.seat))
+                written += 1
+            sess.tails = None
+        for hole in holes:              # those no joiner took, lowest first
+            while seats[-1] is None:
+                seats.pop()
+            if hole >= len(seats):
+                break                   # it was at the end, and is gone
+            last = seats.pop()
+            if last.tail_len:
+                self._tails = _seat_move_jit(
+                    self._tails, np.int32(last.seat), np.int32(hole))
+                written += 1
+            last.seat = hole
+            seats[hole] = last
+        if rebuilt:
+            written = len(batch)        # every seat was placed anew
+        self.stats.note_tails(kept=len(batch) - written, written=written)
+
+    def _unseat(self, sess: _Session) -> None:
+        """Vacate the seat of a session that lives on. It takes its tail
+        with it: one read of the seat, or fresh zeros when the tail is
+        empty. (One that is over just stands up: :meth:`_finish`.)"""
+        seat, sess.seat = sess.seat, None
+        self._seats[seat] = None
+        if sess.tail_len:
+            sess.tails = _seat_read_jit(self._tails, np.int32(seat))
+        else:
+            sess.reset_tail()
+
+    def _new_tails(self, b_pad: int) -> None:
+        """Replace the tail stack by zeros of ``b_pad`` seats, all vacant:
+        the first step, and a change of the padded batch size. As in
+        :meth:`_new_pool`, no program may be built when a seat first
+        changes hands, at whatever tick that is: the three seat programs
+        run once here on scratch zeros of this width and of the next one
+        up (the widest is ``max_batch``'s)."""
+        dt = jnp.dtype(self.cfg.dtype)
+        fam = self.family
+
+        def zeros(b: int) -> tuple:
+            shape = fam.leaf_shape(self.cfg, self.page_tokens, b)
+            return tuple(jnp.zeros(shape, dt) for _ in range(fam.n_leaves))
+
+        self._tails = None
+        for b in {b_pad, min(2 * b_pad, _pow2(self.max_batch))}:
+            if b not in self._seat_ready:
+                at = np.int32(0)
+                scratch = _seat_write_jit(zeros(b), zeros(1), at)
+                _seat_read_jit(_seat_move_jit(scratch, at, at), at)
+                self._seat_ready.add(b)
+        self._tails = zeros(b_pad)
+        self._seats = []
+
+    def _tail(self, sess: _Session) -> tuple:
+        """The session's tail, one (L, 1, KV, P, Hd) array a leaf: its
+        own, or one read of its seat."""
+        if sess.tails is not None:
+            return sess.tails
+        return _seat_read_jit(self._tails, np.int32(sess.seat))
 
     def _ship(self, sess: _Session) -> None:
         """Page boundary: the full tail becomes a stored page — the
         pending CoW clone when one is open, a published shared extent
         for prompt-only pages, a private page otherwise."""
-        packed = jnp.stack(list(sess.tails)).astype(
+        arrays = self._tail(sess)
+        packed = jnp.stack(list(arrays)).astype(
             jnp.dtype(self.store_dtype)
         )
         raw = np.asarray(to_bytes(packed))
-        arrays = sess.tails
         prompt_only = sess.pos <= len(sess.prompt)
         pending = next((e for e in sess.entries if e.pending_fill), None)
         if pending is not None:
@@ -1227,7 +1343,13 @@ class ServingEngine:
             sess.chain_valid = False  # generated content: never publish
         entry.arrays = arrays
         entry.version = entry.page.version
-        sess.reset_tail()
+        if sess.seat is None:
+            sess.reset_tail()
+        else:
+            # The seat stays the session's, and no zeros are made: the
+            # fused step reads a row that enters with tail_len 0 as zeros.
+            sess.tail_len = 0
+            sess.page_toks = []
 
     def _publish_partial(self, sess: _Session) -> None:
         """End of prefill mid-page: publish the prompt's partial tail as
@@ -1239,7 +1361,7 @@ class ServingEngine:
         prompt_toks = sess.page_toks[:sess.tail_len]
         if sess.pos > len(sess.prompt):
             return
-        packed = jnp.stack(list(sess.tails)).astype(
+        packed = jnp.stack(list(self._tail(sess))).astype(
             jnp.dtype(self.store_dtype)
         )
         raw = np.asarray(to_bytes(packed))
@@ -1254,6 +1376,9 @@ class ServingEngine:
             if e.extent is None and not e.page.shared and not e.page.freed:
                 self.store.free_page(e.page)
         sess.entries = []
+        if sess.seat is not None:
+            self._seats[sess.seat] = None
+            sess.seat = None
         if not abandon:
             self.results.append(SessionResult(
                 tenant=sess.req.tenant,

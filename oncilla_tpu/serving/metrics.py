@@ -87,6 +87,12 @@ class ServingStats:
         self.pool_rows_reused = 0
         self.pool_rows_written = 0
         self.pool_rebuilds = 0
+        # The seated sessions' tails, kept in one stack on the device
+        # between ticks: seats of a fused step whose tail the stack
+        # already held (an empty tail is held by any seat), and seats
+        # written, moved or placed in a new stack before the step.
+        self.tails_seats_kept = 0
+        self.tails_seats_written = 0
         # What a step's experts cost (a family with routed experts; the
         # programs hand the counts back): distinct (layer, expert) pairs
         # that received a real token, over fused steps and over prefill
@@ -230,6 +236,14 @@ class ServingStats:
             self.pool_rows_written += written
             self.pool_rebuilds += int(rebuilt)
 
+    def note_tails(self, kept: int = 0, written: int = 0) -> None:
+        """One fused step's seats: ``kept`` cost nothing, ``written``
+        took a dispatch (a joiner's tail written, the last seat moved
+        into a hole) or were placed in a new stack."""
+        with self._mu:
+            self.tails_seats_kept += kept
+            self.tails_seats_written += written
+
     def note_moe_step(self, expert_rows: int, assignments: int) -> None:
         """One fused step of a family with experts: ``expert_rows``
         distinct (layer, expert) pairs were read for ``assignments``
@@ -315,6 +329,10 @@ class ServingStats:
                     "rows_reused": self.pool_rows_reused,
                     "rows_written": self.pool_rows_written,
                     "rebuilds": self.pool_rebuilds,
+                },
+                "tails": {
+                    "seats_kept": self.tails_seats_kept,
+                    "seats_written": self.tails_seats_written,
                 },
                 "moe": {
                     "step_expert_rows": self.moe_step_expert_rows,

@@ -267,7 +267,7 @@ def test_no_token_is_dropped_when_all_choose_one_expert(tiny):
 
 
 def serve(cfg, params, prompts, new_tokens, *, hot, warm, share,
-          max_active=4, max_batch=None):
+          max_active=4, max_batch=None, watch=None):
     import oncilla_tpu as ocm
     from oncilla_tpu.serving.engine import Request, ServingEngine
     from oncilla_tpu.serving.metrics import ServingStats
@@ -286,6 +286,8 @@ def serve(cfg, params, prompts, new_tokens, *, hot, warm, share,
                         max_batch=max_batch, prefetch_workers=0,
                         name="latent", batched=True, keep_logits=True)
     try:
+        if watch is not None:
+            watch(eng)
         for i, p in enumerate(prompts):
             eng.submit(Request(tenant=f"t{i}", tokens=list(p),
                                max_new_tokens=new_tokens))
@@ -305,10 +307,33 @@ def test_engine_serves_it_through_hot_warm_cold_and_back(tiny):
     prompts = [base + rng.integers(1, cfg.vocab, n).tolist()
                for n in (5, 2, 8)] + [rng.integers(1, cfg.vocab, 3).tolist()]
     new = 9
+    seatings = []
+
+    def watch(eng):
+        seat_batch = eng._seat_batch
+
+        def seated(batch):
+            stack = eng._tails
+            seat_batch(batch)
+            assert len(eng._tails) == 1     # one leaf: the latent
+            seatings.append((stack is not None
+                             and stack[0].shape == eng._tails[0].shape,
+                             [s.req.tenant for s in eng._seats]))
+
+        eng._seat_batch = seated
+
     # Two HOT and two WARM pages under four sessions of three to five
     # pages each: pages go down to the cold tier and come back.
     results, meta = serve(cfg, params, prompts, new, hot=2, warm=2,
-                          share=True, max_batch=3)
+                          share=True, max_batch=3, watch=watch)
+    # A seat changed hands inside one stack: t3, which had lost its seat
+    # alive with a token in its tail, sat down where another had finished.
+    assert any(same and any(b != a for b, a in zip(before, after))
+               for (_, before), (same, after) in zip(seatings, seatings[1:]))
+    tails = meta["tails"]
+    assert tails["seats_kept"] > tails["seats_written"] > 3
+    assert (tails["seats_kept"] + tails["seats_written"]
+            == meta["batch"]["size_sum"])
     hops = meta["moves"]["hops"]
     assert hops.get("hbm>host", 0) and hops.get("host>remote", 0)
     assert hops.get("remote>hbm", 0) or hops.get("remote>host", 0)

@@ -52,14 +52,20 @@ def build_engine(tiny_model, *, share=True, hot=3, warm=4, prefetch=0,
 
 
 def run_prompts(tiny_model, prompts, *, new_tokens=6, priorities=None,
-                **kw):
+                watch=None, **kw):
+    """Serve ``prompts`` to the end. ``new_tokens`` is one budget or one a
+    prompt; ``watch`` is handed the engine before anything is submitted."""
     from oncilla_tpu.serving.engine import Request
 
+    if isinstance(new_tokens, int):
+        new_tokens = [new_tokens] * len(prompts)
     ctx, store, eng = build_engine(tiny_model, **kw)
     try:
+        if watch is not None:
+            watch(eng)
         for i, p in enumerate(prompts):
             req = Request(tenant=f"t{i}", tokens=list(p),
-                          max_new_tokens=new_tokens)
+                          max_new_tokens=new_tokens[i])
             if priorities is not None:
                 req.priority = priorities[i]
             eng.submit(req)
@@ -92,20 +98,106 @@ def seeded_prompts(cfg, seed, *, n=4, shared=20, suffix=4):
 # -- 1. paired byte-exactness through tier churn + CoW adoption ------------
 
 
-def test_batched_matches_interleaved_through_churn_and_cow(tiny_model):
+class SeatWatch:
+    """Watch an engine's tail stack. After every seating: the seats are the
+    batch, contiguous from 0, each session in the seat it says it has and
+    holding no tail of its own. Logs the tenants by seat a step, the widths
+    a stack was made at, and the seat programs dispatched in earnest (those
+    ``_new_tails`` runs on scratch to have them built are not counted);
+    ``built`` collects the programs' cache sizes around every new stack."""
+
+    PROGRAMS = ("_seat_write_jit", "_seat_move_jit", "_seat_read_jit")
+
+    def __init__(self, monkeypatch):
+        import oncilla_tpu.serving.engine as engine_mod
+
+        self.steps: list[list[str]] = []
+        self.widths: list[int] = []
+        self.calls = dict.fromkeys(self.PROGRAMS, 0)
+        self.built: list[tuple] = []
+        self._warming = False
+        self._real = {n: getattr(engine_mod, n) for n in self.PROGRAMS}
+        for name in self.PROGRAMS:
+            monkeypatch.setattr(engine_mod, name, self._counted(name))
+
+    def _counted(self, name):
+        def call(*args):
+            self.calls[name] += not self._warming
+            return self._real[name](*args)
+        return call
+
+    def cache_sizes(self) -> tuple:
+        return tuple(self._real[n]._cache_size() for n in self.PROGRAMS)
+
+    def __call__(self, eng):
+        seat_batch, new_tails = eng._seat_batch, eng._new_tails
+
+        def seated(batch):
+            seat_batch(batch)
+            seats = eng._seats
+            assert len(seats) == len(batch) and None not in seats
+            assert {id(s) for s in seats} == {id(s) for s in batch}
+            assert [s.seat for s in seats] == list(range(len(seats)))
+            assert all(s.tails is None for s in seats)
+            assert eng._tails[0].shape[1] >= len(seats)
+            self.steps.append([s.req.tenant for s in seats])
+
+        def made(b_pad):
+            before = self.cache_sizes()
+            self._warming = True
+            try:
+                new_tails(b_pad)
+            finally:
+                self._warming = False
+            self.widths.append(b_pad)
+            self.built.append((before, self.cache_sizes()))
+
+        eng._seat_batch, eng._new_tails = seated, made
+
+    def left_from_the_middle(self) -> bool:
+        """Some step lost the tenant of a seat that was not the last while
+        the tenant of the last seat stayed."""
+        return any(
+            t not in cur and prev[-1] in cur
+            for prev, cur in zip(self.steps, self.steps[1:])
+            for t in prev[:-1])
+
+
+@pytest.mark.parametrize("case", [
+    "churn-and-cow", "more-admitted-than-seats", "a-middle-seat-finishes"])
+def test_batched_matches_interleaved_through_churn_and_cow(
+        tiny_model, monkeypatch, case):
     cfg, _ = tiny_model
     prompts = seeded_prompts(cfg, 11, n=5, shared=20, suffix=4)
     # hot=2/warm=2 with 5 multi-page sessions forces continuous
     # demotion to the cold stand-in and promotion back (tier churn)
     # under BOTH engines; outputs must not notice.
     kw = dict(share=True, hot=2, warm=2, new_tokens=8, max_active=4)
+    if case == "more-admitted-than-seats":
+        kw.update(max_batch=2)
+    elif case == "a-middle-seat-finishes":
+        # t2 is through long before its neighbours.
+        kw.update(new_tokens=[8, 8, 2, 8, 8])
     outs_il, meta_il, _ = run_prompts(tiny_model, prompts,
                                       batched=False, **kw)
-    outs_b, meta_b, _ = run_prompts(tiny_model, prompts,
-                                    batched=True, **kw)
+    watch = SeatWatch(monkeypatch)
+    outs_b, meta_b, _ = run_prompts(tiny_model, prompts, batched=True,
+                                    watch=watch, **kw)
     assert outs_b == outs_il
     # Identical prompts emitted identical continuations.
     assert outs_b["t0"] == outs_b["t1"]
+    # Every step's rows were its seats, contiguous (SeatWatch), and the
+    # counters are those steps' seats.
+    assert len(watch.steps) == meta_b["batch"]["steps"]
+    tails = meta_b["tails"]
+    assert (tails["seats_kept"] + tails["seats_written"]
+            == meta_b["batch"]["size_sum"])
+    assert tails["seats_kept"] > 0
+    if case == "more-admitted-than-seats":
+        assert meta_b["batch"]["size_max"] == 2
+        assert meta_b["preempts"].get("slot", 0) > 0
+    elif case == "a-middle-seat-finishes":
+        assert watch.left_from_the_middle()
     # The fused path actually ran (not a degenerate batch of one).
     assert meta_b["batch"]["steps"] > 0
     assert meta_b["batch"]["size_max"] >= 2
@@ -650,3 +742,314 @@ def test_pool_write_program_compiles_once_a_capacity(tiny_model, monkeypatch):
     outs2, meta2, _ = workload()
     assert (step._cache_size(), write._cache_size()) == built
     assert outs2 == outs and meta2["pool"] == meta["pool"]
+
+
+# -- 8. the seated sessions' tails kept in one stack between ticks ----------
+
+
+def test_steady_decode_keeps_every_seat(tiny_model, monkeypatch):
+    import oncilla_tpu.serving.engine as engine_mod
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(83)
+    # Four sessions in step with each other: five prompt tokens through the
+    # fused step, then three pages' length of decode. Each crosses three
+    # page boundaries, all finish in one tick.
+    prompts = [rng.integers(1, cfg.vocab, 5).tolist() for _ in range(4)]
+    new = 3 * P
+    watch = SeatWatch(monkeypatch)
+    # What the engine itself asks of jax.numpy by name, after the first step.
+    asked = dict.fromkeys(("concatenate", "stack"), 0)
+
+    class CountingJnp:
+        def __getattr__(self, name):
+            real = getattr(engine_mod.jax.numpy, name)
+            if name not in asked:
+                return real
+
+            def call(*args, **kw):
+                asked[name] += 1
+                return real(*args, **kw)
+            return call
+
+    ctx, store, eng = build_engine(tiny_model, share=False, hot=32, warm=4,
+                                  max_active=4)
+    try:
+        watch(eng)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=new))
+        eng._tick()
+        first = eng.stats.snapshot()
+        assert first["batch"]["steps"] == 1
+        # The first seating: four seats placed in a new stack; nothing was
+        # written, since every tail was empty.
+        assert first["tails"] == {"seats_kept": 0, "seats_written": 4}
+        assert watch.widths == [4] and not any(watch.calls.values())
+        monkeypatch.setattr(engine_mod, "jnp", CountingJnp())
+        outs = {r.tenant: list(r.out_tokens) for r in eng.run()}
+        meta = eng.metrics_meta()
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    steps = 5 + new - 1
+    assert meta["batch"]["steps"] == steps
+    assert meta["batch"]["size_sum"] == 4 * steps
+    assert meta["tails"] == {"seats_kept": 4 * (steps - 1),
+                             "seats_written": 4}
+    # A page boundary is one read of the seat and nothing else: no tail is
+    # cut out for the next step, none glued back, and no session is given a
+    # tail of its own (SeatWatch: every seated session holds None).
+    assert watch.widths == [4]
+    assert watch.calls == {"_seat_write_jit": 0, "_seat_move_jit": 0,
+                           "_seat_read_jit": 4 * 3}
+    assert asked == {"concatenate": 0, "stack": 4 * 3}
+    monkeypatch.undo()
+    outs_il, _, _ = run_prompts(tiny_model, prompts, new_tokens=new,
+                                share=False, hot=32, warm=4, max_active=4,
+                                batched=False)
+    assert outs == outs_il
+
+
+def published_partial(eng, prompt):
+    """The bytes of the partial page published under a sub-page prompt,
+    as float32 (leaf, L, 1, KV, P, Hd)."""
+    ext = eng.prefix.child(None, tuple(prompt))
+    assert ext is not None and ext.fill == len(prompt) < P
+    raw = np.array(eng.store.read_page(ext.page), copy=True)
+    return raw.view(np.float32).reshape(eng.page_shape)
+
+
+def test_partial_from_a_used_seat_is_zeros_beyond_its_fill(tiny_model):
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(89)
+    first = rng.integers(1, cfg.vocab, 3).tolist()
+    second = rng.integers(1, cfg.vocab, 4).tolist()
+
+    def serve(prompts):
+        # One session at a time, so the second sits down where the first
+        # sat: a seat that held a whole shipped page and three tokens more.
+        ctx, store, eng = build_engine(tiny_model, share=True, hot=16,
+                                      warm=4, max_active=1)
+        try:
+            for i, p in enumerate(prompts):
+                eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                                   max_new_tokens=P + 1))
+            outs = {r.tenant: list(r.out_tokens) for r in eng.run()}
+            meta = eng.metrics_meta()
+            return outs, meta, published_partial(eng, second)
+        finally:
+            eng.close()
+            store.close()
+            ctx.tini()
+
+    outs_used, meta, used = serve([first, second])
+    outs_fresh, _, fresh = serve([second])
+    # one stack of one seat all the way; the second session wrote nothing
+    assert meta["tails"]["seats_written"] == 1
+    assert outs_used["t1"] == outs_fresh["t0"]
+    assert used[..., :len(second), :].any()
+    assert not used[..., len(second):, :].any()
+    assert used.tobytes() == fresh.tobytes()
+
+
+@pytest.mark.parametrize("case", [
+    "adopts-a-partial-mid-batch", "loses-its-seat-to-a-higher-class",
+    "unseated-with-an-empty-tail", "unseated-with-tokens-in-its-tail"])
+def test_a_seat_changing_hands_serves_the_interleaved_tokens(
+        tiny_model, monkeypatch, case):
+    from oncilla_tpu.qos.policy import PRIO_HIGH
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(97)
+    prompts = [rng.integers(1, cfg.vocab, n).tolist()
+               for n in (P + 3, 5, 6, 4)]
+    budgets = [2 * P, 2 * P, 2 * P, P]
+    adopts = case == "adopts-a-partial-mid-batch"
+    if adopts:
+        prompts[3] = list(prompts[0])   # t3 comes later with t0's prompt
+    late = Request(tenant="t3", tokens=list(prompts[3]),
+                   max_new_tokens=budgets[3])
+    if case == "loses-its-seat-to-a-higher-class":
+        late.priority = PRIO_HIGH
+    watch = SeatWatch(monkeypatch)
+    # Three or four sessions: one stack of four seats all the way.
+    ctx, store, eng = build_engine(tiny_model, share=True, hot=16, warm=4,
+                                  max_active=4, max_batch=4 if adopts else 3)
+    try:
+        watch(eng)
+        for i, p in enumerate(prompts[:3]):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=budgets[i]))
+        # t0 prefills a page, then all three ride the step for five tokens:
+        # t0's partial is published, every tail holds tokens.
+        for _ in range(5):
+            eng._tick()
+        t0, t1, t2 = eng.active
+        assert [s.seat for s in eng.active] == [0, 1, 2]
+        assert [s.tail_len for s in eng.active] == [5, 5, 5]
+        calls0 = dict(watch.calls)
+
+        def since(name):
+            return watch.calls[name] - calls0[name]
+
+        if case.startswith("unseated"):
+            # What _prefill_chunk does at its head to a session that comes
+            # back from the step (no schedule sends one back today).
+            want_empty = case == "unseated-with-an-empty-tail"
+            while (t0.tail_len == 0) != want_empty:
+                eng._tick()
+            eng._unseat(t0)
+            assert t0.seat is None and eng._seats[0] is None
+            assert all(t.shape == eng._tail_shape for t in t0.tails)
+            assert any(np.asarray(t).any() for t in t0.tails) != want_empty
+            calls0 = dict(watch.calls)
+            eng._tick()
+            # t0 sat down where it had sat; its tail was written only if
+            # it held anything, and nobody was moved for it
+            assert t0.seat == 0 and t0.tails is None
+            assert since("_seat_write_jit") == (not want_empty)
+            assert since("_seat_move_jit") == 0
+        eng.submit(late)
+        outs = {r.tenant: list(r.out_tokens) for r in eng.run()}
+        meta = eng.metrics_meta()
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    assert set(watch.widths) <= {4, 2, 1} and watch.widths[0] == 4
+    if adopts:
+        # t3 took t0's page and its partial, and sat down beside the three
+        # with 2 adopted tokens in its tail: one write, in the one stack.
+        assert meta["prefix"]["cow"] == 1
+        assert ["t0", "t1", "t2", "t3"] in watch.steps
+        assert watch.widths.count(4) == 1
+        assert outs["t3"] == outs["t0"][:P]
+    elif case == "loses-its-seat-to-a-higher-class":
+        # t3 took t2's seat while t2 lived on: t2's tail was read out, and
+        # written back when it sat down again.
+        assert meta["preempts"].get("slot", 0) > 0
+        assert ["t0", "t1", "t3"] in watch.steps
+    if not case.startswith("unseated"):
+        assert since("_seat_write_jit") >= 1
+    monkeypatch.undo()
+    want, _, _ = run_prompts(tiny_model, prompts, new_tokens=budgets,
+                             share=True, hot=16, warm=4, max_active=4,
+                             batched=False)
+    assert outs == want
+
+
+def test_seat_programs_are_built_with_the_stack_not_at_a_seat_change(
+        tiny_model, monkeypatch):
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(79)
+    prompts = [rng.integers(1, cfg.vocab, ln).tolist()
+               for ln in (5, 9, 17, 25, 30)]
+    watch = SeatWatch(monkeypatch)
+    # Batches of 5 down to 1 as the sessions finish, each from whatever seat
+    # it has: joins, moves and reads at every width.
+    outs, meta, _ = run_prompts(
+        tiny_model, prompts, new_tokens=[12, 4, 9, 6, 12], share=False,
+        hot=8, warm=8, max_active=5, batched=True, watch=watch)
+    assert sorted(set(watch.widths)) == [1, 2, 4, 8]
+    assert all(watch.calls.values())
+    # Whatever was built was built while a stack was made (for its width
+    # and the next one up), never between two of them ...
+    ends = [after for _, after in watch.built] + [watch.cache_sizes()]
+    assert all(before == ends[i]
+               for i, (before, _) in enumerate(watch.built[1:]))
+    assert ends[-1] == ends[-2]
+    # ... and a width that comes round again builds nothing.
+    seen = set()
+    for b_pad, (before, after) in zip(watch.widths, watch.built):
+        assert before == after or b_pad not in seen
+        seen.update((b_pad, 2 * b_pad))
+    assert (meta["tails"]["seats_kept"] + meta["tails"]["seats_written"]
+            == meta["batch"]["size_sum"])
+
+
+def test_a_step_that_raises_leaves_nobody_seated(tiny_model, monkeypatch):
+    import dataclasses
+
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(101)
+    prompts = [rng.integers(1, cfg.vocab, 5).tolist() for _ in range(3)]
+    ctx, store, eng = build_engine(tiny_model, share=False, hot=16, warm=4,
+                                  max_active=3)
+    try:
+        for i, p in enumerate(prompts):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=4))
+        eng._tick()
+        assert [s.seat for s in eng.active] == [0, 1, 2]
+        step = eng.family.step
+
+        def failing(*args):
+            raise RuntimeError("device lost")
+
+        # The stack is donated to the step: one that raises hands nothing
+        # back, so no session may point into it afterwards.
+        eng.family = dataclasses.replace(eng.family, step=failing)
+        with pytest.raises(RuntimeError, match="device lost"):
+            eng._tick()
+        assert eng._tails is None and eng._seats == []
+        assert all(s.seat is None for s in eng.active)
+        eng.family = dataclasses.replace(eng.family, step=step)
+    finally:
+        # abandons the three without reading a seat that is not there
+        eng.close()
+        store.close()
+        ctx.tini()
+    assert eng.active == []
+
+
+def test_a_session_that_is_over_stands_up_and_takes_no_tail(
+        tiny_model, monkeypatch):
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    rng = np.random.default_rng(103)
+    prompts = [rng.integers(1, cfg.vocab, 5).tolist() for _ in range(3)]
+    watch = SeatWatch(monkeypatch)
+    ctx, store, eng = build_engine(tiny_model, share=False, hot=16, warm=4,
+                                  max_active=3)
+    try:
+        watch(eng)
+        unseated, unseat = [], eng._unseat
+        eng._unseat = lambda s: (unseated.append(s.req.tenant), unseat(s))[1]
+        for i, (p, n) in enumerate(zip(prompts, (6, 2, 6))):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=n))
+        t0, t1, t2 = (eng._tick(), *eng.active)[1:]
+        while not t1.done:
+            eng._tick()
+        # t1 finished from the middle seat: vacated in the tick it finished
+        # in, before any later seating looks at it.
+        assert t1.seat is None and eng._seats == [t0, None, t2]
+        # t0 is abandoned with tokens in its tail: it stands up as well,
+        # and nobody reads the tail of a session that is over.
+        assert t0.tail_len and not t0.done
+        eng._finish(t0, abandon=True)
+        eng.active.remove(t0)
+        assert t0.seat is None and eng._seats == [None, None, t2]
+        outs = {r.tenant: list(r.out_tokens) for r in eng.run()}
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    # t2 went on alone, from seat 2 of the stack of four to a stack of one:
+    # the one tail that was read out for a session to take along
+    assert watch.steps[-1] == ["t2"] and watch.widths == [4, 1]
+    assert unseated == ["t2"]
+    monkeypatch.undo()
+    want, _, _ = run_prompts(tiny_model, prompts, new_tokens=[6, 2, 6],
+                             share=False, hot=16, warm=4, max_active=3,
+                             batched=False)
+    assert outs["t1"] == want["t1"] and outs["t2"] == want["t2"]
