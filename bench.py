@@ -680,10 +680,8 @@ def _run(out: dict, errors: dict, deadline: float) -> None:
 
     # Disaggregated serving (serving/): tiered paged KV + cross-tenant
     # prefix sharing over an in-process cluster, paired shared-vs-noshare
-    # cells + the owner-kill chaos leg, plus the batched-vs-interleaved
-    # paired sweep (detail.serving.batched_sweep: tokens/s at batch
-    # 1/2/4/8 on the same seeded workload). Tiny model: the full-width
-    # proof is chip_smoke.py.
+    # cells + the owner-kill chaos leg + the warm-boot leg. Tiny model:
+    # the full-width proof is chip_smoke.py.
     if budgeted("serving", 150):
         out["detail"]["serving"] = bench_serving(errors)
     mark("serving")
@@ -773,12 +771,12 @@ def bench_dcn(errors: dict) -> dict:
 
 def bench_serving(errors: dict) -> dict:
     """Serving workload harness (oncilla_tpu/serving/): paired
-    shared-vs-noshare cells, the owner-kill chaos leg, and the
-    batched-vs-interleaved tokens/s sweep (``batched_sweep`` key), in
-    this process on the tiny model. Its token gates are byte-for-byte:
-    run on a v5e (PR 21) the sweep raised ``batched@2 diverged from
-    interleaved output``, so this stage — and with it ``main()`` — fails
-    on the chip until the benchmark PR gives it a logit-level check."""
+    shared-vs-noshare cells, the owner-kill chaos leg and the warm-boot
+    leg, in this process on the tiny model. Its token gates are
+    byte-for-byte, which holds on the CPU in float32; on a v5e the last
+    bit of a logit moves with the batch shape (PR 21), so there this
+    stage — and with it ``main()`` — can fail: the chip's check is the
+    benchmark's logit-level one."""
     try:
         from oncilla_tpu.serving.__main__ import run_bench
 

@@ -537,7 +537,7 @@ def serving(sz: Sizing, cfg, params, on_tpu: bool, ledger: KernelLedger,
         ctx, store, engine = _build_engine(
             cfg, params, page_tokens=sz.page_tokens, hot=sz.hot,
             warm=sz.warm, cold_client=cold, share=True, name="chip-smoke",
-            prefetch_workers=2, max_active=sz.max_batch, batched=True,
+            prefetch_workers=2, max_active=sz.max_batch,
             max_batch=sz.max_batch, keep_logits=True,
         )
         try:

@@ -45,8 +45,7 @@ class PagedFamily:
     page, slot)`` writes one page into a pool row in place.
     ``assignments_per_token(cfg)``, for a family whose programs hand an
     ``aux`` back, is how many (layer, expert) pairs one token is routed
-    to. ``token``, the batch-of-1 step of the interleaved loop, is
-    optional."""
+    to."""
 
     n_leaves: int
     leaf_dims: object
@@ -54,7 +53,6 @@ class PagedFamily:
     page: object
     write_row: object
     assignments_per_token: object = None
-    token: object = None
 
     def leaf_shape(self, cfg, page_tokens: int, batch: int = 1) -> tuple:
         kv, hd = self.leaf_dims(cfg)

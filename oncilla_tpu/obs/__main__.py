@@ -187,7 +187,7 @@ def _serving_rows(rank: int, st: dict) -> list[list[str]]:
              f"/{tp.get('remote', 0)}"),
             _fmt_bytes(pref.get("shared_bytes", 0)),
             f"{pref.get('hits', 0)}/{pref.get('cow', 0)}",
-            # mean fused-batch size / max (0/0 = interleaved engine)
+            # mean fused-batch size / max (0/0 = no step taken yet)
             f"{mean:.1f}/{batch.get('size_max', 0)}",
         ])
     return out

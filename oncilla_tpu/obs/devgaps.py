@@ -140,7 +140,8 @@ def gaps(path: str, chip: int = 0) -> dict:
         raise ValueError(f"{path}: no operations of /device:TPU:{chip}, or no "
                          "/host:CPU plane")
     # The scheduler's thread: the one with the most ticks (with no tick at
-    # all, as under the interleaved engine, the most ocm:* annotations).
+    # all, as in a trace of the memory plane alone, the most ocm:*
+    # annotations).
     thread, events = max(host, key=lambda t: (
         sum(n == "ocm:tick" for _, _, n in t[1]),
         sum(n.startswith("ocm:") for _, _, n in t[1])))

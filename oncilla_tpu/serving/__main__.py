@@ -10,7 +10,8 @@ whole scenario end to end on a 3-daemon ``local_cluster`` with
   WITH it — outputs must be identical across the cells (sharing is a
   storage optimization, never a result change), the shared cell must
   show prefix hits, at least one copy-on-write adoption, a hit ratio no
-  worse than the unshared cell, and strictly fewer remote bytes;
+  worse than the unshared cell, strictly fewer remote bytes, and fused
+  steps that seated more than one session;
 - **chaos leg**: the remote owner of the engine's cold pages is killed
   mid-decode under a seeded schedule; decode output must be byte-exact
   vs a chaos-free reference run, TWICE with the identical fault
@@ -77,7 +78,6 @@ def _cold_client(cl, rank: int = 0, mux: bool = False):
 def _build_engine(cfg, params, *, page_tokens: int, hot: int, warm: int,
                   cold_client, share: bool, name: str,
                   prefetch_workers: int, max_active: int = 4,
-                  batched: bool | None = None,
                   max_batch: int | None = None,
                   frozen_backend=None, keep_logits: bool = False):
     import oncilla_tpu as ocm
@@ -102,8 +102,7 @@ def _build_engine(cfg, params, *, page_tokens: int, hot: int, warm: int,
     engine = ServingEngine(
         params, cfg, store, prefix, page_tokens=page_tokens,
         max_active=max_active, prefetch_workers=prefetch_workers,
-        name=name, batched=batched, max_batch=max_batch,
-        keep_logits=keep_logits,
+        name=name, max_batch=max_batch, keep_logits=keep_logits,
     )
     return ctx, store, engine
 
@@ -111,8 +110,7 @@ def _build_engine(cfg, params, *, page_tokens: int, hot: int, warm: int,
 def _run_cell(cl, cfg, params, *, share: bool, prompts, new_tokens: int,
               page_tokens: int, hot: int, warm: int,
               prefetch_workers: int, name: str, mux: bool = False,
-              max_active: int = 4, batched: bool | None = None,
-              max_batch: int | None = None, frozen_backend=None) -> dict:
+              frozen_backend=None) -> dict:
     """One measured cell: a tenant fleet decoded to completion through
     one engine. Returns outputs + the engine's metric snapshot."""
     from oncilla_tpu.serving.engine import Request
@@ -121,9 +119,7 @@ def _run_cell(cl, cfg, params, *, share: bool, prompts, new_tokens: int,
     ctx, store, engine = _build_engine(
         cfg, params, page_tokens=page_tokens, hot=hot, warm=warm,
         cold_client=cold, share=share, name=name,
-        prefetch_workers=prefetch_workers, max_active=max_active,
-        batched=batched, max_batch=max_batch,
-        frozen_backend=frozen_backend,
+        prefetch_workers=prefetch_workers, frozen_backend=frozen_backend,
     )
     try:
         for t, toks in enumerate(prompts):
@@ -237,116 +233,6 @@ def run_pair(seed: int, *, tenants: int = 6, shared_tokens: int = 28,
         "remote_bytes_shared": remote[0],
         "remote_bytes_noshare": remote[1],
         "drained_ranks": drained,
-    }
-
-
-def run_batched_pair(seed: int, *, tenants: int = 4,
-                     shared_tokens: int = 20, suffix_tokens: int = 4,
-                     new_tokens: int = 10, page_tokens: int = 8,
-                     hot: int = 3, warm: int = 4,
-                     prefetch_workers: int = 2) -> dict:
-    """The batched-vs-interleaved correctness gate on one fresh cluster:
-    the same seeded tenant fleet decodes once through the interleaved
-    batch-of-1 loop and once through the fused batched tick loop —
-    per-session outputs must be byte-identical (batching is a dispatch
-    optimization, never a result change)."""
-    from oncilla_tpu.runtime.cluster import local_cluster
-
-    cfg, params = _tiny_model()
-    prompts = _prompts(seed, tenants, shared_tokens, suffix_tokens,
-                       cfg.vocab)
-    with local_cluster(3, config=_cluster_cfg()) as cl:
-        inter = _run_cell(
-            cl, cfg, params, share=True, prompts=prompts,
-            new_tokens=new_tokens, page_tokens=page_tokens, hot=hot,
-            warm=warm, prefetch_workers=prefetch_workers,
-            name="serve-interleaved", batched=False,
-        )
-        bat = _run_cell(
-            cl, cfg, params, share=True, prompts=prompts,
-            new_tokens=new_tokens, page_tokens=page_tokens, hot=hot,
-            warm=warm, prefetch_workers=prefetch_workers,
-            name="serve-batched", batched=True,
-        )
-        drained = _assert_drained(cl)
-    if bat["outputs"] != inter["outputs"]:
-        diffs = [t for t in inter["outputs"]
-                 if bat["outputs"].get(t) != inter["outputs"][t]]
-        raise AssertionError(
-            f"batched decode diverged from interleaved for {diffs}"
-        )
-    if bat["batch"]["steps"] == 0:
-        raise AssertionError("batched cell never took a fused step")
-    return {
-        "seed": seed,
-        "tenants": tenants,
-        "cells": {"interleaved": inter, "batched": bat},
-        "batch": bat["batch"],
-        "preempts": bat["preempts"],
-        "drained_ranks": drained,
-    }
-
-
-def run_batched_sweep(seed: int, *, tenants: int = 8,
-                      shared_tokens: int = 20, suffix_tokens: int = 5,
-                      new_tokens: int = 24, page_tokens: int = 8,
-                      hot: int = 32, warm: int = 16,
-                      sizes: tuple = (1, 2, 4, 8)) -> dict:
-    """Batched-vs-interleaved throughput sweep (no cluster — the cold
-    tier runs its local stand-in so the axis isolates dispatch cost, not
-    DCN): the same seeded fleet decodes through the interleaved loop and
-    through the batched engine at max_batch in ``sizes``; every cell
-    must produce identical outputs. Each config runs twice and reports
-    the second (jit-warm) cell — the first run pays the shape-bucket
-    compiles. The hot tier is sized ABOVE the fleet's working set: a
-    fused step needs every seated session resident at once, so an
-    undersized hot tier measures tier thrash, not the dispatch
-    amortization this sweep isolates (the churn axis is the smoke's
-    paired cell, which runs both engines under the same tight caps)."""
-    cfg, params = _tiny_model()
-    prompts = _prompts(seed, tenants, shared_tokens, suffix_tokens,
-                       cfg.vocab)
-
-    def cell(name, batched, max_batch=None):
-        out = None
-        for _ in range(2):  # second run is jit-warm (process-level cache)
-            out = _run_cell(
-                None, cfg, params, share=True, prompts=prompts,
-                new_tokens=new_tokens, page_tokens=page_tokens,
-                hot=hot, warm=warm, prefetch_workers=0, name=name,
-                max_active=max(sizes), batched=batched,
-                max_batch=max_batch,
-            )
-        return out
-
-    inter = cell("sweep-interleaved", batched=False)
-    cells = {"interleaved": inter}
-    for bs in sizes:
-        c = cell(f"sweep-b{bs}", batched=True, max_batch=bs)
-        if c["outputs"] != inter["outputs"]:
-            raise AssertionError(
-                f"batched@{bs} diverged from interleaved output"
-            )
-        cells[f"batched_{bs}"] = c
-    for c in cells.values():
-        c.pop("outputs")
-    return {
-        "seed": seed,
-        "tenants": tenants,
-        "new_tokens": new_tokens,
-        "page_tokens": page_tokens,
-        "sizes": list(sizes),
-        "cells": cells,
-        "tok_s": {k: c["tok_s"] for k, c in cells.items()},
-        "speedup_vs_interleaved": {
-            k: round(c["tok_s"] / inter["tok_s"], 3)
-            for k, c in cells.items() if k != "interleaved"
-            and inter["tok_s"]
-        },
-        "note": (
-            "tiny model: the axis shows dispatch-overhead "
-            "amortization, not MXU batching; jit-warm second runs"
-        ),
     }
 
 
@@ -513,10 +399,10 @@ def run_warmboot(seed: int, *, tenants: int = 3, shared_tokens: int = 20,
       restart without the persist/ subsystem would pay;
     - **warm**: post-restart, the seeded dir — the engine re-publishes
       the persisted extents at boot, so prefill rides pages computed by
-      the previous incarnation. A discarded jit-warmup pass runs first
-      (the batched-sweep discipline): resuming prefill mid-prefix is a
-      shape the cold arms never compile, and TTFT must measure skipped
-      prefill work, not one XLA compile. For the same reason the hot
+      the previous incarnation. A discarded jit-warmup pass runs
+      first: resuming prefill mid-prefix is a shape the cold arms never
+      compile, and TTFT must measure skipped prefill work, not one XLA
+      compile. For the same reason the hot
       tier is sized above the restored working set — a restored page
       that lands in the COLD tier pays a loopback-DCN fetch per hit,
       which on a tiny CPU model dwarfs the prefill it skipped; the
@@ -675,16 +561,9 @@ def smoke(seed: int, mux: bool | None = None) -> int:
               f"({sh['moves']})")
         return 1
 
-    print("serving smoke: batched-vs-interleaved paired cell ...")
-    bp = run_batched_pair(seed, tenants=4, shared_tokens=20,
-                          suffix_tokens=4, new_tokens=10, hot=3, warm=4)
-    bb = bp["batch"]
-    print(f"  batched: {bb['steps']} fused steps, max batch "
-          f"{bb['size_max']}, {bb['prefill_chunks']} prefill chunks, "
-          f"preempts {bp['preempts']}; outputs byte-identical")
-    if bb["size_max"] < 2:
+    if sh["batch"]["size_max"] < 2:
         print("serving smoke: FAIL — fused steps never batched more "
-              f"than one session (max {bb['size_max']})")
+              f"than one session (max {sh['batch']['size_max']})")
         return 1
 
     if mux is None:
@@ -730,8 +609,7 @@ def smoke(seed: int, mux: bool | None = None) -> int:
     return 0
 
 
-def run_bench(seed: int = 1234, *, chaos: bool = True,
-              batched: bool = True) -> dict:
+def run_bench(seed: int = 1234, *, chaos: bool = True) -> dict:
     """The measured cells for ``bench.py`` ``detail.serving``."""
     from oncilla_tpu.obs import audit as obs_audit
 
@@ -742,8 +620,6 @@ def run_bench(seed: int = 1234, *, chaos: bool = True,
                    new_tokens=16, hot=4, warm=6)
     for cell in out["cells"].values():
         cell.pop("outputs")  # token ids are not a metric
-    if batched:
-        out["batched_sweep"] = run_batched_sweep(seed)
     if chaos:
         with obs_audit.recorded("serving-bench-chaos") as rec:
             out["chaos"] = run_chaos(seed, new_tokens=16, hot=2, warm=2)
@@ -777,21 +653,12 @@ def main(argv=None) -> int:
                     help="with --bench: skip the chaos leg")
     ap.add_argument("--no-mux", action="store_true",
                     help="with --smoke: skip the OCM_MUX/AsyncOcm leg")
-    ap.add_argument("--batched", action="store_true",
-                    help="run ONLY the batched-vs-interleaved throughput "
-                         "sweep (batch 1/2/4/8), one JSON dict on stdout")
-    ap.add_argument("--no-batched", action="store_true",
-                    help="with --bench: skip the batched sweep axis")
     ap.add_argument("--seed", type=int, default=1234)
     args = ap.parse_args(argv)
     if args.smoke:
         return smoke(args.seed, mux=False if args.no_mux else None)
-    if args.batched:
-        print(json.dumps(run_batched_sweep(args.seed)))
-        return 0
     if args.bench:
-        print(json.dumps(run_bench(args.seed, chaos=not args.no_chaos,
-                                   batched=not args.no_batched)))
+        print(json.dumps(run_bench(args.seed, chaos=not args.no_chaos)))
         return 0
     ap.print_help()
     return 2

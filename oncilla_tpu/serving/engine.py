@@ -1,38 +1,48 @@
 """Continuous-batching decode engine over the tiered KV page store.
 
 The compute half of the serving scenario: sessions (one per tenant
-request) interleave page-granular decode turns, admissions join between
-turns (the continuous-batching shape — the batch composition changes
+request) are admitted as seats free up (the batch composition changes
 continuously, it never drains), and every session's KV context lives as
 pages in the :class:`~oncilla_tpu.serving.tiers.TieredPageStore`, shared
 across tenants through the
 :class:`~oncilla_tpu.serving.prefix.PrefixCache`.
 
-Key mechanics:
+There is one scheduler, the tick (:meth:`ServingEngine._tick`):
 
-- **Prefill with prefix reuse** — a new request first walks the prefix
-  trie; matched extents are acquired (refcounted) and their KV is never
-  recomputed. The unmatched remainder is teacher-forced through
-  ``paged_decode_step_jit``, and every completed prompt-only page is
-  *published* back into the trie (content-hash dedup) so the next
-  tenant hits it. A matched **partial** tail extent is adopted by
+- **Admission** — queued requests take the places ``max_active`` leaves,
+  higher QoS classes first.
+- **Prefill with prefix reuse** — at every page boundary a session walks
+  the prefix trie; matched extents are acquired (refcounted) and their
+  KV is never recomputed. Of the unmatched remainder, each session with a
+  whole page of prompt left teacher-forces ONE page a tick through the
+  family's page program (chunked prefill), and the sub-page end rides
+  the fused step a token a tick. Every completed prompt-only page is
+  *published* back into the trie (content-hash dedup) so the next tenant
+  hits it. A matched **partial** tail extent is adopted by
   copy-on-write: the shared page stays byte-exact for everyone else,
   the adopter continues into its private clone.
-- **Prefetch-on-schedule** — while session *i* decodes, the engine
-  issues fetches for session *i+1*'s non-resident pages, threaded
-  (default) or as AsyncOcm coroutines on the PR-13 mux loop
-  (``OCM_MUX=1``). When the prefetch loses the race the wait is
-  recorded as page-fault stall time (``prefetch_stall`` journal event +
-  the stall counters).
+- **One fused step** — up to ``max_batch`` of the remaining sessions are
+  seated, by class, and ONE dispatch of the family's step advances each
+  by a token. The step's page pool and the seated sessions' tails are
+  device state the engine keeps between ticks (:meth:`_batch_pool`,
+  :meth:`_seat_batch`).
+- **Residency and prefetch** — every tick issues fetches for the
+  non-resident pages of all admitted sessions, threaded (default) or as
+  AsyncOcm coroutines on the PR-13 mux loop (``OCM_MUX=1``); a session
+  whose fetch is still in flight gives its seat up for the tick. A chunk
+  promotes its session's pages one by one, a step the whole batch's
+  under one watermark sweep. When the prefetch loses the race the wait
+  is recorded as page-fault stall time (``prefetch_stall`` journal event
+  + the stall counters).
 - **Determinism** — greedy decode (temperature 0) over exact page
   round-trips (a page is cast to ``store_dtype``, at least as wide as
   the model dtype, and back): a page's bytes never depend on the tier it
   lives in or on how a chaos schedule reshuffles the remote owners
   mid-decode. On the CPU backend in float32 the emitted token ids are
-  then a pure function of (params, prompt), which the chaos and pairing
-  gates assert byte for byte. On an accelerator the matmul tiling — and
-  with it the last bit of a logit — may change with the batch shape, so
-  there the check is logit-level agreement with the unpaged forward
+  then a pure function of (params, prompt), which the chaos gates assert
+  byte for byte. On an accelerator the matmul tiling — and with it the
+  last bit of a logit — may change with the batch shape, so there the
+  check is logit-level agreement with the unpaged forward
   (``chip_smoke.py``, via ``keep_logits``).
 """
 
@@ -51,7 +61,6 @@ from oncilla_tpu.core.hbm import from_bytes, to_bytes
 from oncilla_tpu.models import (
     paged_decode_batch_step_jit,
     paged_decode_page_jit,
-    paged_decode_step_jit,
     paged_pool_write_row_jit,
 )
 from oncilla_tpu.models.kv_paging import PagedFamily
@@ -93,19 +102,13 @@ def _dense_page(params, tokens_page, meta, ctx, tails, cfg):
     return logits, (tail_k, tail_v), None
 
 
-def _dense_token(params, token, meta, ctx, tails, cfg):
-    logits, tail_k, tail_v = paged_decode_step_jit(
-        params, token, meta, *ctx, *tails, cfg)
-    return logits, (tail_k, tail_v), None
-
-
 def _dense_write_row(pool, page, slot):
     return paged_pool_write_row_jit(*pool, *page, slot)
 
 
 DENSE_FAMILY = PagedFamily(
     n_leaves=2, leaf_dims=_dense_leaf_dims, step=_dense_step,
-    page=_dense_page, write_row=_dense_write_row, token=_dense_token,
+    page=_dense_page, write_row=_dense_write_row,
 )
 
 
@@ -148,7 +151,7 @@ def family_of(cfg) -> PagedFamily:
 @dataclass
 class Request:
     """One tenant's generation request (greedy decode: deterministic).
-    ``priority`` is a PR-6 QoS class (PRIO_LOW/NORMAL/HIGH): the batched
+    ``priority`` is a PR-6 QoS class (PRIO_LOW/NORMAL/HIGH): the
     scheduler admits and seats higher classes first under contention."""
 
     tenant: str
@@ -261,7 +264,7 @@ class Prefetcher:
 
     def pending(self, page_id: int) -> bool:
         """True while a submitted fetch for ``page_id`` has not landed —
-        the batched scheduler's yield-on-cold probe (a session whose
+        the scheduler's yield-on-cold probe (a session whose
         fetches are still in flight gives up its slot instead of making
         the whole batch wait)."""
         fut = self._futures.get(page_id)
@@ -357,7 +360,9 @@ class _Session:
 
 
 class ServingEngine:
-    """Session-interleaved continuous batching over one page store."""
+    """Tick-driven continuous batching over one page store: a tick
+    admits by class, prefills one page a session that has one left, and
+    advances every seated session a token in one fused step."""
 
     def __init__(
         self,
@@ -372,7 +377,7 @@ class ServingEngine:
         name: str = "engine",
         share_partials: bool = True,
         step_budget_ms: int | None = None,
-        batched: bool | None = None,
+        batched: bool = True,
         max_batch: int | None = None,
         keep_logits: bool = False,
     ):
@@ -389,26 +394,23 @@ class ServingEngine:
         if prefetch_workers is None:
             prefetch_workers = int(os.environ.get("OCM_SERVE_PREFETCH", "2"))
         self.prefetcher = Prefetcher(store, prefetch_workers, self.stats)
-        # Per-decode-step time budget (resilience/timebudget.py,
-        # OCM_STEP_BUDGET_MS): bounds how long one session turn may sit
-        # on a straggling PREFETCH — past the budget the wait is
-        # abandoned and the page faults synchronously with the wait
-        # accounted as stall, so one slow cold fetch degrades to
-        # stall-accounting instead of wedging the whole interleave
-        # schedule. 0/None = the unbudgeted pre-existing behavior.
+        # Per-tick time budget (resilience/timebudget.py,
+        # OCM_STEP_BUDGET_MS): bounds how long one tick may sit on a
+        # straggling PREFETCH — past the budget the wait is abandoned and
+        # the page faults synchronously with the wait accounted as stall,
+        # so one slow cold fetch degrades to stall-accounting instead of
+        # wedging the whole batch. 0/None = no budget: a wait is bounded
+        # by _obtain's 120 s alone.
         if step_budget_ms is None:
             step_budget_ms = int(
                 os.environ.get("OCM_STEP_BUDGET_MS", "0") or 0
             )
         self.step_budget_ms = max(0, int(step_budget_ms))
         self._step_budget = None
-        # True-batched decode (default): every runnable session advances
-        # one token per tick in ONE fused paged_decode_batch_step_jit
-        # dispatch. OCM_SERVING_BATCH=0 keeps the session-interleaved
-        # batch-of-1 loop (the paired byte-exact gate's reference).
-        if batched is None:
-            batched = os.environ.get("OCM_SERVING_BATCH", "1") != "0"
-        self.batched = bool(batched)
+        if not batched:
+            raise ValueError(
+                "batched=False: the session-interleaved loop is gone "
+                "(PR 31); batch-of-one is the fused step at max_batch=1")
         if max_batch is None:
             max_batch = int(os.environ.get("OCM_SERVING_MAX_BATCH", "8"))
         self.max_batch = max(1, int(max_batch))
@@ -416,9 +418,6 @@ class ServingEngine:
         # The model family of cfg: the leaves of a page and the programs
         # dispatched over them.
         self.family = family_of(cfg)
-        if not self.batched and self.family.token is None:
-            raise ValueError("this model family has no batch-of-1 step: "
-                             "it is served batched only")
         # The fused step's page pool, kept on the device between ticks
         # (see _batch_pool): one array of rows (capacity, L, KV, P, Hd) a
         # leaf, the row of every (page_id, version) it holds, least
@@ -483,37 +482,13 @@ class ServingEngine:
         req._submit_t = time.perf_counter()
         self.queue.append(req)
 
-    def run(self, turn_tokens: int | None = None) -> list[SessionResult]:
-        """Drive to completion: admit, interleave page-granular turns
-        with prefetch-on-schedule, collect results. With ``batched``
-        the loop is tick-based instead (:meth:`_run_batched`): one fused
-        jit step per tick over every admitted session."""
-        if self.batched:
-            return self._run_batched()
-        turn = turn_tokens or self.page_tokens
+    def run(self) -> list[SessionResult]:
+        """Drive to completion, a tick at a time (:meth:`_tick`): per
+        tick — priority-ordered admission, one chunked-prefill slice per
+        bulk-prefilling session, then ONE dispatch of the family's fused
+        step advancing every seated session by one token."""
         while self.queue or self.active:
-            while self.queue and len(self.active) < self.max_active:
-                self.active.append(self._admit(self.queue.pop(0)))
-            order = list(self.active)
-            for i, sess in enumerate(order):
-                if sess.done:
-                    continue
-                # Prefetch-on-schedule: the NEXT session's cold pages
-                # fetch while this one computes.
-                for j in range(i + 1, len(order)):
-                    if not order[j].done:
-                        self._prefetch_for(order[j])
-                        break
-                if self.step_budget_ms:
-                    from oncilla_tpu.resilience import timebudget
-
-                    self._step_budget = timebudget.Budget.from_ms(
-                        self.step_budget_ms
-                    )
-                self._turn(sess, turn)
-                if sess.done:
-                    self._finish(sess)
-            self.active = [s for s in self.active if not s.done]
+            self._tick()
         done, self.results = self.results, []
         return done
 
@@ -548,7 +523,7 @@ class ServingEngine:
         # Prefix matching is INCREMENTAL (:meth:`_match_more`, probed at
         # every page boundary), not an admission-time lookup: sessions
         # admitted simultaneously still dedup against pages a sibling
-        # publishes one turn later.
+        # publishes one tick later.
         sess = _Session(req, self._tail_shape, self.family.n_leaves,
                         self.cfg.dtype)
         sess.admit_t = time.perf_counter()
@@ -684,7 +659,7 @@ class ServingEngine:
             already = fut.done()
             t0 = time.perf_counter()
             # A straggling prefetch is waited on at most the remaining
-            # step budget (unbudgeted: the old 120 s backstop): past it
+            # step budget (unbudgeted: 120 s): past it
             # the wait degrades to a synchronous fault below — pure
             # stall accounting, never a wedged decode step. The
             # abandoned future recycles its buffer when it finally
@@ -750,64 +725,6 @@ class ServingEngine:
 
     # -- decode -----------------------------------------------------------
 
-    def _turn(self, sess: _Session, budget: int) -> None:
-        self._match_more(sess)
-        self._ensure_resident(sess)
-        ctx = self._context(sess)
-        for _ in range(budget):
-            if sess.prompt_consumed < len(sess.prompt):
-                tok = sess.prompt[sess.prompt_consumed]
-                sess.prompt_consumed += 1
-                prefill = True
-                self.stats.note_tokens(1, phase="prefill")
-            else:
-                tok = sess.out[-1] if sess.out else sess.prompt[-1]
-                prefill = False
-            meta = jnp.asarray([sess.pos, sess.tail_len, 0], jnp.int32)
-            logits, sess.tails, _ = self.family.token(
-                self.params, jnp.asarray([tok], jnp.int32), meta,
-                ctx, sess.tails, self.cfg,
-            )
-            sess.pos += 1
-            sess.tail_len += 1
-            sess.page_toks.append(int(tok))
-            emit = (not prefill
-                    or sess.prompt_consumed == len(sess.prompt))
-            if emit:
-                sess.out.append(int(jnp.argmax(logits[0])))
-                if self.keep_logits:
-                    sess.logits.append(np.asarray(logits[0]))
-                self._note_first_token(sess)
-                if not prefill:
-                    self.stats.note_tokens(1)
-            if sess.tail_len == self.page_tokens:
-                self._ship(sess)
-                # Page boundary: a sibling may have published the next
-                # chunk of this prompt since the last probe.
-                self._match_more(sess)
-                self._ensure_resident(sess)
-                ctx = self._context(sess)
-            elif (self.share_partials and prefill
-                  and sess.prompt_consumed == len(sess.prompt)):
-                self._publish_partial(sess)
-            if len(sess.out) > sess.req.max_new_tokens:
-                raise AssertionError("overran max_new_tokens")
-            if len(sess.out) == sess.req.max_new_tokens:
-                sess.done = True
-                return
-
-    # -- batched decode ----------------------------------------------------
-
-    def _run_batched(self) -> list[SessionResult]:
-        """Tick-driven continuous batching: per tick — priority-ordered
-        admission, one chunked-prefill slice per bulk-prefilling
-        session, then ONE fused :func:`paged_decode_batch_step_jit`
-        dispatch advancing every seated session by one token."""
-        while self.queue or self.active:
-            self._tick()
-        done, self.results = self.results, []
-        return done
-
     def _tick(self) -> None:
         """One scheduler tick under one root span. Its six children
         (``tick.admit``, ``tick.match``, ``serve_prefill_chunk``,
@@ -842,10 +759,9 @@ class ServingEngine:
                     if prefetch_on:
                         self._prefetch_for(sess)
             # Chunked prefill: a long prompt admits one page-sized slice
-            # per tick (one paged_decode_page_jit dispatch) instead of
-            # streaming its tokens through the shared batch — the batch
-            # never stalls behind a prompt, and the slice is bitwise the
-            # token-wise path.
+            # per tick (one dispatch of the family's page program)
+            # instead of streaming its tokens through the shared batch —
+            # the batch never stalls behind a prompt.
             chunked = False
             for sess in self.active:
                 if not self._bulk_prefill(sess):
@@ -1108,9 +1024,9 @@ class ServingEngine:
 
     def _batch_step(self, batch: list[_Session]) -> None:
         """ONE fused jit dispatch advancing every seated session by one
-        token, then per-session scatter of logits/tails/bookkeeping —
-        bitwise the interleaved per-session step. Runs under the
-        ``serve_batch_step`` span; its ``step.*`` children cover it."""
+        token, then per-session scatter of logits/tails/bookkeeping.
+        Runs under the ``serve_batch_step`` span; its ``step.*`` children
+        cover it."""
         span = GLOBAL_TRACER.span
         P = self.page_tokens
         cfg = self.cfg

@@ -158,14 +158,13 @@ JAX_PLATFORMS=cpu python -m oncilla_tpu.persist --smoke || fail=1
 echo "== serving smoke =="
 # Flagship serving workload (serving/): paired shared-vs-noshare decode
 # cells over a 3-daemon cluster (outputs must be byte-identical, sharing
-# must show prefix hits + a CoW adoption + strictly fewer remote bytes),
-# the batched-vs-interleaved leg (one fused jit step per tick + chunked
-# prefill: outputs byte-identical to the interleaved engine, fused
-# batches actually formed), the AsyncOcm prefetch leg under OCM_MUX,
-# and the chaos leg — kill the cold-page owner mid-decode with
-# OCM_REPLICAS=2, decode byte-exact through failover, twice with
-# identical interleavings, wrapped in the flight-recorder invariant
-# audit; alloctrace ledger drained on every surviving rank. CPU-only.
+# must show prefix hits + a CoW adoption + strictly fewer remote bytes,
+# and its fused steps must have seated more than one session), the
+# AsyncOcm prefetch leg under OCM_MUX, the chaos leg — kill the
+# cold-page owner mid-decode with OCM_REPLICAS=2, decode byte-exact
+# through failover, twice with identical fault schedules, wrapped in the
+# flight-recorder invariant audit — and the warm-boot leg; alloctrace
+# ledger drained on every surviving rank. CPU-only.
 JAX_PLATFORMS=cpu python -m oncilla_tpu.serving --smoke || fail=1
 
 echo "== obs audit smoke =="

@@ -284,7 +284,7 @@ def serve(cfg, params, prompts, new_tokens, *, hot, warm, share,
                         PrefixCache(store, P) if share else None,
                         page_tokens=P, max_active=max_active,
                         max_batch=max_batch, prefetch_workers=0,
-                        name="latent", batched=True, keep_logits=True)
+                        name="latent", keep_logits=True)
     try:
         if watch is not None:
             watch(eng)
@@ -372,21 +372,3 @@ def test_dense_family_counts_no_experts():
     tiny_cfg = lm.LatentMoeConfig.tiny()
     assert lm.PAGED_FAMILY.leaf_shape(tiny_cfg, 8, batch=3) == (
         tiny_cfg.n_layers, 3, 1, 8, tiny_cfg.latent_width)
-
-
-def test_interleaved_loop_is_refused_for_a_family_without_a_token_step(tiny):
-    import oncilla_tpu as ocm
-    from oncilla_tpu.serving.engine import ServingEngine
-    from oncilla_tpu.serving.tiers import TieredPageStore
-
-    cfg, params, _, _ = tiny
-    ctx = ocm.Ocm(config=ocm.OcmConfig(host_arena_bytes=1 << 20,
-                                       device_arena_bytes=1 << 20))
-    store = TieredPageStore(ctx, ServingEngine.page_nbytes(cfg, P),
-                            hot_capacity=2, warm_capacity=2)
-    try:
-        with pytest.raises(ValueError, match="batched only"):
-            ServingEngine(params, cfg, store, page_tokens=P, batched=False)
-    finally:
-        store.close()
-        ctx.tini()
