@@ -17,9 +17,10 @@ Measures single-chip tokens/s for a Llama-style decoder in four modes:
   device->host->device round trip is the single-chip analogue of the DCN
   arm.
 - ``device_fused``: OCM-paged like ``device`` but ONE dispatch per page
-  (``BucketedPagedDecoder.step_page`` — a lax.scan over the page), the
-  per-page serving-loop shape that closes most of the dispatch gap to
-  ``fused`` while keeping the data plane on the path.
+  (``BucketedPagedDecoder.step_page``: the page's teacher-forced tokens
+  go through each layer together, the weights read once a page), the
+  prefill shape of the serving loop, with the data plane on the path. It
+  is no decode loop any more and may exceed ``fused``.
 
 The bucketed decoder keeps shapes static per page (O(tokens/page)
 compilations), which is what makes this measurable on real hardware: the
@@ -125,8 +126,8 @@ def bench_paged(params, cfg, tokens, ctx, kind, page_tokens) -> float:
 
 def bench_paged_fused(params, cfg, tokens, ctx, kind, page_tokens) -> float:
     """Tokens/s with OCM-paged KV and ONE dispatch per page
-    (BucketedPagedDecoder.step_page): the per-page serving loop — page
-    decode scans on-chip, page put/get through the data plane between
+    (BucketedPagedDecoder.step_page): a page's tokens go through each
+    layer together on-chip, page put/get through the data plane between
     dispatches (still refetch=True, so both directions are measured)."""
     n_pages = tokens.shape[1] // page_tokens
 
@@ -156,7 +157,7 @@ def run_bench(
     # executable leaves the chip in a state where subsequent per-step
     # dispatch loses 2-3x throughput (same stickiness bench.py documents
     # for the DMA loops) — measured: plain reads 196 tok/s before fused,
-    # 73 after. device_fused (one scan per page) sits just before fused.
+    # 73 after. device_fused (one program per page) sits just before fused.
     modes: tuple = ("plain", "device", "host", "device_fused", "fused"),
     config: str = "small",
 ) -> dict:

@@ -292,9 +292,11 @@ def paged_decode_step_jit(
 def _paged_token(params, token, pos, tail_len, ctx_start, k_ctx, v_ctx,
                  tail_k, tail_v, cfg, lp_fn, mlp_of):
     """One paged-decode token: the traced body shared by the per-token jit
-    (:func:`paged_decode_step_jit`) and the page-fused scan
-    (:func:`paged_decode_page_jit`). All of pos/tail_len/ctx_start are
-    traced scalars."""
+    (:func:`paged_decode_step_jit`) and the sampling scan
+    (:func:`paged_generate_page_jit`: sampling is sequential by nature).
+    :func:`paged_decode_page_jit`, whose tokens are all known, applies the
+    same mask rule to a whole page at once. All of pos/tail_len/ctx_start
+    are traced scalars."""
     from oncilla_tpu.models import llama
 
     x = params["embed"][token][:, None, :].astype(jnp.dtype(cfg.dtype))
@@ -509,38 +511,67 @@ def paged_decode_page_jit(
     layer_params_fn=None,
     mlp_of=None,
 ):
-    """One full page of paged decode as ONE compiled program: a
-    ``lax.scan`` over the page's P tokens with the tail buffers threaded
-    (and donated) through the carry — the per-page-dispatch formulation a
-    TPU serving loop wants (the per-token loop pays one host dispatch per
-    token; this pays one per page, the same trade as
-    :func:`llama.decode_loop` at page granularity, with the paged OCM
-    context still on the attention path).
+    """One full page of paged decode as ONE compiled program that takes
+    the page's P tokens through each layer TOGETHER, so every weight is
+    read once a page (a per-token loop streams the whole model once a
+    token): one :func:`llama.block` a layer over ``(B, P, D)``, attention
+    over the paged OCM context and, causally, the page's own keys, and the
+    LM head once at the end.
 
-    Starts from an empty tail (tail_len 0); token j of the page decodes
-    at absolute position pos0 + j with tail_len j. Returns
-    (logits (B, P, vocab), new_tail_k, new_tail_v) — the caller ships the
-    now-full tail as a page.
+    Starts from an empty tail (whatever the donated buffers hold is
+    overwritten); token j of the page sits at absolute position pos0 + j
+    and attends to every context key and to the page's keys 0..j, band-
+    limited to its last ``cfg.window`` positions where that is set: the
+    rule :func:`_paged_token` applies a token at a time. The page's fresh
+    K/V are rounded through the tail's dtype before they are attended to,
+    so a later step that reads the shipped page sees the values this
+    program saw. Returns (logits (B, P, vocab), new_tail_k, new_tail_v),
+    the tails full: the caller ships them as a page.
+
+    ``mlp_of`` sees the page's P tokens at once: under a capacity-dropping
+    expert dispatch (:mod:`oncilla_tpu.models.moe`) the page's tokens are
+    routed together and the capacity is reckoned over all of them, as in
+    training, not a token at a time.
     """
     from oncilla_tpu.models import llama
 
     lp_fn = layer_params_fn or llama.layer_params
     pos0, ctx_start = meta[0], meta[1]
     P = tail_k.shape[3]
-
-    def body(carry, inp):
-        tail_k, tail_v = carry
-        tok, j = inp
-        logits, tail_k, tail_v = _paged_token(
-            params, tok, pos0 + j, j, ctx_start, k_ctx, v_ctx,
-            tail_k, tail_v, cfg, lp_fn, mlp_of,
-        )
-        return (tail_k, tail_v), logits
-
-    (tail_k, tail_v), logits = jax.lax.scan(
-        body, (tail_k, tail_v), (tokens_page.T, jnp.arange(P))
+    C = k_ctx.shape[3]
+    x = params["embed"][tokens_page].astype(jnp.dtype(cfg.dtype))
+    positions = pos0 + jnp.arange(P)
+    # Keys = [paged context (all valid) | the page's own (causal)].
+    valid = jnp.concatenate(
+        [jnp.ones((P, C), bool), jnp.tril(jnp.ones((P, P), bool))], axis=1
     )
-    return logits.transpose(1, 0, 2), tail_k, tail_v
+    if cfg.window is not None:
+        # Global key positions, as in _paged_token: the context starts at
+        # ctx_start, the page at pos0; each query keeps its last `window`.
+        gk = jnp.concatenate([ctx_start + jnp.arange(C), positions])
+        valid &= gk[None, :] > (positions - cfg.window)[:, None]
+
+    for i in range(cfg.n_layers):
+        state = {}
+
+        def attend(q, kn, vn, i=i, state=state):
+            tk, tv = kn.astype(tail_k.dtype), vn.astype(tail_v.dtype)
+            state["tk"], state["tv"] = tk, tv
+            k_all = jnp.concatenate(
+                [k_ctx[i].astype(q.dtype), tk.astype(q.dtype)], axis=2
+            )
+            v_all = jnp.concatenate(
+                [v_ctx[i].astype(q.dtype), tv.astype(q.dtype)], axis=2
+            )
+            return llama.grouped_attention(q, k_all, v_all, valid)
+
+        lp = lp_fn(params, i)
+        x = llama.block(cfg, x, lp, positions, attend,
+                        mlp=mlp_of(lp) if mlp_of else None)
+        tail_k = tail_k.at[i].set(state["tk"])
+        tail_v = tail_v.at[i].set(state["tv"])
+
+    return llama.final_logits(params, x, cfg), tail_k, tail_v
 
 
 @partial(
@@ -564,9 +595,11 @@ def paged_generate_page_jit(
 ):
     """One page of *autoregressive* paged decode as ONE compiled program:
     each scan tick consumes the previous tick's sample (greedy at
-    ``temperature`` 0, else softmax sampling) — the sampled flavor of
-    :func:`paged_decode_page_jit` and the per-page serving loop proper
-    (the paged counterpart of :func:`llama.generate`'s sampling scan).
+    ``temperature`` 0, else softmax sampling), so unlike the teacher-forced
+    :func:`paged_decode_page_jit` it is a ``lax.scan`` of
+    :func:`_paged_token` that streams the weights once a token: the
+    per-page serving loop proper (the paged counterpart of
+    :func:`llama.generate`'s sampling scan).
 
     Returns (sampled ids (B, P), new_tail_k, new_tail_v). The tail holds
     K/V of every *consumed* token this page (token0 + the first P-1
@@ -658,12 +691,13 @@ class BucketedPagedDecoder:
         return logits
 
     def step_page(self, tokens_page: jax.Array) -> jax.Array:
-        """Decode one FULL page of teacher-forced tokens in a single
-        compiled dispatch (:func:`paged_decode_page_jit`), then ship the
-        page — the per-page-dispatch serving loop. Requires an empty tail
-        (step/step_page calls must align to page boundaries) and
-        ``tokens_page.shape[-1] == page_tokens``. Returns per-token logits
-        (B, P, vocab)."""
+        """Take one FULL page of teacher-forced tokens through every layer
+        together in a single compiled dispatch
+        (:func:`paged_decode_page_jit`: the weights are read once a page),
+        then ship the page: a prompt's prefill, a page at a time. Requires
+        an empty tail (step/step_page calls must align to page boundaries)
+        and ``tokens_page.shape[-1] == page_tokens``. Returns per-token
+        logits (B, P, vocab)."""
         if self._tail_len != 0:
             raise ValueError(
                 f"step_page needs an empty tail (tail_len="

@@ -32,8 +32,9 @@ Serving: :data:`PAGED_FAMILY` is what
 ``cfg.paged_family``: a page of one latent leaf ``(L, 1, 1, P, W)``, the
 fused batch step over a page pool and block table
 (:func:`latent_decode_batch_step_jit`) and the page program
-(:func:`latent_decode_page_jit`), which takes a page's tokens through each
-layer together.
+(:func:`latent_decode_page_jit`), which, as the dense family's
+(``kv_paging.paged_decode_page_jit``) does, takes a page's tokens through
+each layer together.
 """
 
 from __future__ import annotations

@@ -517,10 +517,10 @@ def test_dots_remat_and_blocked_ce_train_step(rng):
 
 
 def test_step_page_matches_per_token(rng):
-    """The page-fused decode (one scan dispatch per page) produces the
-    same logits as the per-token bucketed decoder, with and without a
-    sliding window, and interleaves with per-token steps at page
-    boundaries."""
+    """The page program (one dispatch per page, its tokens through each
+    layer together) produces the same logits as the per-token bucketed
+    decoder, with and without a sliding window, and interleaves with
+    per-token steps at page boundaries."""
     from dataclasses import replace
 
     import oncilla_tpu as ocm_pkg
