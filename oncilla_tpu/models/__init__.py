@@ -40,6 +40,9 @@ _EXPORTS = {
     "PagedFamily": "kv_paging",
     # latent_moe: latent attention, routed experts, hyper-connections.
     "LatentMoeConfig": "latent_moe",
+    # kda_latent: delta-rule linear attention beside latent attention,
+    # group-limited experts of which the chip holds a share.
+    "KdaLatentConfig": "kda_latent",
 }
 
 __all__ = sorted(_EXPORTS)

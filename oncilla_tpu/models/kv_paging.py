@@ -45,7 +45,17 @@ class PagedFamily:
     page, slot)`` writes one page into a pool row in place.
     ``assignments_per_token(cfg)``, for a family whose programs hand an
     ``aux`` back, is how many (layer, expert) pairs one token is routed
-    to."""
+    to.
+
+    Two things are the family's to say, and most say neither.
+    ``cached_layers(cfg)`` is how many layers keep pages (the ``L`` of a
+    leaf; absent: ``cfg.n_layers``). ``carry_leaves(cfg, batch)`` gives a
+    family whose layers keep a recurrent state of fixed size beside (or
+    in place of) pages its **carry**: the ``(shape, dtype)`` of each leaf,
+    the batch on axis 1 as in a tail. Such a family's ``step`` and
+    ``page`` take the carry as one more argument after ``cfg``, donated,
+    and return it as a fourth value; the engine keeps the seated
+    sessions' carries in one stack, as it keeps their tails."""
 
     n_leaves: int
     leaf_dims: object
@@ -53,10 +63,14 @@ class PagedFamily:
     page: object
     write_row: object
     assignments_per_token: object = None
+    cached_layers: object = None
+    carry_leaves: object = None
 
     def leaf_shape(self, cfg, page_tokens: int, batch: int = 1) -> tuple:
         kv, hd = self.leaf_dims(cfg)
-        return (cfg.n_layers, batch, kv, page_tokens, hd)
+        layers = (self.cached_layers(cfg) if self.cached_layers
+                  else cfg.n_layers)
+        return (layers, batch, kv, page_tokens, hd)
 
 
 @dataclass

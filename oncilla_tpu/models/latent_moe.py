@@ -16,7 +16,11 @@ Three mechanisms, each under its own ``jax.named_scope``:
   real token, each read once (a dynamic slice of the stacked expert
   weights) and applied to every row with that row's weight (zero where it
   was not chosen). Padded batch rows are routed nowhere. The layer hands
-  back how many distinct experts it touched.
+  back how many distinct experts it touched. Two things a config may say
+  and ``LatentMoeConfig`` leaves at "no": a group limit on the choice
+  (``n_group``, ``topk_group``) and a held range (``experts_held``), the
+  experts whose weights this chip has: the layer routes over all of them
+  and computes its own experts' part (``models/kda_latent.py`` uses both).
 - ``mhc`` — manifold-constrained hyper-connections: the residual state is
   ``hc_mult`` streams, every sub-layer reads a sigmoid-gated mix of them
   and writes back through a Sinkhorn-normalised stream-mixing matrix. The
@@ -71,6 +75,8 @@ class LatentMoeConfig:
     n_routed_experts: int = 64
     n_shared_experts: int = 1
     num_experts_per_tok: int = 4
+    n_group: int = 1
+    topk_group: int = 1
     routed_scaling_factor: float = 2.0
     hc_mult: int = 4
     hc_sinkhorn_iters: int = 20
@@ -146,6 +152,11 @@ class LatentMoeConfig:
     def latent_width(self) -> int:
         """Values a position a layer holds in the cache."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def experts_held(self) -> tuple:
+        """(first, count) of the routed experts this chip holds: all."""
+        return (0, self.n_routed_experts)
 
     @property
     def paged_family(self) -> PagedFamily:
@@ -309,6 +320,8 @@ def yarn_inv_freq(cfg: LatentMoeConfig) -> np.ndarray:
     dr, theta = cfg.qk_rope_head_dim, cfg.rope_theta
     exponent = np.arange(0, dr, 2, dtype=np.float64) / dr
     extra = 1.0 / theta ** exponent
+    if cfg.rope_factor <= 1:
+        return extra.astype(np.float32)     # no scaling: plain rotary
     inter = extra / cfg.rope_factor
 
     def correction_dim(rotations):
@@ -346,14 +359,18 @@ def _dot(a, b, spec: str, dtype):
 
 def latent_qkv(h, params, layer: int, positions, cfg: LatentMoeConfig):
     """h: (T, D) float32 -> q_nope (T, H, dn), rotated q_rope (T, H, dr)
-    and this position's cache entry (T, W): normed latent | rotated key."""
+    and this position's cache entry (T, W): normed latent | rotated key.
+    A config whose ``q_lora_rank`` is null has one query matrix ``wq``."""
     dt = jnp.dtype(cfg.dtype)
     H, dn, R = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     eps = cfg.rms_norm_eps
-    cq = rmsnorm(_dot(h, params["wq_a"][layer], "td,dr->tr", dt),
-                 params["q_norm"][layer], eps)
-    q = _dot(cq, params["wq_b"][layer], "tr,rh->th", dt).reshape(
-        h.shape[0], H, -1)
+    if cfg.q_lora_rank:
+        cq = rmsnorm(_dot(h, params["wq_a"][layer], "td,dr->tr", dt),
+                     params["q_norm"][layer], eps)
+        q = _dot(cq, params["wq_b"][layer], "tr,rh->th", dt)
+    else:
+        q = _dot(h, params["wq"][layer], "td,dh->th", dt)
+    q = q.reshape(h.shape[0], H, -1)
     kva = _dot(h, params["wkv_a"][layer], "td,dw->tw", dt)
     ckv = rmsnorm(kva[:, :R], params["kv_norm"][layer], eps)
     k_rope = _rotate(kva[:, R:], positions, cfg)
@@ -414,16 +431,32 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
     return _dot(act, w_down, "tf,fd->td", dt)
 
 
-def route(h, params, j: int, real, cfg: LatentMoeConfig):
+def _group_limit(pick, n_group: int, topk_group: int):
+    """pick: (T, E) scores the choice is made on. A group's score is the
+    sum of its best two; outside the best ``topk_group`` of the
+    ``n_group`` groups every score becomes -inf."""
+    T, E = pick.shape
+    g = pick.reshape(T, n_group, E // n_group)
+    score = jax.lax.top_k(g, 2)[0].sum(-1)
+    _, keep = jax.lax.top_k(score, topk_group)
+    kept = jax.nn.one_hot(keep, n_group, dtype=bool).any(axis=1)
+    return jnp.where(kept[:, :, None], g, -jnp.inf).reshape(T, E)
+
+
+def route(h, params, j: int, real, cfg):
     """h: (T, D) float32; real: (T,) bool. Returns the chosen experts
     (T, k), the (T, E) weight of every expert for every row (zero where it
     was not chosen, and everywhere in a row that is padding) and which
-    experts (E,) a real row chose."""
+    experts (E,) a real row chose. With ``cfg.n_group`` over 1 the choice
+    is group-limited (:func:`_group_limit`)."""
     E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     s = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", h, params["w_router"][j],
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + params["e_bias"][j], k)
+    pick = s + params["e_bias"][j]
+    if cfg.n_group > 1:
+        pick = _group_limit(pick, cfg.n_group, cfg.topk_group)
+    _, idx = jax.lax.top_k(pick, k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     w = cfg.routed_scaling_factor * chosen / chosen.sum(-1, keepdims=True)
     sel = jax.nn.one_hot(idx, E, dtype=bool) & real[:, None, None]
@@ -431,14 +464,22 @@ def route(h, params, j: int, real, cfg: LatentMoeConfig):
     return idx, weights, sel.any(axis=(0, 1))
 
 
-def expert_ffn(h, params, j: int, real, cfg: LatentMoeConfig):
+def expert_ffn(h, params, j: int, real, cfg):
     """Expert layer ``j`` (of the layers that have experts). Dropless: the
     loop runs once for each DISTINCT expert that a real row chose, reads
     that expert's three matrices once, and adds its output to every row
-    under that row's weight. Returns (y (T, D) float32, distinct experts
-    touched () int32, chosen experts (T, k))."""
+    under that row's weight. The layer routes over all
+    ``cfg.n_routed_experts`` and computes the part of the result its own
+    experts give: ``cfg.experts_held`` is the (first, count) range whose
+    weights the stacked leaves hold; a row whose experts all live
+    elsewhere gets the shared expert alone. Returns (y (T, D) float32,
+    distinct held experts touched () int32, chosen experts (T, k))."""
     dt = jnp.dtype(cfg.dtype)
     idx, weights, hit = route(h, params, j, real, cfg)
+    first, held = cfg.experts_held
+    if (first, held) != (0, cfg.n_routed_experts):
+        weights = weights[:, first:first + held]
+        hit = hit[first:first + held]
     n_hit = hit.sum().astype(jnp.int32)
     order = jnp.argsort(~hit, stable=True)      # touched experts first
     x = h.astype(dt)
@@ -462,7 +503,7 @@ def expert_ffn(h, params, j: int, real, cfg: LatentMoeConfig):
     return y, n_hit, idx
 
 
-def _ffn(h, params, layer: int, real, cfg: LatentMoeConfig):
+def _ffn(h, params, layer: int, real, cfg):
     """The FFN sub-layer of ``layer``: (y, experts touched, chosen | None)."""
     K = cfg.first_k_dense_replace
     if layer < K:

@@ -116,7 +116,8 @@ DENSE_FAMILY = PagedFamily(
 # page each (L, b_pad, KV, P, Hd), that the engine owns between ticks
 # (:meth:`ServingEngine._seat_batch`). A seat changes hands through these
 # three programs, all leaves in one dispatch, the seat a traced index: one
-# executable a stack shape serves every seat.
+# executable a stack shape serves every seat. A family with a carry has a
+# second such stack, of its carry's leaves, through the same programs.
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -140,6 +141,15 @@ def _seat_read_jit(stack: tuple, seat: jax.Array) -> tuple:
     """The tail in ``seat``, (L, 1, KV, P, Hd) a leaf, as arrays of its own."""
     return tuple(jax.lax.dynamic_slice_in_dim(s, seat, 1, axis=1)
                  for s in stack)
+
+
+def _zero_carry(family: PagedFamily, cfg, batch: int) -> tuple | None:
+    """Fresh zeros of a family's carry for ``batch`` seats (the programs
+    donate them), or None for a family that keeps none."""
+    if family.carry_leaves is None:
+        return None
+    return tuple(jnp.zeros(shape, dt)
+                 for shape, dt in family.carry_leaves(cfg, batch))
 
 
 def family_of(cfg) -> PagedFamily:
@@ -310,7 +320,8 @@ class _Entry:
 
 
 class _Session:
-    def __init__(self, req: Request, tail_shape: tuple, n_leaves: int, dtype):
+    def __init__(self, req: Request, tail_shape: tuple, n_leaves: int, dtype,
+                 carry: tuple | None = None):
         self.req = req
         self.prompt = [int(t) for t in req.tokens]
         self.entries: list[_Entry] = []
@@ -345,6 +356,10 @@ class _Session:
         #: The page being filled, one array a leaf of the family's page;
         #: None while a seat holds it.
         self.tails: tuple | None = None
+        #: A family with a carry: the session's recurrent state, one array
+        #: a leaf, zeros before its first token; None while a seat holds
+        #: it.
+        self.carry = carry
         self.reset_tail()
 
     def reset_tail(self) -> None:
@@ -418,6 +433,12 @@ class ServingEngine:
         # The model family of cfg: the leaves of a page and the programs
         # dispatched over them.
         self.family = family_of(cfg)
+        self._has_carry = self.family.carry_leaves is not None
+        if self._has_carry and prefix is not None:
+            raise ValueError(
+                "prefix_cache with a family that keeps a recurrent carry: "
+                "a prefix page is adoptable only with the carry at its "
+                "boundary, and no extent holds one yet (ROADMAP.md, Queue 2)")
         # The fused step's page pool, kept on the device between ticks
         # (see _batch_pool): one array of rows (capacity, L, KV, P, Hd) a
         # leaf, the row of every (page_id, version) it holds, least
@@ -433,6 +454,9 @@ class ServingEngine:
         # is None until the next step closes it), and the stack widths
         # whose seat programs have already run.
         self._tails: tuple | None = None
+        # A family with a carry: the seated sessions' carries, one stack a
+        # leaf with the seat on axis 1, beside the tails and seat for seat.
+        self._carry: tuple | None = None
         self._seats: list[_Session | None] = []
         self._seat_ready: set[int] = set()
         self._tab_cache: tuple = (None, None)
@@ -498,6 +522,7 @@ class ServingEngine:
         self.active = []
         self._pool = None
         self._tails = None
+        self._carry = None
         self._seats = []
         # Persist the prefix trie into the frozen tier (if one backs
         # the store) BEFORE the prefetcher drains: the pages are still
@@ -524,8 +549,9 @@ class ServingEngine:
         # every page boundary), not an admission-time lookup: sessions
         # admitted simultaneously still dedup against pages a sibling
         # publishes one tick later.
-        sess = _Session(req, self._tail_shape, self.family.n_leaves,
-                        self.cfg.dtype)
+        sess = _Session(
+            req, self._tail_shape, self.family.n_leaves, self.cfg.dtype,
+            _zero_carry(self.family, self.cfg, 1))
         sess.admit_t = time.perf_counter()
         self._note_pages_done(sess)
         return sess
@@ -834,10 +860,13 @@ class ServingEngine:
             pc = sess.prompt_consumed
             chunk = sess.prompt[pc:pc + P]
             meta = jnp.asarray([sess.pos, 0], jnp.int32)
-            logits, sess.tails, touched = self.family.page(
-                self.params, jnp.asarray([chunk], jnp.int32), meta,
-                ctx, sess.tails, self.cfg,
-            )
+            args = (self.params, jnp.asarray([chunk], jnp.int32), meta,
+                    ctx, sess.tails, self.cfg)
+            if self._has_carry:
+                logits, sess.tails, touched, sess.carry = self.family.page(
+                    *args, sess.carry)
+            else:
+                logits, sess.tails, touched = self.family.page(*args)
         sess.pos += P
         sess.tail_len = P
         sess.page_toks = list(chunk)
@@ -1035,8 +1064,11 @@ class ServingEngine:
                 self._ensure_resident_batch(batch)
             with span("step.args"):
                 # Rows in seat order from here on: row b is seat b.
-                self._seat_batch(batch)
+                joined, moved = self._seat_batch(batch)
                 batch = list(self._seats)
+            if self._has_carry:
+                with span("step.carry"):
+                    self._seat_carries(joined, moved)
             with span("step.pool"):
                 *pool, table, tables = self._batch_pool(batch)
             with span("step.args"):
@@ -1066,16 +1098,21 @@ class ServingEngine:
                 # The stack is donated; the step hands it back with every
                 # row's token in place. A step that raises hands nothing
                 # back: nobody holds a seat of a stack that is gone.
-                try:
-                    logits, self._tails, touched = self.family.step(
-                        self.params, jnp.asarray(toks, jnp.int32),
+                args = (self.params, jnp.asarray(toks, jnp.int32),
                         jnp.asarray(metas, jnp.int32), len(batch), pool,
-                        self._tab_cache[1], self._tails, cfg,
-                    )
+                        self._tab_cache[1], self._tails, cfg)
+                try:
+                    if self._has_carry:
+                        logits, self._tails, touched, self._carry = (
+                            self.family.step(*args, self._carry))
+                    else:
+                        logits, self._tails, touched = self.family.step(
+                            *args)
                 except BaseException:
                     for sess in batch:
                         sess.seat = None
                     self._tails = None
+                    self._carry = None
                     self._seats = []
                     raise
             with span("step.sync"):
@@ -1129,7 +1166,7 @@ class ServingEngine:
                     if len(sess.out) == sess.req.max_new_tokens:
                         sess.done = True
 
-    def _seat_batch(self, batch: list[_Session]) -> None:
+    def _seat_batch(self, batch: list[_Session]) -> tuple[list, list]:
         """Seat ``batch`` for one fused step: afterwards ``self._seats``
         is the batch, seat by seat, and ``self._tails`` holds every
         session's tail in its seat. The stack is device state that
@@ -1142,7 +1179,9 @@ class ServingEngine:
         enters with tail_len 0 as zeros), and the step counts rows
         [0, n_real) as sessions, so a seat vacated in the middle is taken
         by a joiner or by the last seat, moved. A change of ``b_pad``
-        unseats everybody into a fresh stack (:meth:`_new_tails`)."""
+        unseats everybody into a fresh stack (:meth:`_new_tails`).
+        Returns the sessions that sat down and the (from, to) seats that
+        moved, for :meth:`_seat_carries`."""
         b_pad = _pow2(len(batch))
         rebuilt = self._tails is None or self._tails[0].shape[1] != b_pad
         staying = set() if rebuilt else {id(sess) for sess in batch}
@@ -1154,9 +1193,11 @@ class ServingEngine:
         seats = self._seats
         holes = [b for b, sess in enumerate(seats) if sess is None]
         written = 0
+        joined, moved = [], []
         for sess in batch:
             if sess.seat is not None:
                 continue                # in its seat since the last step
+            joined.append(sess)
             if holes:
                 sess.seat = holes.pop(0)
                 seats[sess.seat] = sess
@@ -1178,11 +1219,30 @@ class ServingEngine:
                 self._tails = _seat_move_jit(
                     self._tails, np.int32(last.seat), np.int32(hole))
                 written += 1
+            moved.append((last.seat, hole))
             last.seat = hole
             seats[hole] = last
         if rebuilt:
             written = len(batch)        # every seat was placed anew
         self.stats.note_tails(kept=len(batch) - written, written=written)
+        return joined, moved
+
+    def _seat_carries(self, joined: list[_Session], moved: list) -> None:
+        """A family with a carry: bring the carry stack to the seating
+        :meth:`_seat_batch` just made. A joiner's carry is written into
+        its seat whatever it holds (a seat keeps what its last session
+        left there), a moved seat's carry moves with it, and a session
+        that kept its seat costs nothing."""
+        for sess in joined:
+            self._carry = _seat_write_jit(
+                self._carry, sess.carry, np.int32(sess.seat))
+            sess.carry = None
+        for src, dst in moved:
+            self._carry = _seat_move_jit(
+                self._carry, np.int32(src), np.int32(dst))
+        written = len(joined) + len(moved)
+        self.stats.note_carry(kept=len(self._seats) - written,
+                              written=written)
 
     def _unseat(self, sess: _Session) -> None:
         """Vacate the seat of a session that lives on. It takes its tail
@@ -1190,6 +1250,8 @@ class ServingEngine:
         empty. (One that is over just stands up: :meth:`_finish`.)"""
         seat, sess.seat = sess.seat, None
         self._seats[seat] = None
+        if self._has_carry:
+            sess.carry = _seat_read_jit(self._carry, np.int32(seat))
         if sess.tail_len:
             sess.tails = _seat_read_jit(self._tails, np.int32(seat))
         else:
@@ -1209,14 +1271,20 @@ class ServingEngine:
             shape = fam.leaf_shape(self.cfg, self.page_tokens, b)
             return tuple(jnp.zeros(shape, dt) for _ in range(fam.n_leaves))
 
-        self._tails = None
+        def carry_zeros(b: int) -> tuple:
+            return _zero_carry(fam, self.cfg, b)
+
+        stacks = (zeros, carry_zeros) if self._has_carry else (zeros,)
+        self._tails = self._carry = None
         for b in {b_pad, min(2 * b_pad, _pow2(self.max_batch))}:
             if b not in self._seat_ready:
                 at = np.int32(0)
-                scratch = _seat_write_jit(zeros(b), zeros(1), at)
-                _seat_read_jit(_seat_move_jit(scratch, at, at), at)
+                for make in stacks:
+                    scratch = _seat_write_jit(make(b), make(1), at)
+                    _seat_read_jit(_seat_move_jit(scratch, at, at), at)
                 self._seat_ready.add(b)
         self._tails = zeros(b_pad)
+        self._carry = carry_zeros(b_pad)
         self._seats = []
 
     def _tail(self, sess: _Session) -> tuple:

@@ -93,6 +93,10 @@ class ServingStats:
         # written, moved or placed in a new stack before the step.
         self.tails_seats_kept = 0
         self.tails_seats_written = 0
+        # The same for the carry stack of a family that keeps a recurrent
+        # state a session (ServingEngine._seat_carries).
+        self.carry_seats_kept = 0
+        self.carry_seats_written = 0
         # What a step's experts cost (a family with routed experts; the
         # programs hand the counts back): distinct (layer, expert) pairs
         # that received a real token, over fused steps and over prefill
@@ -244,6 +248,14 @@ class ServingStats:
             self.tails_seats_kept += kept
             self.tails_seats_written += written
 
+    def note_carry(self, kept: int = 0, written: int = 0) -> None:
+        """One fused step's seats of the carry stack: ``kept`` carries
+        were in their seat already, ``written`` were written in (a
+        joiner's) or moved with their seat."""
+        with self._mu:
+            self.carry_seats_kept += kept
+            self.carry_seats_written += written
+
     def note_moe_step(self, expert_rows: int, assignments: int) -> None:
         """One fused step of a family with experts: ``expert_rows``
         distinct (layer, expert) pairs were read for ``assignments``
@@ -333,6 +345,10 @@ class ServingStats:
                 "tails": {
                     "seats_kept": self.tails_seats_kept,
                     "seats_written": self.tails_seats_written,
+                },
+                "carry": {
+                    "seats_kept": self.carry_seats_kept,
+                    "seats_written": self.carry_seats_written,
                 },
                 "moe": {
                     "step_expert_rows": self.moe_step_expert_rows,

@@ -314,11 +314,12 @@ def test_engine_serves_it_through_hot_warm_cold_and_back(tiny):
 
         def seated(batch):
             stack = eng._tails
-            seat_batch(batch)
+            changes = seat_batch(batch)
             assert len(eng._tails) == 1     # one leaf: the latent
             seatings.append((stack is not None
                              and stack[0].shape == eng._tails[0].shape,
                              [s.req.tenant for s in eng._seats]))
+            return changes
 
         eng._seat_batch = seated
 

@@ -260,7 +260,7 @@ class SeatWatch:
         seat_batch, new_tails = eng._seat_batch, eng._new_tails
 
         def seated(batch):
-            seat_batch(batch)
+            changes = seat_batch(batch)
             seats = eng._seats
             assert len(seats) == len(batch) and None not in seats
             assert {id(s) for s in seats} == {id(s) for s in batch}
@@ -268,6 +268,7 @@ class SeatWatch:
             assert all(s.tails is None for s in seats)
             assert eng._tails[0].shape[1] >= len(seats)
             self.steps.append([s.req.tenant for s in seats])
+            return changes
 
         def made(b_pad):
             before = self.cache_sizes()
