@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import os
 import time
+import weakref
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -305,9 +306,15 @@ class Prefetcher:
             self._aocm = None
 
 
-@dataclass
+@dataclass(eq=False)
 class _Entry:
-    """One page of a session's context."""
+    """One page of a context, with its decode-ready arrays. A private page
+    has one entry, in its session's list; a shared extent's page has one
+    entry too, listed by every live session whose context holds the page
+    (:meth:`ServingEngine._entry_of`), so the arrays are built once a
+    ``(page, version)`` and every holder reads the same ones. Sharing them
+    is safe: device arrays are immutable and no program donates a page's
+    arrays (``_context`` concatenates, the pool's row write copies)."""
 
     page: Page
     extent: SharedExtent | None = None
@@ -460,6 +467,12 @@ class ServingEngine:
         self._seats: list[_Session | None] = []
         self._seat_ready: set[int] = set()
         self._tab_cache: tuple = (None, None)
+        # The entry of every shared extent's page some live session's
+        # context holds, by page_id (see _Entry). The sessions' lists are
+        # the references: when the last holder finishes, the entry and its
+        # arrays go, as a finished session's private entries do, and a
+        # dead extent, which nothing reclaims, keeps no arrays.
+        self._shared = weakref.WeakValueDictionary()  # page_id -> _Entry
         self.queue: list[Request] = []
         self.active: list[_Session] = []
         self.results: list[SessionResult] = []
@@ -585,7 +598,7 @@ class ServingEngine:
                     return
                 self.prefix.acquire(ext)
                 sess.shared_refs.append(ext)
-                sess.entries.append(_Entry(page=ext.page, extent=ext))
+                sess.entries.append(self._entry_of(ext))
                 sess.chain_parent = ext
                 sess.pos += P
                 sess.prompt_consumed += P
@@ -635,6 +648,25 @@ class ServingEngine:
         return (e.arrays is not None and e.version == e.page.version
                 and e.page.tier == Tier.HOT)
 
+    def _entry_of(self, ext: SharedExtent) -> _Entry:
+        """The one entry of a shared extent's page, for a session that
+        takes the page into its context: the entry its live holders list,
+        arrays and all, or a fresh one whose arrays the next residency
+        pass builds once, for everyone who takes the page after."""
+        entry = self._shared.get(ext.page.page_id)
+        if entry is None:
+            entry = _Entry(page=ext.page, extent=ext)
+            self._shared[ext.page.page_id] = entry
+        else:
+            self.stats.note_arrays(shared=1)
+        return entry
+
+    def _rebuild(self, e: _Entry, data: np.ndarray) -> None:
+        """Build the entry's arrays from its page's bytes in the store."""
+        e.arrays = self._unpack(data)
+        e.version = e.page.version
+        self.stats.note_arrays(rebuilt=1)
+
     def _prefetch_for(self, sess: _Session) -> None:
         for e in sess.entries:
             if (not e.pending_fill and not self._resident(e)
@@ -653,16 +685,14 @@ class ServingEngine:
                 self.store.touch(e.page)
                 continue
             if hot:
-                # Decode arrays lost (session cold start / page moved
-                # back up): rebuild from the fast tier — no stall.
-                data = np.array(self.store.read_page(e.page), copy=True)
-                e.arrays = self._unpack(data)
-                e.version = e.page.version
+                # No holder has built the arrays at this version (an extent
+                # nobody alive held / a page moved back up or rewritten):
+                # rebuild from the fast tier — no stall.
+                self._rebuild(e, self.store.read_page(e.page))
                 continue
             data = self._obtain(sess, e.page)
             self.store.promote(e.page, data=data[0], version=data[1])
-            e.arrays = self._unpack(data[0])
-            e.version = e.page.version
+            self._rebuild(e, data[0])
             if data[2] is not None:
                 self.prefetcher.recycle(data[2])
 
@@ -949,22 +979,20 @@ class ServingEngine:
                     self.store.touch(e.page)
                     continue
                 if hot:
-                    data = np.array(self.store.read_page(e.page),
-                                    copy=True)
-                    e.arrays = self._unpack(data)
-                    e.version = e.page.version
+                    self._rebuild(e, self.store.read_page(e.page))
                     continue
                 pid = e.page.page_id
                 if pid not in seen:
+                    # A page two sessions of the batch hold is one entry:
+                    # obtained, promoted and rebuilt once.
                     got = self._obtain(sess, e.page)
                     seen[pid] = got
                     items.append((e.page, got[0], got[1]))
-                installs.append((e, seen[pid]))
+                    installs.append((e, got))
         if items:
             self.store.promote_many(items)
         for e, got in installs:
-            e.arrays = self._unpack(got[0])
-            e.version = e.page.version
+            self._rebuild(e, got[0])
         for got in seen.values():
             if got[2] is not None:
                 self.prefetcher.recycle(got[2])
@@ -1130,12 +1158,13 @@ class ServingEngine:
                         int(touched),
                         len(batch) * self.family.assignments_per_token(cfg))
                 kept = np.asarray(logits) if self.keep_logits else None
-            dt = time.perf_counter() - step.t0
-            self.stats.note_batch_step(len(batch), dt)
-            obs_journal.record(
-                "batch_step", size=len(batch), pad=b_pad,
-                pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
-            )
+                # The step's own books, inside the span that ends it.
+                dt = time.perf_counter() - step.t0
+                self.stats.note_batch_step(len(batch), dt)
+                obs_journal.record(
+                    "batch_step", size=len(batch), pad=b_pad,
+                    pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
+                )
             with span("step.scatter"):
                 for b, (sess, tok, prefill) in enumerate(
                         zip(batch, toks, prefills)):
@@ -1313,20 +1342,30 @@ class ServingEngine:
             page = self.store.alloc_page(raw)
             entry = _Entry(page=page)
             sess.entries.append(entry)
+        # The tail just packed is the page's arrays, bit for bit what a
+        # rebuild from the stored bytes gives (the store's dtype is at
+        # least as wide as the model's).
+        entry.arrays = arrays
+        entry.version = entry.page.version
         if (self.prefix is not None and prompt_only and sess.chain_valid
                 and not entry.page.shared):
             ext = self.prefix.publish(
                 sess.chain_parent, tuple(sess.page_toks), entry.page
             )
-            entry.page = ext.page  # dedup may have swapped in the winner
-            entry.extent = ext
+            if ext.page is entry.page:
+                entry.extent = ext
+                self._shared[ext.page.page_id] = entry
+            else:
+                # Dedup: another session published these tokens first and
+                # its page won (this one is freed). The loser takes the
+                # winner's entry, so a (page_id, version) has one bit
+                # pattern, the stored one, in every context and pool row.
+                sess.entries[-1] = self._entry_of(ext)
             self.prefix.acquire(ext)
             sess.shared_refs.append(ext)
             sess.chain_parent = ext
         elif not prompt_only:
             sess.chain_valid = False  # generated content: never publish
-        entry.arrays = arrays
-        entry.version = entry.page.version
         if sess.seat is None:
             sess.reset_tail()
         else:

@@ -93,6 +93,13 @@ class ServingStats:
         # written, moved or placed in a new stack before the step.
         self.tails_seats_kept = 0
         self.tails_seats_written = 0
+        # A page's decode-ready arrays, kept once a (page, version) for
+        # every session whose context holds the page: pages a session took
+        # that a live session already held (it lists that holder's entry,
+        # arrays and all: no work), and arrays built from the store's bytes
+        # (a residency pass found none at the page's version).
+        self.arrays_pages_shared = 0
+        self.arrays_pages_rebuilt = 0
         # The same for the carry stack of a family that keeps a recurrent
         # state a session (ServingEngine._seat_carries).
         self.carry_seats_kept = 0
@@ -248,6 +255,14 @@ class ServingStats:
             self.tails_seats_kept += kept
             self.tails_seats_written += written
 
+    def note_arrays(self, shared: int = 0, rebuilt: int = 0) -> None:
+        """A page's decode arrays: ``shared`` pages entered a context that
+        a live session already held, ``rebuilt`` arrays were built from the
+        store's bytes."""
+        with self._mu:
+            self.arrays_pages_shared += shared
+            self.arrays_pages_rebuilt += rebuilt
+
     def note_carry(self, kept: int = 0, written: int = 0) -> None:
         """One fused step's seats of the carry stack: ``kept`` carries
         were in their seat already, ``written`` were written in (a
@@ -345,6 +360,10 @@ class ServingStats:
                 "tails": {
                     "seats_kept": self.tails_seats_kept,
                     "seats_written": self.tails_seats_written,
+                },
+                "arrays": {
+                    "pages_shared": self.arrays_pages_shared,
+                    "pages_rebuilt": self.arrays_pages_rebuilt,
                 },
                 "carry": {
                     "seats_kept": self.carry_seats_kept,

@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import importlib.util
 import os
+import weakref
 
 import numpy as np
 import pytest
@@ -670,11 +672,11 @@ def watch_pool(eng):
     table names against the entries it stands for, and log (keys the pool
     held before, capacity before, this batch's distinct keys, capacity
     after). A row is bitwise the ``arrays`` of the entry first seated under
-    its key. Entries that share a key hold the same page and almost always
-    the same bits; the exception is a CoW adopter whose finished page was
-    folded onto the leader's at publish (its last token came from the fused
-    step, the leader's from the page program): such an entry agrees with
-    the row to rounding, and their keys are returned beside the log."""
+    its key. Entries that share a key hold the same page and the same bits:
+    a shared page has one entry, and a CoW adopter whose finished page is
+    folded onto the leader's at publish takes the leader's. The keys of
+    entries that agree with their row to rounding only are returned beside
+    the log: there should be none."""
     calls = []
     inexact = set()
     seated: dict = {}
@@ -758,9 +760,9 @@ def test_pool_rows_are_the_entries_arrays_after_every_tick(tiny_model):
     buckets = [cap for _, _, _, cap in calls]
     assert meta["pool"]["rebuilds"] == 1 + sum(
         a != b for a, b in zip(buckets, buckets[1:]))
-    # every entry agreed with its row bit for bit, but for the one folded
-    # CoW page (t1's last prompt page)
-    assert len(inexact) <= 1
+    # every entry agreed with its row bit for bit, the folded CoW page
+    # (t1's last prompt page) too: it took the leader's entry
+    assert not inexact
 
 
 def test_steady_decode_writes_one_row_a_shipped_page(tiny_model):
@@ -824,6 +826,281 @@ def test_promoted_page_gets_its_row_rewritten(tiny_model):
     # The rewritten row holds the page: the logits are the reference's.
     outs = held_to_reference(tiny_model, [prompt], results)
     assert len(outs["t0"]) == 5
+
+
+# -- 7a. a shared page's decode arrays are kept once, by the page -----------
+
+
+class ArraysWatch:
+    """Log what an engine does about its pages' decode arrays: the pages a
+    session takes into its context (``take``: adopted in ``_match_more``,
+    shipped, or folded onto a winner's page at a dedup), every rebuild from
+    the store's bytes, and the pages a finishing session held. Counts the
+    ``_unpack`` calls and the ``read_page`` calls that found their page HOT,
+    and keeps the bits of every page as its publisher shipped it. After every
+    tick, every entry of a shared page is the one entry of that page and
+    holds the publisher's bits."""
+
+    def __init__(self):
+        self.log: list[tuple] = []
+        self.unpacks = 0
+        self.hot_reads = 0
+        self.bits: dict[int, list] = {}
+        self.refs: list = []
+        self._stored = None
+
+    def __call__(self, eng):
+        unpack, read = eng._unpack, eng.store.read_page
+        alloc, write = eng.store.alloc_page, eng.store.write_page
+        match, ship = eng._match_more, eng._ship
+        rebuild, finish, tick = eng._rebuild, eng._finish, eng._tick
+
+        def counted_unpack(data):
+            self.unpacks += 1
+            return unpack(data)
+
+        def counted_read(page, out=None):
+            self.hot_reads += page.tier == Tier.HOT
+            return read(page, out)
+
+        def logged_match(sess):
+            n = len(sess.entries)
+            match(sess)
+            for e in sess.entries[n:]:
+                if not e.pending_fill:
+                    self.log.append(("take", sess.req.tenant,
+                                     e.page.page_id))
+
+        def noting_alloc(data, **kw):
+            page = alloc(data, **kw)
+            self._stored = page.page_id
+            return page
+
+        def noting_write(page, data):
+            self._stored = page.page_id
+            write(page, data)
+
+        def logged_ship(sess):
+            ship(sess)
+            e = sess.entries[-1]
+            # The page the tail was stored in is the session's page, unless
+            # a dedup at publish folded it onto the winner's.
+            own = e.page.page_id == self._stored
+            self.log.append(("ship" if own else "take", sess.req.tenant,
+                             e.page.page_id))
+            if own:
+                # copies: on the CPU a view would keep the arrays alive
+                self.bits[e.page.page_id] = [np.array(a) for a in e.arrays]
+                self.refs.append(weakref.ref(e.arrays[0]))
+
+        def logged_rebuild(e, data):
+            self.log.append(("rebuild", None, e.page.page_id))
+            rebuild(e, data)
+
+        def logged_finish(sess, abandon=False):
+            self.log.append(("finish", sess.req.tenant,
+                             [e.page.page_id for e in sess.entries]))
+            finish(sess, abandon)
+
+        def checked_tick():
+            tick()
+            by_page = {}
+            for sess in eng.active:
+                for e in sess.entries:
+                    if e.extent is None:
+                        continue
+                    pid = e.page.page_id
+                    assert by_page.setdefault(pid, e) is e
+                    assert eng._shared[pid] is e
+                    if eng._resident(e) and pid in self.bits:
+                        for mine, want in zip(e.arrays, self.bits[pid]):
+                            assert np.array_equal(np.asarray(mine), want)
+            assert set(eng._shared) == set(by_page)
+
+        eng._unpack, eng.store.read_page = counted_unpack, counted_read
+        eng.store.alloc_page, eng.store.write_page = noting_alloc, noting_write
+        eng._match_more, eng._ship = logged_match, logged_ship
+        eng._rebuild, eng._finish, eng._tick = (
+            logged_rebuild, logged_finish, checked_tick)
+
+    def replay(self) -> dict:
+        """The counters the log must have produced, by the rule alone: a
+        session that takes a page some live session already holds shares
+        that holder's arrays; a page is held until the last session that
+        took or shipped it finishes."""
+        want = {"pages_shared": 0, "pages_rebuilt": 0}
+        holders: dict = {}
+        for what, tenant, pid in self.log:
+            if what == "finish":
+                for p in pid:
+                    holders.get(p, set()).discard(tenant)
+            elif what == "rebuild":
+                want["pages_rebuilt"] += 1
+            else:
+                want["pages_shared"] += (what == "take"
+                                         and bool(holders.get(pid)))
+                holders.setdefault(pid, set()).add(tenant)
+        return want
+
+    def alive(self) -> int:
+        """Shipped pages' arrays that something still holds."""
+        gc.collect()
+        return sum(r() is not None for r in self.refs)
+
+
+def prefix_prompts(cfg, seed, *, n, pages, suffixes=None):
+    """``n`` prompts on one prefix of ``pages`` whole pages, each with a
+    sub-page remainder of its own (no two alike: no CoW adoption)."""
+    rng = np.random.default_rng(seed)
+    base = rng.integers(1, cfg.vocab, pages * P).tolist()
+    suffixes = suffixes or [2 + i % (P - 2) for i in range(n)]
+    return [base + rng.integers(1, cfg.vocab, k).tolist() for k in suffixes]
+
+
+def test_adopters_take_the_publishers_arrays_as_they_are(tiny_model):
+    cfg, _ = tiny_model
+    prompts = prefix_prompts(cfg, 91, n=4, pages=3)
+    watch = ArraysWatch()
+    pool_log = []
+
+    def both(eng):
+        pool_log.append(watch_pool(eng))
+        watch(eng)
+
+    outs, meta, _ = run_prompts(tiny_model, prompts, watch=both,
+                                share=True, hot=32, warm=4, max_active=4)
+    # Every prefix page was computed and shipped once, by whoever got there
+    # first; the other three sessions took it: twelve page-places, three
+    # ships. Nobody pulled a HOT page's bytes back or took a page apart.
+    takes = [e for e in watch.log if e[0] == "take"]
+    assert len(takes) == 9 and len({pid for _, _, pid in takes}) == 3
+    assert watch.unpacks == 0 and watch.hot_reads == 0
+    assert meta["arrays"] == watch.replay() == {
+        "pages_shared": 9, "pages_rebuilt": 0}
+    assert meta["prefix"]["hits"] >= 9
+    # the pool's rows are the entries' arrays, which are the publishers'
+    # bits (ArraysWatch, after every tick): no second pattern under a key
+    (calls, inexact), = pool_log
+    assert calls and not inexact
+
+
+def test_a_pages_arrays_live_as_long_as_a_session_holds_the_page(tiny_model):
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    prompts = prefix_prompts(cfg, 93, n=6, pages=3)
+    ctx, store, eng = build_engine(tiny_model, share=True, hot=48, warm=4,
+                                   max_active=3)
+    watch = ArraysWatch()
+    watch(eng)
+    try:
+        results = []
+        for wave in (range(0, 3), range(3, 6)):
+            for i in wave:
+                eng.submit(Request(tenant=f"t{i}", tokens=list(prompts[i]),
+                                   max_new_tokens=5))
+            before = eng.stats.snapshot()["arrays"]
+            unpacks = watch.unpacks
+            n_log = len(watch.log)
+            results += eng.run()
+            # the last session that held a page has finished: nothing is
+            # kept for it, though the extents stay in the trie
+            assert not eng._shared and watch.alive() == 0
+            assert len(eng.prefix.extents()) >= 3
+        after = eng.stats.snapshot()["arrays"]
+        # The second wave found the prefix in the trie and its arrays gone:
+        # each page was rebuilt once, by the first session to need it, and
+        # the two others took it as it was.
+        rebuilt = [pid for what, _, pid in watch.log[n_log:]
+                   if what == "rebuild"]
+        assert len(rebuilt) == len(set(rebuilt)) == 3
+        assert watch.unpacks - unpacks == 3
+        assert after["pages_rebuilt"] - before["pages_rebuilt"] == 3
+        assert after["pages_shared"] - before["pages_shared"] == 6
+        assert after == watch.replay()
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    held_to_reference(tiny_model, prompts, results)
+
+
+@pytest.mark.parametrize("case", ["demoted", "rewritten"])
+def test_a_page_lost_under_two_sharers_is_rebuilt_once(tiny_model, case):
+    from oncilla_tpu.serving.engine import Request
+
+    cfg, _ = tiny_model
+    prompts = prefix_prompts(cfg, 95, n=2, pages=2, suffixes=[3, 5])
+    ctx, store, eng = build_engine(tiny_model, share=True, hot=16, warm=4,
+                                   max_active=2)
+    watch = ArraysWatch()
+    calls, inexact = watch_pool(eng)
+    watch(eng)
+    try:
+        for i, p in enumerate(prompts):
+            eng.submit(Request(tenant=f"t{i}", tokens=list(p),
+                               max_new_tokens=2 * P))
+        while len(calls) < 2 or len(calls[-1][2]) < 2:
+            eng._tick()
+        a, b = eng.active
+        entry = a.entries[0]
+        assert entry is b.entries[0] and eng._resident(entry)
+        page, old_key = entry.page, (entry.page.page_id, entry.version)
+        before = eng.stats.snapshot()
+        unpacks = watch.unpacks
+        if case == "demoted":
+            store.demote(page, Tier.WARM)
+        else:
+            # The store refuses a write under live references; what a
+            # rewrite does to the holders is a new version of the page.
+            raw = np.array(store.read_page(page), copy=True)
+            refs, page.refs = page.refs, 0
+            store.write_page(page, raw)
+            page.refs = refs
+        assert not eng._resident(entry)
+        eng._tick()
+        after = eng.stats.snapshot()
+        new_key = (page.page_id, entry.version)
+        assert eng._resident(entry) and new_key != old_key
+        assert a.entries[0] is entry and b.entries[0] is entry
+        # one rebuild and one row for the two of them
+        assert watch.unpacks == unpacks + 1
+        assert (after["arrays"]["pages_rebuilt"]
+                == before["arrays"]["pages_rebuilt"] + 1)
+        assert after["pool"]["rows_written"] == (
+            before["pool"]["rows_written"] + 1)
+        assert new_key in eng._pool_slots
+        assert old_key not in eng._pool_slots
+        assert after["moves"]["promote"] - before["moves"]["promote"] == (
+            case == "demoted")
+        # (the engine's weak table must not see this test's references)
+        del a, b, entry
+        results = eng.run()
+        assert eng.stats.snapshot()["arrays"] == watch.replay()
+    finally:
+        eng.close()
+        store.close()
+        ctx.tini()
+    assert not inexact
+    outs = held_to_reference(tiny_model, prompts, results)
+    assert [len(outs[f"t{i}"]) for i in range(2)] == [2 * P, 2 * P]
+
+
+def test_without_a_prefix_cache_no_page_is_shared(tiny_model):
+    cfg, _ = tiny_model
+    # The churned workload of the paired gate above, the prefix cache off:
+    # no page is ever in two contexts. Commit 57b5cae (before a page kept
+    # its arrays) made 105 _unpack calls and wrote 93 rows on it.
+    prompts = seeded_prompts(cfg, 11, n=5, shared=20, suffix=4)
+    watch = ArraysWatch()
+    outs, meta, _ = run_prompts(tiny_model, prompts, watch=watch,
+                                share=False, hot=2, warm=2, new_tokens=8,
+                                max_active=4)
+    assert meta["arrays"] == watch.replay() == {
+        "pages_shared": 0, "pages_rebuilt": 105}
+    assert watch.unpacks == 105
+    assert meta["pool"]["rows_written"] == 93
+    assert meta["moves"]["promote"] > 0 and meta["moves"]["demote"] > 0
 
 
 def test_pool_write_program_compiles_once_a_capacity(tiny_model, monkeypatch):
