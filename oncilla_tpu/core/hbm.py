@@ -198,7 +198,9 @@ class DeviceArena:
 
     @staticmethod
     def _idx(off: int):
-        return jnp.asarray(off, dtype=jnp.int32)
+        # A numpy scalar: the jitted arena ops take it as the int32 operand
+        # they were traced with, and no eager convert runs first.
+        return np.int32(off)
 
     @property
     def capacity(self) -> int:

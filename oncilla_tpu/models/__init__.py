@@ -38,11 +38,15 @@ _EXPORTS = {
     "paged_decode_page_jit": "kv_paging",
     "paged_generate_page_jit": "kv_paging",
     "PagedFamily": "kv_paging",
+    "PageKind": "kv_paging",
     # latent_moe: latent attention, routed experts, hyper-connections.
     "LatentMoeConfig": "latent_moe",
     # kda_latent: delta-rule linear attention beside latent attention,
     # group-limited experts of which the chip holds a share.
     "KdaLatentConfig": "kda_latent",
+    # swa_moe: window and full attention layers with per-head gates, a
+    # page of two kinds, experts of which the chip holds a share.
+    "SwaMoeConfig": "swa_moe",
 }
 
 __all__ = sorted(_EXPORTS)

@@ -27,6 +27,19 @@ from oncilla_tpu.utils.debug import GLOBAL_TRACER
 
 
 @dataclass(frozen=True)
+class PageKind:
+    """One kind of page of a family: ``layers`` layers cache their
+    positions in it (the ``L`` of its ``n_leaves`` leaves), and a query
+    looks back over ``window`` positions of them (None: over all). A page
+    of a kind with a window is of no use once every key in it lies outside
+    the window of every later query; the engine then drops it."""
+
+    layers: int
+    window: int | None = None
+    n_leaves: int = 1
+
+
+@dataclass(frozen=True)
 class PagedFamily:
     """What :class:`~oncilla_tpu.serving.engine.ServingEngine` takes from a
     model family: the leaves a page and a tail are made of, and the
@@ -47,7 +60,7 @@ class PagedFamily:
     ``aux`` back, is how many (layer, expert) pairs one token is routed
     to.
 
-    Two things are the family's to say, and most say neither.
+    Four things are the family's to say, and most say none.
     ``cached_layers(cfg)`` is how many layers keep pages (the ``L`` of a
     leaf; absent: ``cfg.n_layers``). ``carry_leaves(cfg, batch)`` gives a
     family whose layers keep a recurrent state of fixed size beside (or
@@ -55,7 +68,25 @@ class PagedFamily:
     the batch on axis 1 as in a tail. Such a family's ``step`` and
     ``page`` take the carry as one more argument after ``cfg``, donated,
     and return it as a fourth value; the engine keeps the seated
-    sessions' carries in one stack, as it keeps their tails."""
+    sessions' carries in one stack, as it keeps their tails.
+
+    ``kinds(cfg)`` gives a family whose layers do not all cache alike its
+    page's **kinds** (:class:`PageKind`), each with its own layer count,
+    leaves and lifetime: a session then ships one page a kind at every
+    page boundary, ``n_leaves`` counts the leaves of all kinds, and
+    ``pool``, ``ctx`` and ``tails`` hold them kind by kind. ``table`` is a
+    tuple of block tables, one a kind, and ``meta`` has a context length
+    and a first position a kind: a step's rows are ``[pos, tail_len,
+    ctx_len, ctx_start, ctx_len, ctx_start, ...]``, a page's ``[pos0,
+    ctx_start, ctx_start, ...]``. A family that says nothing has one kind
+    of every cached layer, its ``table`` is the one array and its ``meta``
+    what it has always been. ``write_row`` is called a kind at a time.
+
+    ``context(pages, cfg, page_tokens)`` gives a family its own way of
+    joining a session's pages into the page program's ``ctx``: ``pages``
+    is, a kind, the list of that kind's pages in context order, each a
+    tuple of leaves. Absent: every leaf's pages concatenated along the
+    token axis, one small program a context length and leaf shape."""
 
     n_leaves: int
     leaf_dims: object
@@ -65,12 +96,32 @@ class PagedFamily:
     assignments_per_token: object = None
     cached_layers: object = None
     carry_leaves: object = None
+    kinds: object = None
+    context: object = None
 
-    def leaf_shape(self, cfg, page_tokens: int, batch: int = 1) -> tuple:
-        kv, hd = self.leaf_dims(cfg)
+    def page_kinds(self, cfg) -> tuple:
+        if self.kinds is not None:
+            return tuple(self.kinds(cfg))
         layers = (self.cached_layers(cfg) if self.cached_layers
                   else cfg.n_layers)
-        return (layers, batch, kv, page_tokens, hd)
+        return (PageKind(layers, None, self.n_leaves),)
+
+    def kind_leaves(self, cfg) -> list:
+        """Where each kind's leaves lie among the family's: a slice a
+        kind."""
+        ends = np.cumsum([k.n_leaves for k in self.page_kinds(cfg)]).tolist()
+        return [slice(a, b) for a, b in zip([0] + ends, ends)]
+
+    def leaf_shapes(self, cfg, page_tokens: int, batch: int = 1) -> tuple:
+        """The shape of every leaf, kind by kind."""
+        kv, hd = self.leaf_dims(cfg)
+        return tuple((kind.layers, batch, kv, page_tokens, hd)
+                     for kind in self.page_kinds(cfg)
+                     for _ in range(kind.n_leaves))
+
+    def leaf_shape(self, cfg, page_tokens: int, batch: int = 1) -> tuple:
+        """The one shape of a family that names no kinds."""
+        return self.leaf_shapes(cfg, page_tokens, batch)[0]
 
 
 @dataclass

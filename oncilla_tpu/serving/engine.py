@@ -144,6 +144,15 @@ def _seat_read_jit(stack: tuple, seat: jax.Array) -> tuple:
                  for s in stack)
 
 
+@partial(jax.jit, static_argnames=("dtype",))
+def _pack_pages_jit(kinds: tuple, dtype: str) -> tuple:
+    """A full tail on its way into the store, in ONE dispatch whatever the
+    family: a kind's leaves stacked, cast to the store's type and flattened
+    to bytes (``core.hbm.to_bytes``). Returns a uint8 vector a kind."""
+    return tuple(to_bytes(jnp.stack(leaves).astype(jnp.dtype(dtype)))
+                 for leaves in kinds)
+
+
 def _zero_carry(family: PagedFamily, cfg, batch: int) -> tuple | None:
     """Fresh zeros of a family's carry for ``batch`` seats (the programs
     donate them), or None for a family that keeps none."""
@@ -324,14 +333,21 @@ class _Entry:
     pending_fill: bool = False
     arrays: tuple | None = None   # the page's leaves, decode-ready, cfg dtype
     version: int = -1             # page.version the arrays were built at
+    kind: int = 0                 # which of the family's page kinds it is
 
 
 class _Session:
-    def __init__(self, req: Request, tail_shape: tuple, n_leaves: int, dtype,
-                 carry: tuple | None = None):
+    def __init__(self, req: Request, leaf_shapes: tuple, dtype,
+                 carry: tuple | None = None, n_kinds: int = 1):
         self.req = req
         self.prompt = [int(t) for t in req.tokens]
+        #: The context's pages in the order they were taken or shipped,
+        #: every kind's in one list (``_Entry.kind``); a kind's pages are
+        #: in context order among themselves.
         self.entries: list[_Entry] = []
+        #: Pages of each kind dropped from the front of the context: the
+        #: kind's first page left starts at ``dropped * page_tokens``.
+        self.dropped = [0] * n_kinds
         self.shared_refs: list[SharedExtent] = []
         self.out: list[int] = []
         self.logits: list[np.ndarray] = []
@@ -354,8 +370,7 @@ class _Session:
         self.pages_done_t: float | None = None
         self.unseated_ticks = 0
         self.ttft_parts: dict | None = None
-        self._tail_shape = tail_shape
-        self._n_leaves = n_leaves
+        self._leaf_shapes = leaf_shapes
         self._tail_dt = jnp.dtype(dtype)
         #: The seat of the engine's tail stack that holds this session's
         #: tail, or None while the session holds it itself.
@@ -375,8 +390,8 @@ class _Session:
         # and the decode step donates the tail buffers — a cached zeros
         # array would be consumed by the first donation and poison every
         # later page.
-        self.tails = tuple(jnp.zeros(self._tail_shape, self._tail_dt)
-                           for _ in range(self._n_leaves))
+        self.tails = tuple(jnp.zeros(shape, self._tail_dt)
+                           for shape in self._leaf_shapes)
         self.tail_len = 0
         self.page_toks = []
 
@@ -446,15 +461,34 @@ class ServingEngine:
                 "prefix_cache with a family that keeps a recurrent carry: "
                 "a prefix page is adoptable only with the carry at its "
                 "boundary, and no extent holds one yet (ROADMAP.md, Queue 2)")
-        # The fused step's page pool, kept on the device between ticks
-        # (see _batch_pool): one array of rows (capacity, L, KV, P, Hd) a
-        # leaf, the row of every (page_id, version) it holds, least
-        # recently seated first, and the rows nothing was written to yet.
-        self._pool: tuple | None = None
-        self._pool_slots: dict[tuple, int] = {}
-        self._pool_free: list[int] = []
+        # The kinds of the family's page (most families: one), the shape
+        # of every leaf of a page, kind by kind, and where each kind's
+        # leaves lie among them.
+        self.kinds = self.family.page_kinds(cfg)
+        if len(self.kinds) > 1 and prefix is not None:
+            raise ValueError(
+                "prefix_cache with a family whose page comes in kinds: an "
+                "extent holds one page, and a prefix is adoptable only with "
+                "the last pages of its window kinds (ROADMAP.md, Queue 2)")
+        self._leaf_shapes = self.family.leaf_shapes(cfg, self.page_tokens)
+        self._kind_leaves = self.family.kind_leaves(cfg)
+        self._has_window = any(k.window is not None for k in self.kinds)
+        # A chunk's expert count each, still on the device: a ship hands
+        # the store its page where it lies and waits for nothing, so the
+        # counts are looked at where the host waits for the device anyway
+        # (:meth:`_note_pages`).
+        self._pages_touched: list = []
+        n_kinds = len(self.kinds)
+        # The fused step's page pools, kept on the device between ticks
+        # (see _batch_pool), one a kind: one array of rows (capacity, L,
+        # KV, P, Hd) a leaf, the row of every (page_id, version) it holds,
+        # least recently seated first, and the rows nothing was written to
+        # yet (or whose page was dropped).
+        self._pool: list = [None] * n_kinds
+        self._pool_slots: list[dict] = [{} for _ in self.kinds]
+        self._pool_free: list[list] = [[] for _ in self.kinds]
         # Pool capacities whose row-write program has already run.
-        self._pool_write_ready: set[int] = set()
+        self._pool_write_ready: list[set] = [set() for _ in self.kinds]
         # The seated sessions' tails, kept on the device between ticks
         # (see _seat_batch): one stack (L, b_pad, KV, P, Hd) a leaf, the
         # session in every seat (seats [0, len) are taken, a vacated one
@@ -466,7 +500,7 @@ class ServingEngine:
         self._carry: tuple | None = None
         self._seats: list[_Session | None] = []
         self._seat_ready: set[int] = set()
-        self._tab_cache: tuple = (None, None)
+        self._tab_cache: list = [(None, None)] * n_kinds
         # The entry of every shared extent's page some live session's
         # context holds, by page_id (see _Entry). The sessions' lists are
         # the references: when the last holder finishes, the entry and its
@@ -476,8 +510,10 @@ class ServingEngine:
         self.queue: list[Request] = []
         self.active: list[_Session] = []
         self.results: list[SessionResult] = []
-        self._tail_shape = self.family.leaf_shape(cfg, self.page_tokens)
-        self.page_shape = (self.family.n_leaves,) + self._tail_shape
+        # A stored page of each kind: its leaves stacked.
+        self.page_shapes = tuple(
+            (kind.n_leaves,) + self._leaf_shapes[sl.start]
+            for kind, sl in zip(self.kinds, self._kind_leaves))
         expect = self.page_nbytes(cfg, self.page_tokens, store_dtype)
         if expect != store.page_bytes:
             raise ValueError(
@@ -497,19 +533,20 @@ class ServingEngine:
     @staticmethod
     def page_nbytes(cfg, page_tokens: int,
                     store_dtype: str = "float32") -> int:
-        """Size of one packed page (every leaf of ``cfg``'s family) — what
-        the :class:`TieredPageStore` must be built with."""
+        """Size of one packed page (every leaf of it; of the largest
+        kind, where ``cfg``'s family has several) — what the
+        :class:`TieredPageStore` must be built with."""
         fam = family_of(cfg)
-        return int(
-            fam.n_leaves * np.prod(fam.leaf_shape(cfg, page_tokens))
-            * jnp.dtype(store_dtype).itemsize
-        )
+        kv, hd = fam.leaf_dims(cfg)
+        return (max(kind.n_leaves * kind.layers
+                    for kind in fam.page_kinds(cfg))
+                * kv * page_tokens * hd * jnp.dtype(store_dtype).itemsize)
 
     @property
     def _pool_k(self):
-        """The pool's first leaf (the dense family's K rows), or None: its
-        row count is the pool's capacity."""
-        return None if self._pool is None else self._pool[0]
+        """The first pool's first leaf (the dense family's K rows), or
+        None: its row count is that pool's capacity."""
+        return None if self._pool[0] is None else self._pool[0][0]
 
     # -- submission / driving --------------------------------------------
 
@@ -533,7 +570,8 @@ class ServingEngine:
         for sess in self.active:
             self._finish(sess, abandon=True)
         self.active = []
-        self._pool = None
+        self._pool = [None] * len(self.kinds)
+        self._pages_touched = []
         self._tails = None
         self._carry = None
         self._seats = []
@@ -563,8 +601,8 @@ class ServingEngine:
         # admitted simultaneously still dedup against pages a sibling
         # publishes one tick later.
         sess = _Session(
-            req, self._tail_shape, self.family.n_leaves, self.cfg.dtype,
-            _zero_carry(self.family, self.cfg, 1))
+            req, self._leaf_shapes, self.cfg.dtype,
+            _zero_carry(self.family, self.cfg, 1), len(self.kinds))
         sess.admit_t = time.perf_counter()
         self._note_pages_done(sess)
         return sess
@@ -637,12 +675,12 @@ class ServingEngine:
 
     # -- residency / prefetch --------------------------------------------
 
-    def _unpack(self, data: np.ndarray) -> tuple:
+    def _unpack(self, data: np.ndarray, kind: int = 0) -> tuple:
+        shape = self.page_shapes[kind]
         packed = from_bytes(jnp.asarray(np.array(data, copy=True)),
-                            self.page_shape, self.store_dtype)
+                            shape, self.store_dtype)
         dt = jnp.dtype(self.cfg.dtype)
-        return tuple(packed[i].astype(dt)
-                     for i in range(self.family.n_leaves))
+        return tuple(packed[i].astype(dt) for i in range(shape[0]))
 
     def _resident(self, e: _Entry) -> bool:
         return (e.arrays is not None and e.version == e.page.version
@@ -663,7 +701,7 @@ class ServingEngine:
 
     def _rebuild(self, e: _Entry, data: np.ndarray) -> None:
         """Build the entry's arrays from its page's bytes in the store."""
-        e.arrays = self._unpack(data)
+        e.arrays = self._unpack(data, e.kind)
         e.version = e.page.version
         self.stats.note_arrays(rebuilt=1)
 
@@ -753,7 +791,7 @@ class ServingEngine:
                     obs_journal.record("prefetch_stall",
                                        page_id=page.page_id,
                                        wait_ms=round(waited * 1e3, 3))
-                return (buf, version, buf)
+                return (buf[:page.nbytes], version, buf)
             if buf is not None:
                 self.prefetcher.recycle(buf)
         # Page fault: no (usable) prefetch — the whole fetch is stall.
@@ -768,16 +806,23 @@ class ServingEngine:
         return (data, version, None)
 
     def _context(self, sess: _Session) -> tuple:
-        """The session's paged context, a leaf at a time: its pages'
-        arrays joined along the token axis."""
-        pages = [e.arrays for e in sess.entries if not e.pending_fill]
-        n = self.family.n_leaves
-        if not pages:
-            shape = self._tail_shape[:3] + (0,) + self._tail_shape[4:]
-            z = jnp.zeros(shape, jnp.dtype(self.cfg.dtype))
-            return (z,) * n
-        return tuple(jnp.concatenate([a[i] for a in pages], axis=3)
-                     for i in range(n))
+        """The session's paged context, a leaf at a time, kind by kind:
+        the arrays of the kind's pages joined along the token axis."""
+        pages = [[] for _ in self.kinds]
+        for e in sess.entries:
+            if not e.pending_fill:
+                pages[e.kind].append(e.arrays)
+        if self.family.context is not None:
+            return self.family.context(pages, self.cfg, self.page_tokens)
+        ctx = []
+        for held, leaves in zip(pages, self._kind_leaves):
+            for i, shape in enumerate(self._leaf_shapes[leaves]):
+                if held:
+                    ctx.append(jnp.concatenate([a[i] for a in held], axis=3))
+                else:
+                    ctx.append(jnp.zeros(shape[:3] + (0,) + shape[4:],
+                                         jnp.dtype(self.cfg.dtype)))
+        return tuple(ctx)
 
     # -- decode -----------------------------------------------------------
 
@@ -889,7 +934,9 @@ class ServingEngine:
         with span("prefill.dispatch"):
             pc = sess.prompt_consumed
             chunk = sess.prompt[pc:pc + P]
-            meta = jnp.asarray([sess.pos, 0], jnp.int32)
+            # Where the chunk starts, and a kind where its context does.
+            meta = jnp.asarray(
+                [sess.pos] + [n * P for n in sess.dropped], jnp.int32)
             args = (self.params, jnp.asarray([chunk], jnp.int32), meta,
                     ctx, sess.tails, self.cfg)
             if self._has_carry:
@@ -916,9 +963,11 @@ class ServingEngine:
         with span("prefill.ship"):
             self._ship(sess)
             if touched is not None:
-                # The ship has waited for the page program: no wait here.
-                self.stats.note_moe_page(int(touched))
+                self._pages_touched.append(touched)
             self._match_more(sess)
+        if self._has_window:
+            with span("prefill.drop"):
+                self._drop_passed(sess)
 
     def _yields_cold(self, sess: _Session) -> bool:
         """True when a seat should be given up this tick: some context
@@ -998,39 +1047,52 @@ class ServingEngine:
                 self.prefetcher.recycle(got[2])
 
     def _batch_pool(self, batch: list[_Session]):
-        """The tick's page pool + per-session block table. The pool is
-        device state that outlives the tick: every distinct resident
-        page of the batch holds one row of a (capacity, L, KV, P, Hd)
-        pool, one such array a leaf of the family's page (a shared prefix
-        page is one row however many sessions reference it) under its
-        (page_id, version), and table[b] lists session b's rows. A page
-        that has a row keeps it, with no device work; a page without one
-        takes a free row, or the row of the page seated longest ago that
-        this batch does not reference, and ONE dispatch of the family's
-        row write (:func:`paged_pool_write_row_jit`) writes its leaves
-        there in place. So a session that loses its seat for a tick
-        finds its rows again. ``capacity`` and MP snap to power-of-two
-        buckets of this batch's rows; when the capacity bucket changes,
-        the batch's pages are written into a fresh pool
-        (:meth:`_new_pool`). Returns ``(*pool leaves, table, tables)``."""
-        rows: dict[tuple, tuple] = {}
-        tables = []
+        """The tick's page pools + per-session block tables, one of each a
+        kind of the family's page (:meth:`_kind_pool`). Returns, each a
+        list over the kinds: the pools (a tuple of leaves each), the block
+        tables ``(len(batch), MP)`` and, session by session, the keys the
+        tables' rows stand for."""
+        n = len(self.kinds)
+        rows: list[dict] = [{} for _ in range(n)]
+        keys: list[list] = [[] for _ in range(n)]
         for sess in batch:
-            trow = []
+            mine = [[] for _ in range(n)]
             for e in sess.entries:
                 if e.pending_fill:
                     continue
                 key = (e.page.page_id, e.version)
-                rows.setdefault(key, e.arrays)
-                trow.append(key)
-            tables.append(trow)
-        max_pages = max((len(t) for t in tables), default=0)
+                rows[e.kind].setdefault(key, e.arrays)
+                mine[e.kind].append(key)
+            for k in range(n):
+                keys[k].append(mine[k])
+        tables = [self._kind_pool(k, rows[k], keys[k]) for k in range(n)]
+        return list(self._pool), tables, keys
+
+    def _kind_pool(self, k: int, rows: dict, keys: list) -> np.ndarray:
+        """Bring kind ``k``'s pool up to the batch and return its block
+        table. The pool is device state that outlives the tick: every
+        distinct resident page of the batch holds one row of a (capacity,
+        L, KV, P, Hd) pool, one such array a leaf of the kind (a shared
+        prefix page is one row however many sessions reference it) under
+        its (page_id, version), and table[b] lists session b's rows. A
+        page that has a row keeps it, with no device work; a page without
+        one takes a free row, or the row of the page seated longest ago
+        that this batch does not reference, and ONE dispatch of the
+        family's row write (:func:`paged_pool_write_row_jit`) writes its
+        leaves there in place. So a session that loses its seat for a tick
+        finds its rows again. ``capacity`` and MP snap to power-of-two
+        buckets of this batch's rows; when the capacity bucket changes,
+        the batch's pages are written into a fresh pool
+        (:meth:`_new_pool`). ``rows`` is the batch's distinct pages by
+        key, ``keys`` each session's keys in context order."""
+        max_pages = max((len(t) for t in keys), default=0)
         mp = _pow2(max_pages) if max_pages else 0
         capacity = _pow2(len(rows)) if rows else 1
-        rebuilt = self._pool is None or self._pool[0].shape[0] != capacity
+        rebuilt = (self._pool[k] is None
+                   or self._pool[k][0].shape[0] != capacity)
         if rebuilt:
-            self._new_pool(capacity)
-        slots = self._pool_slots
+            self._new_pool(k, capacity)
+        slots, free = self._pool_slots[k], self._pool_free[k]
         fresh = []
         for key in rows:
             if key in slots:
@@ -1041,43 +1103,41 @@ class ServingEngine:
             # Every seated key is behind the unseated ones by now, and the
             # batch has at most `capacity` keys: with no row free, the
             # oldest key is one this batch does not reference.
-            slot = (self._pool_free.pop() if self._pool_free
-                    else slots.pop(next(iter(slots))))
-            self._pool = self.family.write_row(
-                self._pool, rows[key], np.int32(slot))
+            slot = free.pop() if free else slots.pop(next(iter(slots)))
+            self._pool[k] = self.family.write_row(
+                self._pool[k], rows[key], np.int32(slot))
             slots[key] = slot
         self.stats.note_pool(reused=len(rows) - len(fresh),
                              written=len(fresh), rebuilt=rebuilt)
-        table = np.zeros((len(batch), mp), np.int32)
-        for b, trow in enumerate(tables):
+        table = np.zeros((len(keys), mp), np.int32)
+        for b, trow in enumerate(keys):
             table[b, :len(trow)] = [slots[key] for key in trow]
-        return (*self._pool, table, tables)
+        return table
 
-    def _new_pool(self, capacity: int) -> None:
-        """Replace the pool by zeros of ``capacity`` rows, all free. The
-        rare path: the first pool, and a crossing of a power-of-two row
-        count. No program may be built when a page is first written in
-        place, at whatever tick that is: the row write runs once here on
-        scratch zeros of this capacity and of the next one up."""
+    def _new_pool(self, k: int, capacity: int) -> None:
+        """Replace kind ``k``'s pool by zeros of ``capacity`` rows, all
+        free. The rare path: the first pool, and a crossing of a
+        power-of-two row count. No program may be built when a page is
+        first written in place, at whatever tick that is: the row write
+        runs once here on scratch zeros of this capacity and of the next
+        one up."""
         dt = jnp.dtype(self.cfg.dtype)
-        page = self._tail_shape         # (L, 1, KV, P, Hd)
-        leaves = self.family.n_leaves
+        pages = self._leaf_shapes[self._kind_leaves[k]]    # (L, 1, KV, P, Hd)
 
         def zeros(n: int) -> tuple:
-            shape = (n, page[0]) + page[2:]
-            return tuple(jnp.zeros(shape, dt) for _ in range(leaves))
+            return tuple(jnp.zeros((n, page[0]) + page[2:], dt)
+                         for page in pages)
 
         # The old pool's memory goes before the new pool is made.
-        self._pool = None
+        self._pool[k] = None
         for n in (capacity, 2 * capacity):
-            if n not in self._pool_write_ready:
-                zpage = jnp.zeros(page, dt)
-                self.family.write_row(zeros(n), (zpage,) * leaves,
-                                      np.int32(0))
-                self._pool_write_ready.add(n)
-        self._pool = zeros(capacity)
-        self._pool_slots = {}
-        self._pool_free = list(range(capacity - 1, -1, -1))
+            if n not in self._pool_write_ready[k]:
+                zpage = tuple(jnp.zeros(page, dt) for page in pages)
+                self.family.write_row(zeros(n), zpage, np.int32(0))
+                self._pool_write_ready[k].add(n)
+        self._pool[k] = zeros(capacity)
+        self._pool_slots[k] = {}
+        self._pool_free[k] = list(range(capacity - 1, -1, -1))
 
     def _batch_step(self, batch: list[_Session]) -> None:
         """ONE fused jit dispatch advancing every seated session by one
@@ -1098,11 +1158,13 @@ class ServingEngine:
                 with span("step.carry"):
                     self._seat_carries(joined, moved)
             with span("step.pool"):
-                *pool, table, tables = self._batch_pool(batch)
+                pools, tables, keys = self._batch_pool(batch)
             with span("step.args"):
                 b_pad = _pow2(len(batch))
                 toks, metas, prefills = [], [], []
-                for sess, trow in zip(batch, tables):
+                layers = [kind.layers for kind in self.kinds]
+                held = whole = 0
+                for b, sess in enumerate(batch):
                     if sess.prompt_consumed < len(sess.prompt):
                         tok = sess.prompt[sess.prompt_consumed]
                         sess.prompt_consumed += 1
@@ -1113,22 +1175,38 @@ class ServingEngine:
                         prefill = False
                     toks.append(tok)
                     prefills.append(prefill)
-                    metas.append([sess.pos, sess.tail_len, len(trow) * P, 0])
+                    # A row: where it is, then a kind how long its
+                    # context is and where that starts.
+                    row = [sess.pos, sess.tail_len]
+                    for k, n_layers in enumerate(layers):
+                        pages, gone = len(keys[k][b]), sess.dropped[k]
+                        row += [pages * P, gone * P]
+                        held += n_layers * pages
+                        whole += n_layers * (pages + gone)
+                    metas.append(row)
+                self.stats.note_kv(held * P, whole * P)
                 pad_b = b_pad - len(batch)
                 toks += [0] * pad_b
-                metas += [[0, 0, 0, 0]] * pad_b
-                tab = np.zeros((b_pad, table.shape[1]), np.int32)
-                tab[:len(batch)] = table
-                tab_key = (tab.shape, tab.tobytes())
+                metas += [[0] * len(metas[0])] * pad_b
+                tabs = []
+                for table in tables:
+                    tab = np.zeros((b_pad, table.shape[1]), np.int32)
+                    tab[:len(batch)] = table
+                    tabs.append(((tab.shape, tab.tobytes()), tab))
             with span("step.dispatch"):
-                if self._tab_cache[0] != tab_key:
-                    self._tab_cache = (tab_key, jnp.asarray(tab))
+                for k, (tab_key, tab) in enumerate(tabs):
+                    if self._tab_cache[k][0] != tab_key:
+                        self._tab_cache[k] = (tab_key, jnp.asarray(tab))
+                on_device = [cached for _, cached in self._tab_cache]
                 # The stack is donated; the step hands it back with every
                 # row's token in place. A step that raises hands nothing
                 # back: nobody holds a seat of a stack that is gone.
                 args = (self.params, jnp.asarray(toks, jnp.int32),
-                        jnp.asarray(metas, jnp.int32), len(batch), pool,
-                        self._tab_cache[1], self._tails, cfg)
+                        jnp.asarray(metas, jnp.int32), len(batch),
+                        tuple(leaf for pool in pools for leaf in pool),
+                        on_device[0] if len(pools) == 1
+                        else tuple(on_device),
+                        self._tails, cfg)
                 try:
                     if self._has_carry:
                         logits, self._tails, touched, self._carry = (
@@ -1157,13 +1235,14 @@ class ServingEngine:
                     self.stats.note_moe_step(
                         int(touched),
                         len(batch) * self.family.assignments_per_token(cfg))
+                self._note_pages()
                 kept = np.asarray(logits) if self.keep_logits else None
                 # The step's own books, inside the span that ends it.
                 dt = time.perf_counter() - step.t0
                 self.stats.note_batch_step(len(batch), dt)
                 obs_journal.record(
                     "batch_step", size=len(batch), pad=b_pad,
-                    pages=int(tab.shape[1]), ms=round(dt * 1e3, 3),
+                    pages=int(tables[0].shape[1]), ms=round(dt * 1e3, 3),
                 )
             with span("step.scatter"):
                 for b, (sess, tok, prefill) in enumerate(
@@ -1186,6 +1265,9 @@ class ServingEngine:
                             # session's, empty.
                             self._ship(sess)
                             self._match_more(sess)
+                        if self._has_window:
+                            with span("step.drop"):
+                                self._drop_passed(sess)
                     elif (self.share_partials and prefill
                           and sess.prompt_consumed == len(sess.prompt)):
                         with span("step.publish"):
@@ -1297,8 +1379,9 @@ class ServingEngine:
         fam = self.family
 
         def zeros(b: int) -> tuple:
-            shape = fam.leaf_shape(self.cfg, self.page_tokens, b)
-            return tuple(jnp.zeros(shape, dt) for _ in range(fam.n_leaves))
+            return tuple(
+                jnp.zeros(shape, dt)
+                for shape in fam.leaf_shapes(self.cfg, self.page_tokens, b))
 
         def carry_zeros(b: int) -> tuple:
             return _zero_carry(fam, self.cfg, b)
@@ -1324,29 +1407,37 @@ class ServingEngine:
         return _seat_read_jit(self._tails, np.int32(sess.seat))
 
     def _ship(self, sess: _Session) -> None:
-        """Page boundary: the full tail becomes a stored page — the
-        pending CoW clone when one is open, a published shared extent
-        for prompt-only pages, a private page otherwise."""
-        arrays = self._tail(sess)
-        packed = jnp.stack(list(arrays)).astype(
-            jnp.dtype(self.store_dtype)
-        )
-        raw = np.asarray(to_bytes(packed))
+        """Page boundary: the full tail becomes a stored page, one a
+        kind of the family's page — the pending CoW clone when one is
+        open, a published shared extent for prompt-only pages, a private
+        page otherwise."""
+        tail = self._tail(sess)
         prompt_only = sess.pos <= len(sess.prompt)
         pending = next((e for e in sess.entries if e.pending_fill), None)
-        if pending is not None:
-            self.store.write_page(pending.page, raw)
-            entry = pending
-            entry.pending_fill = False
-        else:
-            page = self.store.alloc_page(raw)
-            entry = _Entry(page=page)
-            sess.entries.append(entry)
-        # The tail just packed is the page's arrays, bit for bit what a
-        # rebuild from the stored bytes gives (the store's dtype is at
-        # least as wide as the model's).
-        entry.arrays = arrays
-        entry.version = entry.page.version
+        # The pages go to the store where they lie, on the device: one
+        # sited in HOT never passes through the host, and the ship does
+        # not wait for the program that made the tail.
+        packed = _pack_pages_jit(
+            tuple(tail[leaves] for leaves in self._kind_leaves),
+            self.store_dtype)
+        for k, leaves in enumerate(self._kind_leaves):
+            arrays = tail[leaves]
+            if pending is not None:
+                self.store.write_page(pending.page, packed[k])
+                entry = pending
+                entry.pending_fill = False
+            else:
+                page = self.store.alloc_page(packed[k])
+                entry = _Entry(page=page, kind=k)
+                sess.entries.append(entry)
+            # The tail just packed is the page's arrays, bit for bit what
+            # a rebuild from the stored bytes gives (the store's dtype is
+            # at least as wide as the model's).
+            entry.arrays = arrays
+            entry.version = entry.page.version
+            if self.kinds[k].window is not None:
+                self.stats.note_window(shipped=1)
+        # With a prefix cache the page has one kind: `entry` is the page.
         if (self.prefix is not None and prompt_only and sess.chain_valid
                 and not entry.page.shared):
             ext = self.prefix.publish(
@@ -1373,6 +1464,31 @@ class ServingEngine:
             # fused step reads a row that enters with tail_len 0 as zeros.
             sess.tail_len = 0
             sess.page_toks = []
+
+    def _drop_passed(self, sess: _Session) -> None:
+        """After a ship: drop the pages of a kind with a window whose
+        every key lies outside the window of every later query (a page
+        that starts at ``s`` once ``s + P <= pos - window``, the rule of
+        ``kv_paging.BucketedPagedDecoder``). A dropped page is freed in the
+        store, whatever tier it lies in, its arrays are released and its
+        pool row is free again: it is never demoted, nothing will read it.
+        A session so lists ``ceil(window / P)`` pages of the kind at
+        most."""
+        P = self.page_tokens
+        for k, kind in enumerate(self.kinds):
+            if kind.window is None:
+                continue
+            while (sess.dropped[k] + 1) * P <= sess.pos - kind.window:
+                first = next(e for e in sess.entries if e.kind == k)
+                sess.entries.remove(first)
+                slot = self._pool_slots[k].pop(
+                    (first.page.page_id, first.version), None)
+                if slot is not None:
+                    self._pool_free[k].append(slot)
+                first.arrays = None
+                self.store.free_page(first.page)
+                sess.dropped[k] += 1
+                self.stats.note_window(dropped=1)
 
     def _publish_partial(self, sess: _Session) -> None:
         """End of prefill mid-page: publish the prompt's partial tail as
@@ -1415,7 +1531,16 @@ class ServingEngine:
 
     # -- introspection ----------------------------------------------------
 
+    def _note_pages(self) -> None:
+        """Look at the expert counts of the chunks since the last look: a
+        host sync, made where the host waits for the device anyway."""
+        if self._pages_touched:
+            counts = jax.device_get(self._pages_touched)
+            self.stats.note_moe_page(int(sum(counts)), len(counts))
+            self._pages_touched = []
+
     def metrics_meta(self) -> dict:
+        self._note_pages()
         meta = self.stats.snapshot()
         meta["prefetch"]["mode"] = self.prefetcher.mode
         if self.prefix is not None:

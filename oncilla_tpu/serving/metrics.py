@@ -113,6 +113,17 @@ class ServingStats:
         self.moe_step_assignments = 0
         self.moe_page_expert_rows = 0
         self.moe_page_count = 0
+        # A family whose page comes in kinds, some with a window: pages of
+        # such a kind shipped, and dropped once they had left the window
+        # of every later query (freed, never demoted).
+        self.window_pages_shipped = 0
+        self.window_pages_dropped = 0
+        # (layer, position) pairs the seated sessions' live pages held,
+        # summed over the fused steps, and what they would have held had
+        # every cached layer kept every position (equal unless a kind
+        # drops pages).
+        self.kv_positions_held = 0
+        self.kv_positions_whole = 0
         self.preempts: dict[str, int] = {}
         # Time-to-first-token per session (submit -> first emitted
         # token), same cumulative prom-style bucket shape as the step
@@ -279,11 +290,27 @@ class ServingStats:
             self.moe_step_expert_rows += expert_rows
             self.moe_step_assignments += assignments
 
-    def note_moe_page(self, expert_rows: int) -> None:
-        """One prefill page of a family with experts."""
+    def note_moe_page(self, expert_rows: int, pages: int = 1) -> None:
+        """``pages`` prefill pages of a family with experts (one, or as
+        many as the engine summed on the device before it looked)."""
         with self._mu:
             self.moe_page_expert_rows += expert_rows
-            self.moe_page_count += 1
+            self.moe_page_count += pages
+
+    def note_window(self, shipped: int = 0, dropped: int = 0) -> None:
+        """Pages of a kind with a window: ``shipped`` into the store,
+        ``dropped`` from it because they had left the window."""
+        with self._mu:
+            self.window_pages_shipped += shipped
+            self.window_pages_dropped += dropped
+
+    def note_kv(self, held: int, whole: int) -> None:
+        """One fused step's context: ``held`` (layer, position) pairs in
+        the seated sessions' live pages, ``whole`` had nothing been
+        dropped."""
+        with self._mu:
+            self.kv_positions_held += held
+            self.kv_positions_whole += whole
 
     def note_prefill_chunk(self) -> None:
         with self._mu:
@@ -374,6 +401,14 @@ class ServingStats:
                     "step_assignments": self.moe_step_assignments,
                     "page_expert_rows": self.moe_page_expert_rows,
                     "page_count": self.moe_page_count,
+                },
+                "window": {
+                    "pages_shipped": self.window_pages_shipped,
+                    "pages_dropped": self.window_pages_dropped,
+                },
+                "kv": {
+                    "positions_held": self.kv_positions_held,
+                    "positions_whole": self.kv_positions_whole,
                 },
                 "preempts": dict(self.preempts),
                 "ttft": {

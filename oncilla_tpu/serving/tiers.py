@@ -49,6 +49,7 @@ import itertools
 import threading
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from oncilla_tpu.core.errors import (
@@ -96,7 +97,8 @@ class FrozenPageHandle:
 
 @dataclass
 class Page:
-    """One KV page: fixed-size bytes living in exactly one tier."""
+    """One KV page: ``nbytes`` bytes, the store's ``page_bytes`` at most,
+    living in exactly one tier."""
 
     page_id: int
     nbytes: int
@@ -114,8 +116,23 @@ class Page:
     freed: bool = field(default=False, compare=False)
 
 
+def _page_bytes(data) -> tuple:
+    """A page's bytes as a flat uint8 vector, and how many: a ``jax.Array``
+    that is one already stays where it lies, on the device."""
+    if not (isinstance(data, jax.Array) and data.dtype == np.uint8
+            and data.ndim == 1):
+        data = np.ascontiguousarray(np.asarray(data)).view(
+            np.uint8).reshape(-1)
+    return data, int(data.size)
+
+
 class TieredPageStore:
-    """Fixed-page-size store over three tiers with watermark demotion.
+    """Page store over three tiers with watermark demotion. A page takes
+    one slot of ``page_bytes`` in its tier's arena whatever its own size
+    (so pages of unlike size leave no holes, and a tier's capacity is a
+    count of pages); it keeps its own ``nbytes``, and that many are put,
+    got and moved. A family whose page comes in kinds
+    (``models/kv_paging.py::PageKind``) builds the store for its largest.
 
     Single-writer discipline: all tier *mutation* (alloc/promote/demote/
     free) happens on the engine thread; prefetch workers only ever fetch
@@ -207,7 +224,10 @@ class TieredPageStore:
         else:
             self.ctx.free(handle)
 
-    def _put(self, tier: Tier, handle: OcmAlloc, data: np.ndarray) -> None:
+    def _put(self, tier: Tier, handle: OcmAlloc, data) -> None:
+        if tier != Tier.HOT:
+            # Only HOT takes a page where it lies on the device.
+            data = np.asarray(data)
         if tier == Tier.FROZEN:
             self.frozen_backend.write(
                 handle.key, np.asarray(data).tobytes(), meta={"kind": "page"}
@@ -287,20 +307,23 @@ class TieredPageStore:
                    prefer: Tier = Tier.HOT) -> Page:
         """Store one page of bytes, preferring ``prefer`` and degrading
         down-tier when the preferred arena is full, then enforce
-        watermarks."""
-        raw = np.ascontiguousarray(np.asarray(data)).view(
-            np.uint8).reshape(-1)
-        if raw.nbytes != self.page_bytes:
+        watermarks. ``data`` may be a uint8 vector that lies on the device
+        (a ``jax.Array``): a page sited in HOT is then written device to
+        device, and pulled to the host only for a tier below."""
+        data, nbytes = _page_bytes(data)
+        if not 0 < nbytes <= self.page_bytes:
             raise ValueError(
-                f"page is {raw.nbytes} B, store built for {self.page_bytes}"
+                f"page is {nbytes} B, store built for pages of "
+                f"{self.page_bytes} B at most"
             )
         return self._place(
-            lambda tier, handle: self._put(tier, handle, raw), shared, prefer
-        )
+            lambda tier, handle: self._put(tier, handle, data), shared,
+            prefer, nbytes)
 
-    def _place(self, fill, shared: bool, prefer: Tier) -> Page:
-        """Site a new page by the tier policy; ``fill(tier, handle)``
-        writes its bytes into the extent the policy chose."""
+    def _place(self, fill, shared: bool, prefer: Tier, nbytes: int) -> Page:
+        """Site a new page of ``nbytes`` by the tier policy;
+        ``fill(tier, handle)`` writes its bytes into the extent the policy
+        chose."""
         start = _ORDER.index(prefer)
         last_err: Exception | None = None
         for tier in _ORDER[start:]:
@@ -318,7 +341,7 @@ class TieredPageStore:
                 printd("serving: %s tier alloc degraded: %s", tier.value, e)
                 continue
             fill(tier, handle)
-            page = Page(next(self._ids), self.page_bytes, tier, handle,
+            page = Page(next(self._ids), nbytes, tier, handle,
                         shared=shared)
             self.touch(page)
             self.pages[page.page_id] = page
@@ -349,10 +372,9 @@ class TieredPageStore:
                 f"write to shared page {page.page_id} with {page.refs} "
                 "live reference(s); copy-on-write first"
             )
-        raw = np.ascontiguousarray(np.asarray(data)).view(
-            np.uint8).reshape(-1)
-        if raw.nbytes != page.nbytes:
-            raise ValueError(f"page write of {raw.nbytes} B into "
+        raw, nbytes = _page_bytes(data)
+        if nbytes != page.nbytes:
+            raise ValueError(f"page write of {nbytes} B into "
                              f"{page.nbytes} B page")
         self._put(page.tier, page.handle, raw)
         page.version += 1
@@ -376,7 +398,8 @@ class TieredPageStore:
         # between the tier choice and the copy.
         self.pin(page)
         try:
-            clone = self._place(fill, shared=False, prefer=Tier.HOT)
+            clone = self._place(fill, shared=False, prefer=Tier.HOT,
+                                nbytes=page.nbytes)
         finally:
             self.unpin(page)
         self.stats.note_cow()

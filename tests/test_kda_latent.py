@@ -478,7 +478,7 @@ def test_the_pool_snaps_to_its_bucket_and_seating_hands_on_its_changes(tiny):
 
         def pooled(batch):
             out = batch_pool(batch)
-            pools.append((eng._pool[0].shape[0],
+            pools.append((eng._pool[0][0].shape[0],
                           len({(e.page.page_id, e.version) for s in batch
                                for e in s.entries if not e.pending_fill})))
             return out

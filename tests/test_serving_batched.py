@@ -683,9 +683,11 @@ def watch_pool(eng):
     inner = eng._batch_pool
 
     def watched(batch):
-        held = set(eng._pool_slots)
+        held = set(eng._pool_slots[0])
         cap0 = None if eng._pool_k is None else eng._pool_k.shape[0]
-        pool_k, pool_v, table, tables = inner(batch)
+        # the dense family's page has one kind: one pool, one table
+        pools, tabs, by_kind = inner(batch)
+        ((pool_k, pool_v),), (table,), (tables,) = pools, tabs, by_kind
         pk, pv = np.asarray(pool_k), np.asarray(pool_v)
         if cap0 != pk.shape[0]:
             held = set()
@@ -701,7 +703,7 @@ def watch_pool(eng):
                     if key not in held:
                         seated[key] = mine
                 row = table[b, i]
-                assert eng._pool_slots[key] == row
+                assert eng._pool_slots[0][key] == row
                 assert np.array_equal(pk[row], seated[key][0])
                 assert np.array_equal(pv[row], seated[key][1])
                 if not (np.array_equal(pk[row], mine[0])
@@ -710,10 +712,11 @@ def watch_pool(eng):
                     assert e.extent is not None
                     np.testing.assert_allclose(pk[row], mine[0], atol=1e-5)
                     np.testing.assert_allclose(pv[row], mine[1], atol=1e-5)
-        assert len(set(eng._pool_slots.values())) == len(eng._pool_slots)
-        assert not set(eng._pool_slots.values()) & set(eng._pool_free)
+        slots = eng._pool_slots[0]
+        assert len(set(slots.values())) == len(slots)
+        assert not set(slots.values()) & set(eng._pool_free[0])
         calls.append((held, cap0, keys, pk.shape[0]))
-        return pool_k, pool_v, table, tables
+        return pools, tabs, by_kind
 
     eng._batch_pool = watched
     return calls, inexact
@@ -803,7 +806,7 @@ def test_promoted_page_gets_its_row_rewritten(tiny_model):
         entry = sess.entries[0]
         old_key = (entry.page.page_id, entry.version)
         before = eng.stats.snapshot()["pool"]
-        assert old_key in eng._pool_slots
+        assert old_key in eng._pool_slots[0]
         # Demoted under the seated session: the next step faults it back
         # with a new version, i.e. a new key for the same page.
         store.demote(entry.page, Tier.WARM)
@@ -811,9 +814,9 @@ def test_promoted_page_gets_its_row_rewritten(tiny_model):
         new_key = (entry.page.page_id, entry.version)
         after = eng.stats.snapshot()["pool"]
         assert new_key != old_key and new_key[0] == old_key[0]
-        assert new_key in eng._pool_slots
+        assert new_key in eng._pool_slots[0]
         # two rows, two slots: the stale key gave its row up
-        assert old_key not in eng._pool_slots
+        assert old_key not in eng._pool_slots[0]
         assert after["rows_written"] == before["rows_written"] + 1
         assert after["rows_reused"] == before["rows_reused"] + 1
         assert after["rebuilds"] == before["rebuilds"] == 1
@@ -855,9 +858,9 @@ class ArraysWatch:
         match, ship = eng._match_more, eng._ship
         rebuild, finish, tick = eng._rebuild, eng._finish, eng._tick
 
-        def counted_unpack(data):
+        def counted_unpack(data, kind=0):
             self.unpacks += 1
-            return unpack(data)
+            return unpack(data, kind)
 
         def counted_read(page, out=None):
             self.hot_reads += page.tier == Tier.HOT
@@ -1069,8 +1072,8 @@ def test_a_page_lost_under_two_sharers_is_rebuilt_once(tiny_model, case):
                 == before["arrays"]["pages_rebuilt"] + 1)
         assert after["pool"]["rows_written"] == (
             before["pool"]["rows_written"] + 1)
-        assert new_key in eng._pool_slots
-        assert old_key not in eng._pool_slots
+        assert new_key in eng._pool_slots[0]
+        assert old_key not in eng._pool_slots[0]
         assert after["moves"]["promote"] - before["moves"]["promote"] == (
             case == "demoted")
         # (the engine's weak table must not see this test's references)
@@ -1156,8 +1159,14 @@ def test_steady_decode_keeps_every_seat(tiny_model, monkeypatch):
     prompts = [rng.integers(1, cfg.vocab, 5).tolist() for _ in range(4)]
     new = 3 * P
     watch = SeatWatch(monkeypatch)
-    # What the engine itself asks of jax.numpy by name, after the first step.
+    # What the engine itself asks of jax.numpy by name, after the first step,
+    # and the ships' one packing program.
     asked = dict.fromkeys(("concatenate", "stack"), 0)
+    packs = []
+    pack = engine_mod._pack_pages_jit
+    monkeypatch.setattr(
+        engine_mod, "_pack_pages_jit",
+        lambda kinds, dtype: packs.append(len(kinds)) or pack(kinds, dtype))
 
     class CountingJnp:
         def __getattr__(self, name):
@@ -1202,7 +1211,10 @@ def test_steady_decode_keeps_every_seat(tiny_model, monkeypatch):
     assert watch.widths == [4]
     assert watch.calls == {"_seat_write_jit": 0, "_seat_move_jit": 0,
                            "_seat_read_jit": 4 * 3}
-    assert asked == {"concatenate": 0, "stack": 4 * 3}
+    # ... and one dispatch that packs the page for the store (its stack is
+    # traced once, if no earlier engine had it traced).
+    assert packs == [1] * (4 * 3)
+    assert asked["concatenate"] == 0 and asked["stack"] <= 1
     monkeypatch.undo()
     outs = held_to_reference(tiny_model, prompts, results)
     assert [len(outs[f"t{i}"]) for i in range(4)] == [new] * 4
@@ -1214,7 +1226,7 @@ def published_partial(eng, prompt):
     ext = eng.prefix.child(None, tuple(prompt))
     assert ext is not None and ext.fill == len(prompt) < P
     raw = np.array(eng.store.read_page(ext.page), copy=True)
-    return raw.view(np.float32).reshape(eng.page_shape)
+    return raw.view(np.float32).reshape(eng.page_shapes[0])
 
 
 def test_partial_from_a_used_seat_is_zeros_beyond_its_fill(tiny_model):
@@ -1301,7 +1313,7 @@ def test_a_seat_changing_hands_serves_the_reference_tokens(
                 eng._tick()
             eng._unseat(t0)
             assert t0.seat is None and eng._seats[0] is None
-            assert all(t.shape == eng._tail_shape for t in t0.tails)
+            assert all(t.shape == eng._leaf_shapes[0] for t in t0.tails)
             assert any(np.asarray(t).any() for t in t0.tails) != want_empty
             calls0 = dict(watch.calls)
             eng._tick()
