@@ -55,7 +55,12 @@ class PagedFamily:
     leaves, both return ``(logits, new tails, aux)``. ``aux`` is None, or
     for a family with experts a () int32 on the device: the distinct
     (layer, expert) pairs that received a real token. ``write_row(pool,
-    page, slot)`` writes one page into a pool row in place.
+    page, slot)`` writes one page into a pool row in place; the engine
+    does not call it, not even as the group of one: it brings its pool up
+    to a batch with two programs of its own, the same for every family
+    (``serving.engine._pool_write_jit`` writes several pages a dispatch,
+    ``_pool_gather_jit`` carries the rows over a change of capacity).
+    The benchmark's warmers call it, a kind at a time.
     ``assignments_per_token(cfg)``, for a family whose programs hand an
     ``aux`` back, is how many (layer, expert) pairs one token is routed
     to.
@@ -80,7 +85,7 @@ class PagedFamily:
     ctx_len, ctx_start, ctx_len, ctx_start, ...]``, a page's ``[pos0,
     ctx_start, ctx_start, ...]``. A family that says nothing has one kind
     of every cached layer, its ``table`` is the one array and its ``meta``
-    what it has always been. ``write_row`` is called a kind at a time.
+    what it has always been.
 
     ``context(pages, cfg, page_tokens)`` gives a family its own way of
     joining a session's pages into the page program's ``ctx``: ``pages``
@@ -92,7 +97,7 @@ class PagedFamily:
     leaf_dims: object
     step: object
     page: object
-    write_row: object
+    write_row: object = None
     assignments_per_token: object = None
     cached_layers: object = None
     carry_leaves: object = None
@@ -551,7 +556,10 @@ def paged_pool_write_row_jit(
     place: both pools are donated and ``slot`` is traced, so one compiled
     program per pool shape serves every row. A row is a byte copy of
     the page (``dynamic_update_slice``), so a pool kept up to date this
-    way is bitwise the pool stacked from the same pages."""
+    way is bitwise the pool stacked from the same pages. The engine writes
+    its pool several pages a dispatch with a program of its own
+    (``serving.engine._pool_write_jit``, the same update a page); this one
+    stays behind ``PagedFamily.write_row`` for the benchmark's warmers."""
     at = (slot, 0, 0, 0, 0)
     return (
         jax.lax.dynamic_update_slice(pool_k, page_k[None, :, 0], at),

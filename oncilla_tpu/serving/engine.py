@@ -58,11 +58,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from oncilla_tpu.core.hbm import from_bytes, to_bytes
+from oncilla_tpu.core.hbm import _pow2_chunks, from_bytes, to_bytes
 from oncilla_tpu.models import (
     paged_decode_batch_step_jit,
     paged_decode_page_jit,
-    paged_pool_write_row_jit,
 )
 from oncilla_tpu.models.kv_paging import PagedFamily
 from oncilla_tpu.obs import journal as obs_journal
@@ -103,13 +102,9 @@ def _dense_page(params, tokens_page, meta, ctx, tails, cfg):
     return logits, (tail_k, tail_v), None
 
 
-def _dense_write_row(pool, page, slot):
-    return paged_pool_write_row_jit(*pool, *page, slot)
-
-
 DENSE_FAMILY = PagedFamily(
     n_leaves=2, leaf_dims=_dense_leaf_dims, step=_dense_step,
-    page=_dense_page, write_row=_dense_write_row,
+    page=_dense_page,
 )
 
 
@@ -151,6 +146,42 @@ def _pack_pages_jit(kinds: tuple, dtype: str) -> tuple:
     to bytes (``core.hbm.to_bytes``). Returns a uint8 vector a kind."""
     return tuple(to_bytes(jnp.stack(leaves).astype(jnp.dtype(dtype)))
                  for leaves in kinds)
+
+
+# The fused step's page pool, one array of rows (capacity, L, KV, P, Hd) a
+# leaf, is brought up to a batch by these two programs, for every family
+# alike (:meth:`ServingEngine._kind_pool`).
+
+#: The most pages one dispatch of :func:`_pool_write_jit` writes. A tick's
+#: new pages go in power-of-two groups up to this many (as
+#: ``core.hbm._pow2_chunks`` cuts a fill), so a pool shape has five write
+#: programs and no group is padded. On the chip a dispatch costs the host
+#: 0.43-0.47 ms from 4 pages to 16 of the dense family (0.46 for one row),
+#: and a row written twice is not free: 0.27 ms of device time in the
+#: latent family's pool (PERF.md section 6, PR 37).
+_POOL_GROUP = 16
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _pool_write_jit(pool: tuple, pages: tuple, slots: jax.Array) -> tuple:
+    """Write a group of pages, (L, 1, KV, P, Hd) a leaf each, into the rows
+    ``slots`` of the pool, in place: the pool is donated and the slots are
+    traced, so one program a pool shape and group size serves every row. A
+    row is a byte copy of its page (``dynamic_update_slice``), so a pool
+    kept up to date this way is bitwise the pool stacked from the same
+    pages."""
+    for g, page in enumerate(pages):
+        at = (slots[g], 0, 0, 0, 0)
+        pool = tuple(jax.lax.dynamic_update_slice(rows, leaf[None, :, 0], at)
+                     for rows, leaf in zip(pool, page))
+    return pool
+
+
+@jax.jit
+def _pool_gather_jit(pool: tuple, idx: jax.Array) -> tuple:
+    """A pool of ``len(idx)`` rows made from another's: row ``i`` is the old
+    pool's row ``idx[i]``, a byte copy, every leaf in the one dispatch."""
+    return tuple(rows.at[idx].get(mode="promise_in_bounds") for rows in pool)
 
 
 def _zero_carry(family: PagedFamily, cfg, batch: int) -> tuple | None:
@@ -323,7 +354,7 @@ class _Entry:
     (:meth:`ServingEngine._entry_of`), so the arrays are built once a
     ``(page, version)`` and every holder reads the same ones. Sharing them
     is safe: device arrays are immutable and no program donates a page's
-    arrays (``_context`` concatenates, the pool's row write copies)."""
+    arrays (``_context`` concatenates, the pool's group write copies)."""
 
     page: Page
     extent: SharedExtent | None = None
@@ -487,7 +518,8 @@ class ServingEngine:
         self._pool: list = [None] * n_kinds
         self._pool_slots: list[dict] = [{} for _ in self.kinds]
         self._pool_free: list[list] = [[] for _ in self.kinds]
-        # Pool capacities whose row-write program has already run.
+        # Pool capacities whose programs, and whose neighbours', have
+        # already run (:meth:`_warm_pool`).
         self._pool_write_ready: list[set] = [set() for _ in self.kinds]
         # The seated sessions' tails, kept on the device between ticks
         # (see _seat_batch): one stack (L, b_pad, KV, P, Hd) a leaf, the
@@ -1077,21 +1109,21 @@ class ServingEngine:
         its (page_id, version), and table[b] lists session b's rows. A
         page that has a row keeps it, with no device work; a page without
         one takes a free row, or the row of the page seated longest ago
-        that this batch does not reference, and ONE dispatch of the
-        family's row write (:func:`paged_pool_write_row_jit`) writes its
-        leaves there in place. So a session that loses its seat for a tick
-        finds its rows again. ``capacity`` and MP snap to power-of-two
-        buckets of this batch's rows; when the capacity bucket changes,
-        the batch's pages are written into a fresh pool
-        (:meth:`_new_pool`). ``rows`` is the batch's distinct pages by
-        key, ``keys`` each session's keys in context order."""
+        that this batch does not reference, and the tick's new pages are
+        written there in place, up to ``_POOL_GROUP`` a dispatch
+        (:func:`_pool_write_jit`, in power-of-two groups).
+        So a session that loses its seat for a tick finds its rows again.
+        ``capacity`` and MP snap to power-of-two buckets of this batch's
+        rows; when the capacity bucket changes, the rows are carried over
+        on the device in one dispatch (:meth:`_new_pool`) and only the
+        pages that had no row are written. ``rows`` is the batch's distinct
+        pages by key, ``keys`` each session's keys in context order."""
         max_pages = max((len(t) for t in keys), default=0)
         mp = _pow2(max_pages) if max_pages else 0
         capacity = _pow2(len(rows)) if rows else 1
-        rebuilt = (self._pool[k] is None
-                   or self._pool[k][0].shape[0] != capacity)
-        if rebuilt:
-            self._new_pool(k, capacity)
+        first = self._pool[k] is None
+        rebuilt = first or self._pool[k][0].shape[0] != capacity
+        carried = self._new_pool(k, capacity, rows) if rebuilt else 0
         slots, free = self._pool_slots[k], self._pool_free[k]
         fresh = []
         for key in rows:
@@ -1103,41 +1135,80 @@ class ServingEngine:
             # Every seated key is behind the unseated ones by now, and the
             # batch has at most `capacity` keys: with no row free, the
             # oldest key is one this batch does not reference.
-            slot = free.pop() if free else slots.pop(next(iter(slots)))
-            self._pool[k] = self.family.write_row(
-                self._pool[k], rows[key], np.int32(slot))
-            slots[key] = slot
-        self.stats.note_pool(reused=len(rows) - len(fresh),
-                             written=len(fresh), rebuilt=rebuilt)
+            slots[key] = free.pop() if free else slots.pop(next(iter(slots)))
+        groups = _pow2_chunks(len(fresh), _POOL_GROUP)
+        at = 0
+        for n in groups:
+            group, at = fresh[at:at + n], at + n
+            self._pool[k] = _pool_write_jit(
+                self._pool[k], tuple(rows[key] for key in group),
+                np.asarray([slots[key] for key in group], np.int32))
+        self.stats.note_pool(
+            reused=len(rows) - len(fresh), written=len(fresh),
+            rebuilt=rebuilt, group_writes=len(groups),
+            gathers=int(rebuilt and not first), carried=carried)
         table = np.zeros((len(keys), mp), np.int32)
         for b, trow in enumerate(keys):
             table[b, :len(trow)] = [slots[key] for key in trow]
         return table
 
-    def _new_pool(self, k: int, capacity: int) -> None:
-        """Replace kind ``k``'s pool by zeros of ``capacity`` rows, all
-        free. The rare path: the first pool, and a crossing of a
-        power-of-two row count. No program may be built when a page is
-        first written in place, at whatever tick that is: the row write
-        runs once here on scratch zeros of this capacity and of the next
-        one up."""
+    def _new_pool(self, k: int, capacity: int, rows: dict) -> int:
+        """Give kind ``k`` a pool of ``capacity`` rows. The rare path: the
+        first pool, which is zeros with every row free, and a crossing of a
+        power-of-two row count, which makes the new pool from the old one
+        on the device in ONE dispatch (:func:`_pool_gather_jit`): on growth
+        every key keeps a row, on a shrink the keys of ``rows``, this
+        batch's, do and the others lose theirs; the keys are renumbered
+        from row 0 in the order they had, least recently seated first. A
+        row no key owns holds whatever the gather left there: the step
+        reads a row only through a table entry. Old and new pool are alive
+        together while the gather runs. Returns the rows carried over."""
+        self._warm_pool(k, capacity)
+        old, slots = self._pool[k], self._pool_slots[k]
+        if old is None:
+            kept = []
+            self._pool[k] = self._zero_pool(k, capacity)
+        else:
+            grown = capacity > old[0].shape[0]
+            kept = [key for key in slots if grown or key in rows]
+            idx = np.zeros(capacity, np.int32)
+            idx[:len(kept)] = [slots[key] for key in kept]
+            self._pool[k] = _pool_gather_jit(old, idx)
+        self._pool_slots[k] = dict(zip(kept, range(len(kept))))
+        self._pool_free[k] = list(range(capacity - 1, len(kept) - 1, -1))
+        return len(kept)
+
+    def _zero_pool(self, k: int, capacity: int) -> tuple:
+        """Zeros of kind ``k``'s pool at ``capacity`` rows."""
         dt = jnp.dtype(self.cfg.dtype)
-        pages = self._leaf_shapes[self._kind_leaves[k]]    # (L, 1, KV, P, Hd)
+        return tuple(jnp.zeros((capacity, page[0]) + page[2:], dt)
+                     for page in self._leaf_shapes[self._kind_leaves[k]])
 
-        def zeros(n: int) -> tuple:
-            return tuple(jnp.zeros((n, page[0]) + page[2:], dt)
-                         for page in pages)
-
-        # The old pool's memory goes before the new pool is made.
-        self._pool[k] = None
-        for n in (capacity, 2 * capacity):
-            if n not in self._pool_write_ready[k]:
-                zpage = tuple(jnp.zeros(page, dt) for page in pages)
-                self.family.write_row(zeros(n), zpage, np.int32(0))
-                self._pool_write_ready[k].add(n)
-        self._pool[k] = zeros(capacity)
-        self._pool_slots[k] = {}
-        self._pool_free[k] = list(range(capacity - 1, -1, -1))
+    def _warm_pool(self, k: int, capacity: int) -> None:
+        """No program may be built when a page is first written in place or
+        a crossing first carries the rows over, at whatever tick that is:
+        when a capacity is first reached, the group writes at it, at half
+        and at twice it and the gathers between them run once here, on one
+        scratch pool of zeros handed from call to call. (A crossing that
+        skips a capacity builds its gather where it falls.)"""
+        ready = self._pool_write_ready[k]
+        if capacity in ready:
+            return
+        # A neighbour that was reached has run what lies between the two.
+        sizes = [n for n in (capacity // 2, capacity, 2 * capacity)
+                 if n and (n == capacity or n not in ready)]
+        dt = jnp.dtype(self.cfg.dtype)
+        page = tuple(jnp.zeros(shape, dt)
+                     for shape in self._leaf_shapes[self._kind_leaves[k]])
+        scratch = self._zero_pool(k, sizes[0])
+        for n in sizes[1:] + sizes[-2::-1]:          # up, then down again
+            g = _POOL_GROUP
+            while g:                                 # every group size
+                scratch = _pool_write_jit(scratch, (page,) * g,
+                                          np.zeros(g, np.int32))
+                g //= 2
+            scratch = _pool_gather_jit(scratch, np.zeros(n, np.int32))
+        ready.add(capacity)
 
     def _batch_step(self, batch: list[_Session]) -> None:
         """ONE fused jit dispatch advancing every seated session by one
@@ -1371,7 +1442,7 @@ class ServingEngine:
     def _new_tails(self, b_pad: int) -> None:
         """Replace the tail stack by zeros of ``b_pad`` seats, all vacant:
         the first step, and a change of the padded batch size. As in
-        :meth:`_new_pool`, no program may be built when a seat first
+        :meth:`_warm_pool`, no program may be built when a seat first
         changes hands, at whatever tick that is: the three seat programs
         run once here on scratch zeros of this width and of the next one
         up (the widest is ``max_batch``'s)."""

@@ -87,6 +87,12 @@ class ServingStats:
         self.pool_rows_reused = 0
         self.pool_rows_written = 0
         self.pool_rebuilds = 0
+        # The dispatches the pool took: group writes (several new rows
+        # each), gathers (a crossing of the row bucket: the new pool made
+        # from the old one) and the rows the gathers carried over.
+        self.pool_group_writes = 0
+        self.pool_gathers = 0
+        self.pool_rows_carried = 0
         # The seated sessions' tails, kept in one stack on the device
         # between ticks: seats of a fused step whose tail the stack
         # already held (an empty tail is held by any seat), and seats
@@ -249,14 +255,21 @@ class ServingStats:
             self.preempts[reason] = self.preempts.get(reason, 0) + 1
 
     def note_pool(self, reused: int = 0, written: int = 0,
-                  rebuilt: bool = False) -> None:
-        """One tick's page pool: ``reused`` rows kept their slot,
-        ``written`` rows went to the device; ``rebuilt`` when they went
-        into a new pool (the first, or the row bucket changed)."""
+                  rebuilt: bool = False, group_writes: int = 0,
+                  gathers: int = 0, carried: int = 0) -> None:
+        """One tick's page pool: ``reused`` rows of the batch had a row
+        already (one carried over a crossing too: nothing was written for
+        it), ``written`` rows went to the device; ``rebuilt`` when the pool
+        was made anew (the first, or the row bucket changed). The
+        dispatches that took: ``group_writes``, and ``gathers`` that
+        ``carried`` rows into the new pool, the batch's or not."""
         with self._mu:
             self.pool_rows_reused += reused
             self.pool_rows_written += written
             self.pool_rebuilds += int(rebuilt)
+            self.pool_group_writes += group_writes
+            self.pool_gathers += gathers
+            self.pool_rows_carried += carried
 
     def note_tails(self, kept: int = 0, written: int = 0) -> None:
         """One fused step's seats: ``kept`` cost nothing, ``written``
@@ -383,6 +396,11 @@ class ServingStats:
                     "rows_reused": self.pool_rows_reused,
                     "rows_written": self.pool_rows_written,
                     "rebuilds": self.pool_rebuilds,
+                },
+                "pool_dispatches": {
+                    "group_writes": self.pool_group_writes,
+                    "gathers": self.pool_gathers,
+                    "rows_carried": self.pool_rows_carried,
                 },
                 "tails": {
                     "seats_kept": self.tails_seats_kept,

@@ -32,6 +32,7 @@ from jax._src import cache_key  # noqa: E402
 from oncilla_tpu.models import kda_latent as kl  # noqa: E402
 from oncilla_tpu.models import kv_paging, llama  # noqa: E402
 from oncilla_tpu.models import latent_moe as lm  # noqa: E402
+from oncilla_tpu.models import swa_moe as sm  # noqa: E402
 from oncilla_tpu.serving import engine as eng  # noqa: E402
 
 P, B, MP, N = 4, 2, 2, 4
@@ -91,10 +92,34 @@ out["kda.page"] = key(kl.kda_decode_page_jit.lower(
     p3, z((1, P), i32), z((2,), i32), z((Lm, 1, 1, 2 * P, W3)),
     z((Lm, 1, 1, P, W3)), *carry(1), c3))
 
+c4 = sm.SwaMoeConfig.tiny()
+p4 = sm.init_params(jax.random.key(0), c4)
+
+
+def swa(rows=None, batch=1, tokens=P):
+    """The family's leaves, kind by kind: pool rows, or a tail or context."""
+    return tuple(z((rows, s[0]) + s[2:]) if rows else z(s)
+                 for s in sm.PAGED_FAMILY.leaf_shapes(c4, tokens, batch))
+
+
+out["swa.step"] = key(sm.swa_decode_batch_step_jit.lower(
+    p4, z((B,), i32), z((B, 6), i32), np.int32(B), swa(rows=N),
+    (z((B, MP), i32), z((B, MP), i32)), swa(batch=B), c4))
+out["swa.page"] = key(sm.swa_decode_page_jit.lower(
+    p4, z((1, P), i32), z((3,), i32), swa(tokens=2 * P), swa(), c4))
+
 stack = (z(tail), z(tail))
 out["seat.write"] = key(eng._seat_write_jit.lower(
     stack, (z((L, 1, KV, P, Hd)),) * 2, np.int32(0)))
 out["seat.move"] = key(eng._seat_move_jit.lower(
     stack, np.int32(0), np.int32(1)))
 out["seat.read"] = key(eng._seat_read_jit.lower(stack, np.int32(0)))
+# The pool's own two programs (PR 37; a tree from before has neither).
+if hasattr(eng, "_pool_write_jit"):
+    page = (z((L, 1, KV, P, Hd)),) * 2
+    out["pool.write"] = key(eng._pool_write_jit.lower(
+        (z(row), z(row)), (page,) * eng._POOL_GROUP,
+        np.zeros(eng._POOL_GROUP, np.int32)))
+    out["pool.gather"] = key(eng._pool_gather_jit.lower(
+        (z(row), z(row)), np.zeros(2 * N, np.int32)))
 print(json.dumps(out, indent=1))

@@ -689,8 +689,6 @@ def watch_pool(eng):
         pools, tabs, by_kind = inner(batch)
         ((pool_k, pool_v),), (table,), (tables,) = pools, tabs, by_kind
         pk, pv = np.asarray(pool_k), np.asarray(pool_v)
-        if cap0 != pk.shape[0]:
-            held = set()
         keys = []
         for b, sess in enumerate(batch):
             live = [e for e in sess.entries if not e.pending_fill]
@@ -724,8 +722,9 @@ def watch_pool(eng):
 
 def expected_pool_counters(calls):
     """The counters the logged calls must have produced: a key is written
-    when the pool did not hold it (a new pool holds nothing), reused when
-    it did; a pool is built when the row bucket changes."""
+    when the pool did not hold it, reused when it did (a change of the row
+    bucket carries the batch's rows over: nothing is written for them); a
+    pool is built when the row bucket changes."""
     want = {"rows_reused": 0, "rows_written": 0, "rebuilds": 0}
     for held, cap0, keys, cap1 in calls:
         want["rebuilds"] += cap0 != cap1
@@ -1109,39 +1108,50 @@ def test_without_a_prefix_cache_no_page_is_shared(tiny_model):
 def test_pool_write_program_compiles_once_a_capacity(tiny_model, monkeypatch):
     import oncilla_tpu.serving.engine as engine_mod
     from oncilla_tpu.models import paged_decode_batch_step_jit as step
-    from oncilla_tpu.models import paged_pool_write_row_jit as write
 
+    write, gather = engine_mod._pool_write_jit, engine_mod._pool_gather_jit
     cfg, _ = tiny_model
     rng = np.random.default_rng(79)
     prompts = [rng.integers(1, cfg.vocab, ln).tolist()
                for ln in (5, 9, 17, 25, 30)]
     # The programs' own cache is process-wide (a whole run's other tests
-    # have filled it), so count what THIS workload hands the row write: one
-    # program a distinct pool shape.
-    shapes = []
+    # have filled it), so count what THIS workload hands the group write
+    # and the gather: one program a distinct pool shape, or pair of them.
+    shapes, crossings = [], []
 
-    def recording(pool_k, pool_v, *rest):
-        shapes.append(pool_k.shape)
-        return write(pool_k, pool_v, *rest)
+    def recording(pool, pages, slots):
+        assert len(pages) == len(slots) <= engine_mod._POOL_GROUP
+        shapes.append(pool[0].shape)
+        return write(pool, pages, slots)
 
-    monkeypatch.setattr(engine_mod, "paged_pool_write_row_jit", recording)
+    def crossing(pool, idx):
+        crossings.append((pool[0].shape[0], len(idx)))
+        return gather(pool, idx)
+
+    monkeypatch.setattr(engine_mod, "_pool_write_jit", recording)
+    monkeypatch.setattr(engine_mod, "_pool_gather_jit", crossing)
 
     def workload():
         return run_prompts(tiny_model, prompts, new_tokens=12,
                            share=False, hot=8, warm=8, max_active=5)
 
     outs, meta, _ = workload()
-    built = (step._cache_size(), write._cache_size())
-    # one write program a pool capacity: those the run reached (1..16 rows)
-    # and the next one up, never one a row or a tick
+    built = (step._cache_size(), write._cache_size(), gather._cache_size())
+    # the write programs of a pool capacity: those the run reached (1..16
+    # rows) and their neighbours, never one a row or a tick; one gather a
+    # pair of capacities, mostly neighbours
     assert meta["pool"]["rebuilds"] >= 2
     assert 0 < len(set(shapes)) <= 6 < meta["pool"]["rows_written"]
     assert {s[0] for s in shapes} <= {1, 2, 4, 8, 16, 32}
-    # a second identical workload builds nothing more, for the fused step
-    # and for the row write
+    assert {max(a, b) // min(a, b) for a, b in crossings} <= {2, 4}
+    assert meta["pool_dispatches"]["gathers"] == meta["pool"]["rebuilds"] - 1
+    # a second identical workload builds nothing more, for the fused step,
+    # the group write and the gather
     outs2, meta2, _ = workload()
-    assert (step._cache_size(), write._cache_size()) == built
+    assert (step._cache_size(), write._cache_size(),
+            gather._cache_size()) == built
     assert outs2 == outs and meta2["pool"] == meta["pool"]
+    assert meta2["pool_dispatches"] == meta["pool_dispatches"]
 
 
 # -- 8. the seated sessions' tails kept in one stack between ticks ----------
