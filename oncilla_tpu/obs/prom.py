@@ -245,6 +245,31 @@ def _serving_samples(doc: "_Doc", srv: dict, rank) -> None:
                        name=fam + "_sum", rank=rank, engine=name)
             doc.sample(fam, "histogram", help_, n,
                        name=fam + "_count", rank=rank, engine=name)
+        itl = eng.get("itl")
+        if itl and itl.get("count"):
+            # The gaps are filed a bucket each with their parts; summed
+            # from the bottom the counts are cumulative, over the bounds
+            # that hold a gap (serving/metrics.py: GAP_BUCKETS, a quarter
+            # of an octave apart; the parts stay in the snapshot).
+            n = itl["count"]
+            fam = "ocm_serving_itl_seconds"
+            help_ = ("Engine time between two tokens of one session, "
+                     "tick end to tick end (cumulative histogram).")
+            below = 0
+            for le, bucket in sorted(
+                    (float(le), b) for le, b in itl.get("hist", {}).items()):
+                below += bucket["count"]
+                if le != float("inf"):
+                    doc.sample(fam, "histogram", help_, below,
+                               name=fam + "_bucket", rank=rank, engine=name,
+                               le=_num(le))
+            doc.sample(fam, "histogram", help_, n,
+                       name=fam + "_bucket", rank=rank, engine=name,
+                       le="+Inf")
+            doc.sample(fam, "histogram", help_, itl.get("sum_s", 0.0),
+                       name=fam + "_sum", rank=rank, engine=name)
+            doc.sample(fam, "histogram", help_, n,
+                       name=fam + "_count", rank=rank, engine=name)
         for reason, n in sorted(eng.get("preempts", {}).items()):
             doc.sample("ocm_serving_preempts_total", "counter",
                        "Batch-slot preemptions by reason (slot = lost "

@@ -67,10 +67,16 @@ from oncilla_tpu.models.kv_paging import PagedFamily
 from oncilla_tpu.obs import journal as obs_journal
 from oncilla_tpu.qos.policy import PRIO_NORMAL
 from oncilla_tpu.serving import metrics as serving_metrics
-from oncilla_tpu.serving.metrics import ServingStats
+from oncilla_tpu.serving.metrics import GAP_PHASES, ServingStats
 from oncilla_tpu.serving.prefix import PrefixCache, SharedExtent
 from oncilla_tpu.serving.tiers import Page, Tier, TieredPageStore
 from oncilla_tpu.utils.debug import GLOBAL_TRACER, printd
+
+
+# Where each phase lies in the tick's account (``ServingEngine._acct``):
+# the phases of ``metrics.GAP_PHASES``, then the time outside the engine.
+_CHUNK, _BUILD, _DEVICE, _SCATTER, _FINISH, _SCHED, _OUTSIDE = range(
+    len(GAP_PHASES) + 1)
 
 
 def _pow2(n: int) -> int:
@@ -401,6 +407,16 @@ class _Session:
         self.pages_done_t: float | None = None
         self.unseated_ticks = 0
         self.ttft_parts: dict | None = None
+        # The tails' anatomy: the engine's account and its count of ticks
+        # as they stood when the tick that made this session's last token
+        # closed its books (one tuple for all that tick's sessions; None
+        # before the first token), the same at admission (the account's
+        # engine seconds, summed), and the ``serve_prefill_chunk`` spans
+        # that were this session's own.
+        self.gap_mark: tuple | None = None
+        self.admit_engine_s = 0.0
+        self.admit_tick = 0
+        self.own_chunk_s = 0.0
         self._leaf_shapes = leaf_shapes
         self._tail_dt = jnp.dtype(dtype)
         #: The seat of the engine's tail stack that holds this session's
@@ -542,6 +558,17 @@ class ServingEngine:
         self.queue: list[Request] = []
         self.active: list[_Session] = []
         self.results: list[SessionResult] = []
+        # The tick's phase account (docs/OBSERVABILITY.md, "Serving tick
+        # anatomy"): seconds since this engine was built, an entry a phase
+        # (``_CHUNK`` ... ``_OUTSIDE``), summed from the ``dt`` of the spans
+        # a tick opens anyway; the ticks whose books are closed; the last
+        # tick's ``tick`` and ``tick.finish`` spans and the moment its books
+        # were closed; and the sessions that emitted in this tick.
+        self._acct = [0.0] * (len(GAP_PHASES) + 1)
+        self._ticks = 0
+        self._last_tick = None
+        self._mark_t = 0.0
+        self._emitted: list[_Session] = []
         # A stored page of each kind: its leaves stacked.
         self.page_shapes = tuple(
             (kind.n_leaves,) + self._leaf_shapes[sl.start]
@@ -636,6 +663,8 @@ class ServingEngine:
             req, self._leaf_shapes, self.cfg.dtype,
             _zero_carry(self.family, self.cfg, 1), len(self.kinds))
         sess.admit_t = time.perf_counter()
+        sess.admit_engine_s = sum(self._acct[:_OUTSIDE])
+        sess.admit_tick = self._ticks
         self._note_pages_done(sess)
         return sess
 
@@ -866,7 +895,9 @@ class ServingEngine:
         self time falls out of per-name totals (docs/OBSERVABILITY.md,
         "Serving tick anatomy")."""
         span = GLOBAL_TRACER.span
-        with span("tick"):
+        acct = self._acct
+        with span("tick") as tick:
+            named = sum(acct[:_FINISH])    # chunk, build, device, scatter
             with span("tick.admit"):
                 # Admission is priority-aware: PRIO_HIGH requests seat
                 # first when the queue outruns max_active (stable within
@@ -901,7 +932,7 @@ class ServingEngine:
                     continue
                 # Span per chunk: its phases (and any cold-tier dcn fetch
                 # spans the chunk faults on) tree under it.
-                with span("serve_prefill_chunk"):
+                with span("serve_prefill_chunk") as chunk:
                     # Re-probe the prefix cache first: a session earlier
                     # in this same tick may have shipped (and registered)
                     # exactly the page this one is about to compute —
@@ -913,15 +944,73 @@ class ServingEngine:
                         self._prefill_chunk(sess)
                         chunked = True
                     self._note_pages_done(sess)
+                acct[_CHUNK] += chunk.dt
+                sess.own_chunk_s += chunk.dt
             with span("tick.select"):
                 batch = self._select_batch(allow_force=not chunked)
             if batch:
                 self._batch_step(batch)
-            with span("tick.finish"):
+            with span("tick.finish") as finish:
                 for sess in self.active:
                     if sess.done:
                         self._finish(sess)
                 self.active = [s for s in self.active if not s.done]
+                self._close_books(tick, finish, sum(acct[:_FINISH]) - named)
+        self._last_tick = (tick, finish)
+
+    def _close_books(self, tick, finish, named: float) -> None:
+        """The end of a tick, inside ``tick.finish``: bring the account up
+        to this moment and put every token the tick made down to the phases
+        its gap spanned. ``named`` is what the tick's chunk, build, device
+        and scatter spans added; ``finish`` runs until now, and what is
+        left of the tick is ``sched``. The one clock read a tick: a gap runs
+        from one tick's reading to another's, which is where the caller of
+        :meth:`_tick` stamps its tokens, so the entries of the account add
+        up to the wall time between two readings without remainder. What
+        the last tick did after its reading is known now that its spans
+        are closed: the rest of its ``tick.finish`` (these books) is
+        ``finish`` and the closing of ``tick`` is ``sched``, so each phase
+        is the total of its spans; from its end to this tick's start the
+        engine was not running: ``outside``."""
+        now = time.perf_counter()
+        acct = self._acct
+        if self._last_tick is not None:
+            last, last_finish = self._last_tick
+            finished = last_finish.t0 + last_finish.dt
+            ended = last.t0 + last.dt
+            acct[_FINISH] += finished - self._mark_t
+            acct[_SCHED] += ended - finished
+            acct[_OUTSIDE] += tick.t0 - ended
+        acct[_FINISH] += now - finish.t0
+        acct[_SCHED] += finish.t0 - tick.t0 - named
+        self._mark_t = now
+        self._ticks = n = self._ticks + 1
+        if not self._emitted:
+            return
+        # The sessions that last emitted in one tick hold one mark, and
+        # their gaps are one gap: a steady batch costs one subtraction.
+        mark = (tuple(acct), n)
+        engine_s = sum(acct[:_OUTSIDE])
+        same: dict[int, list] = {}      # id(mark) -> [mark, sessions]
+        firsts = []
+        for sess in self._emitted:
+            before, sess.gap_mark = sess.gap_mark, mark
+            if before is not None:
+                if id(before) in same:
+                    same[id(before)][1] += 1
+                else:
+                    same[id(before)] = [before, 1]
+            elif sess.ttft_parts is not None:
+                # A first token opens the session's first gap and closes
+                # none: that wait is the TTFT's (_note_first_token).
+                firsts.append((engine_s - sess.admit_engine_s,
+                               n - sess.admit_tick, sess.unseated_ticks,
+                               sess.own_chunk_s, sess.ttft_parts["queue_s"]))
+        self.stats.note_gaps(
+            [([now_s - then_s for now_s, then_s in zip(acct, then)],
+              n - tick_no, count) for (then, tick_no), count in same.values()],
+            firsts)
+        self._emitted = []
 
     def _note_first_token(self, sess: _Session) -> None:
         """TTFT: observed once per session, on its first emitted token
@@ -987,6 +1076,7 @@ class ServingEngine:
         if sess.prompt_consumed == len(sess.prompt):
             with span("prefill.sync"):
                 sess.out.append(int(jnp.argmax(logits[0, -1])))
+                self._emitted.append(sess)
                 if self.keep_logits:
                     sess.logits.append(np.asarray(logits[0, -1]))
             self._note_first_token(sess)
@@ -1218,19 +1308,24 @@ class ServingEngine:
         span = GLOBAL_TRACER.span
         P = self.page_tokens
         cfg = self.cfg
+        acct = self._acct       # each child's dt, into its phase
         with span("serve_batch_step") as step:
-            with span("step.residency"):
+            with span("step.residency") as part:
                 self._ensure_resident_batch(batch)
-            with span("step.args"):
+            acct[_BUILD] += part.dt
+            with span("step.args") as part:
                 # Rows in seat order from here on: row b is seat b.
                 joined, moved = self._seat_batch(batch)
                 batch = list(self._seats)
+            acct[_BUILD] += part.dt
             if self._has_carry:
-                with span("step.carry"):
+                with span("step.carry") as part:
                     self._seat_carries(joined, moved)
-            with span("step.pool"):
+                acct[_BUILD] += part.dt
+            with span("step.pool") as part:
                 pools, tables, keys = self._batch_pool(batch)
-            with span("step.args"):
+            acct[_BUILD] += part.dt
+            with span("step.args") as part:
                 b_pad = _pow2(len(batch))
                 toks, metas, prefills = [], [], []
                 layers = [kind.layers for kind in self.kinds]
@@ -1264,7 +1359,8 @@ class ServingEngine:
                     tab = np.zeros((b_pad, table.shape[1]), np.int32)
                     tab[:len(batch)] = table
                     tabs.append(((tab.shape, tab.tobytes()), tab))
-            with span("step.dispatch"):
+            acct[_BUILD] += part.dt
+            with span("step.dispatch") as part:
                 for k, (tab_key, tab) in enumerate(tabs):
                     if self._tab_cache[k][0] != tab_key:
                         self._tab_cache[k] = (tab_key, jnp.asarray(tab))
@@ -1292,7 +1388,8 @@ class ServingEngine:
                     self._carry = None
                     self._seats = []
                     raise
-            with span("step.sync"):
+            acct[_DEVICE] += part.dt
+            with span("step.sync") as part:
                 # One fused greedy argmax + host transfer for the whole
                 # batch (row b is bitwise jnp.argmax(logits[b]) — same
                 # bits, same first-max tie-break); doubles as the step's
@@ -1315,7 +1412,8 @@ class ServingEngine:
                     "batch_step", size=len(batch), pad=b_pad,
                     pages=int(tables[0].shape[1]), ms=round(dt * 1e3, 3),
                 )
-            with span("step.scatter"):
+            acct[_DEVICE] += part.dt
+            with span("step.scatter") as part:
                 for b, (sess, tok, prefill) in enumerate(
                         zip(batch, toks, prefills)):
                     sess.pos += 1
@@ -1325,6 +1423,7 @@ class ServingEngine:
                             or sess.prompt_consumed == len(sess.prompt))
                     if emit:
                         sess.out.append(int(best[b]))
+                        self._emitted.append(sess)
                         if kept is not None:
                             sess.logits.append(kept[b])
                         self._note_first_token(sess)
@@ -1347,6 +1446,7 @@ class ServingEngine:
                         raise AssertionError("overran max_new_tokens")
                     if len(sess.out) == sess.req.max_new_tokens:
                         sess.done = True
+            acct[_SCATTER] += part.dt
 
     def _seat_batch(self, batch: list[_Session]) -> tuple[list, list]:
         """Seat ``batch`` for one fused step: afterwards ``self._seats``

@@ -18,10 +18,46 @@ the PR-9 discipline (observability stays in-band and filesystem/process
 
 from __future__ import annotations
 
+import bisect
 import threading
 
 _lock = threading.Lock()
 _published: dict[str, "ServingStats"] = {}
+
+#: The phases a scheduler tick's wall time is put down to, in the order of
+#: the engine's account (``ServingEngine._acct``, whose one further entry is
+#: the time outside the engine, between two ticks).
+GAP_PHASES = ("chunk", "build", "device", "scatter", "finish", "sched")
+
+#: Upper bounds, in seconds, of the buckets a token gap and a first-token
+#: wait are filed in: geometric, a quarter of an octave apart, 1 ms to
+#: 131 s; a last bucket (``inf``) takes what lies beyond.
+GAP_BUCKETS = tuple(1e-3 * 2.0 ** (k / 4) for k in range(69))
+
+_ITL_FIELDS = ("count", "sum_s", "ticks", "outside_s") + tuple(
+    f"{phase}_s" for phase in GAP_PHASES)
+_TTFT_TAIL_FIELDS = ("count", "sum_s", "ticks", "unseated_ticks",
+                     "own_chunk_s", "queue_s")
+
+
+def _file(table: dict, secs: float, row: tuple, count: int = 1) -> None:
+    """Add ``count`` entries of ``row`` each into the bucket of ``table``
+    (bucket index -> sums, the entries' number first) that ``secs`` puts
+    them in."""
+    i = bisect.bisect_left(GAP_BUCKETS, secs)
+    into = table.get(i)
+    if into is None:
+        into = table[i] = [0] * (len(row) + 1)
+    into[0] += count
+    for k, v in enumerate(row, 1):
+        into[k] += count * v
+
+
+def _filed(table: dict, fields: tuple) -> dict:
+    """``{upper bound: {field: sum}}`` over the buckets that hold
+    something, by rising bound; ``inf`` is what lies beyond the last."""
+    return {GAP_BUCKETS[i] if i < len(GAP_BUCKETS) else float("inf"):
+            dict(zip(fields, table[i])) for i in sorted(table)}
 
 
 class ServingStats:
@@ -143,6 +179,13 @@ class ServingStats:
         # ticks of that remainder a runnable session spent unseated.
         self.ttft_parts = {"queue_s": 0.0, "chunk_s": 0.0, "tail_s": 0.0,
                            "unseated_ticks": 0}
+        # The tails' anatomy (note_gaps): every gap between two tokens of
+        # a session, and every wait for a first token, filed by its engine
+        # seconds in a bucket of GAP_BUCKETS that adds up what it was made
+        # of: a table each (_file), _ITL_FIELDS and _TTFT_TAIL_FIELDS a
+        # bucket.
+        self._itl: dict[int, list] = {}
+        self._ttft_tail: dict[int, list] = {}
 
     BATCH_BUCKETS = (1, 2, 4, 8, 16, 32)
     STEP_BUCKETS = (0.001, 0.005, 0.025, 0.1, 0.5, 2.5)
@@ -247,6 +290,24 @@ class ServingStats:
             parts["tail_s"] += tail_s
             parts["unseated_ticks"] += unseated_ticks
 
+    def note_gaps(self, gaps, firsts=()) -> None:
+        """One tick's token gaps, all at once. ``gaps`` is ``(parts, ticks,
+        count)`` for each set of ``count`` sessions whose gap is the same
+        (they emitted in the same two ticks): ``parts`` the seconds of each
+        of GAP_PHASES and then the seconds outside the engine, ``ticks``
+        the ticks spanned. A gap is filed by its engine seconds, the sum of
+        its six phases. ``firsts`` are the tick's first tokens, ``(engine
+        seconds since admission, ticks, unseated_ticks, own_chunk_s,
+        queue_s)`` each, filed the same way."""
+        n = len(GAP_PHASES)
+        with self._mu:
+            for parts, ticks, count in gaps:
+                secs = sum(parts[:n])
+                _file(self._itl, secs,
+                      (secs, ticks, parts[n], *parts[:n]), count)
+            for first in firsts:
+                _file(self._ttft_tail, first[0], first)
+
     def note_preempt(self, reason: str) -> None:
         """A session lost (or yielded) its batch slot this tick:
         ``slot`` = lost priority-ordered slot contention, ``cold_page``
@@ -348,6 +409,7 @@ class ServingStats:
     def snapshot(self) -> dict:
         with self._mu:
             lookups, hits = self.lookups, self.hits
+            itl = _filed(self._itl, _ITL_FIELDS)
             return {
                 "engine": self.engine,
                 "tokens": {
@@ -435,6 +497,14 @@ class ServingStats:
                     "hist": dict(self.ttft_s_hist),
                     "parts": {k: round(v, 6)
                               for k, v in self.ttft_parts.items()},
+                    "tail_hist": _filed(self._ttft_tail,
+                                        _TTFT_TAIL_FIELDS),
+                },
+                "itl": {
+                    "count": sum(b["count"] for b in itl.values()),
+                    "sum_s": sum(b["sum_s"] for b in itl.values()),
+                    "outside_s": sum(b["outside_s"] for b in itl.values()),
+                    "hist": itl,
                 },
             }
 

@@ -111,7 +111,7 @@ class _Span:
     and hand-rolled for the hot path (see Tracer.span)."""
 
     __slots__ = ("tracer", "op", "nbytes", "ctx", "saved_ctx",
-                 "annotation", "journal_on", "wall0", "t0", "rec")
+                 "annotation", "journal_on", "wall0", "t0", "dt", "rec")
 
     def __init__(self, tracer: "Tracer", op: str, nbytes: int):
         self.tracer = tracer
@@ -149,7 +149,9 @@ class _Span:
         return self
 
     def __exit__(self, *exc) -> None:
-        dt = time.perf_counter() - self.t0
+        # Kept for whoever opened the span (``with span(op) as s: ...;
+        # s.dt``): the seconds the stats below are fed.
+        dt = self.dt = time.perf_counter() - self.t0
         if self.annotation is not None:
             self.annotation.__exit__(*exc)
         if self.ctx is not None:

@@ -1362,29 +1362,41 @@ def test_a_seat_changing_hands_serves_the_reference_tokens(
 
 def test_seat_programs_are_built_with_the_stack_not_at_a_seat_change(
         tiny_model, monkeypatch):
+    import oncilla_tpu.serving.engine as engine_mod
+
     cfg, _ = tiny_model
     rng = np.random.default_rng(79)
     prompts = [rng.integers(1, cfg.vocab, ln).tolist()
                for ln in (5, 9, 17, 25, 30)]
+    # The seat programs are the module's: what an earlier test of this
+    # process built is built. Counted from their own cleared caches, the
+    # builds are this engine's, whatever ran before.
+    for name in SeatWatch.PROGRAMS:
+        getattr(engine_mod, name).clear_cache()
     watch = SeatWatch(monkeypatch)
+    assert watch.cache_sizes() == (0, 0, 0)
     # Batches of 5 down to 1 as the sessions finish, each from whatever seat
     # it has: joins, moves and reads at every width.
     outs, meta, _ = run_prompts(
         tiny_model, prompts, new_tokens=[12, 4, 9, 6, 12], share=False,
-        hot=8, warm=8, max_active=5, watch=watch)
+        hot=8, warm=8, max_active=5, max_batch=8, watch=watch)
     assert sorted(set(watch.widths)) == [1, 2, 4, 8]
     assert all(watch.calls.values())
-    # Whatever was built was built while a stack was made (for its width
-    # and the next one up), never between two of them ...
+    # Whatever was built was built while a stack was made, never between
+    # two of them ...
     ends = [after for _, after in watch.built] + [watch.cache_sizes()]
     assert all(before == ends[i]
                for i, (before, _) in enumerate(watch.built[1:]))
     assert ends[-1] == ends[-2]
-    # ... and a width that comes round again builds nothing.
-    seen = set()
+    # ... a stack builds the programs of its own width and of the next one
+    # up (the widest is max_batch's), one of each a width, and a width that
+    # comes round again builds nothing.
+    ready = set()
     for b_pad, (before, after) in zip(watch.widths, watch.built):
-        assert before == after or b_pad not in seen
-        seen.update((b_pad, 2 * b_pad))
+        new = {b_pad, min(2 * b_pad, 8)} - ready
+        assert after == tuple(n + len(new) for n in before), (b_pad, ready)
+        ready |= new
+    assert ready == {1, 2, 4, 8} and ends[-1] == (4, 4, 4)
     assert (meta["tails"]["seats_kept"] + meta["tails"]["seats_written"]
             == meta["batch"]["size_sum"])
 

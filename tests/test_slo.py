@@ -458,3 +458,37 @@ def test_serving_ttft_histogram_renders_and_validates():
     bucket_lines = [ln for ln in fams[fam] if "_bucket" in ln]
     assert any('le="+Inf"' in ln and ln.endswith(" 2")
                for ln in bucket_lines)
+
+
+def test_serving_itl_histogram_renders_cumulative_and_validates():
+    from oncilla_tpu.serving.metrics import GAP_BUCKETS, ServingStats
+
+    st = ServingStats("eng")
+    assert "ocm_serving_itl_seconds" not in prom.render_serving(
+        {"engines": [st.snapshot()]}, rank=0)
+    # gaps of 0.8 ms, 3 ms, 3.1 ms, 70 ms and one beyond the last bound, all
+    # device time but for the last, which stood 2 s outside the engine too
+    def gap(device_s, outside_s=0.0):
+        return [0.0, 0.0, device_s, 0.0, 0.0, 0.0, outside_s]
+
+    st.note_gaps([(gap(0.0008), 1, 1), (gap(0.003), 1, 1), (gap(0.0031), 1, 1),
+                  (gap(0.07), 2, 1), (gap(200.0, 2.0), 40, 1)])
+    snap = st.snapshot()
+    assert snap["itl"]["count"] == 5 and snap["itl"]["outside_s"] == 2.0
+    text = prom.render_serving({"engines": [snap]}, rank=0)
+    fams = prom.validate(text)
+    fam = "ocm_serving_itl_seconds"
+    buckets = {}
+    for ln in fams[fam]:
+        if "_bucket" in ln:
+            le = ln.split('le="')[1].split('"')[0]
+            buckets[float(le.replace("+Inf", "inf"))] = int(ln.rsplit(" ", 1)[1])
+    # only the bounds that hold a gap, the program's own, counted from below
+    assert sorted(buckets.values()) == [1, 3, 4, 5]
+    assert set(buckets) - {float("inf")} < set(GAP_BUCKETS)
+    assert buckets[0.001] == 1 and buckets[float("inf")] == 5
+    assert any(ln.startswith(fam + "_sum") and
+               float(ln.rsplit(" ", 1)[1]) == pytest.approx(200.0769)
+               for ln in fams[fam])
+    assert any(ln.startswith(fam + "_count") and ln.endswith(" 5")
+               for ln in fams[fam])
