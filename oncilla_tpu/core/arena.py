@@ -131,6 +131,21 @@ class ArenaAllocator:
             f"cannot reserve [{offset}, {offset + need}): overlaps live extent"
         )
 
+    def check_live(self, extents) -> None:
+        """Raise unless every extent is live and listed once: what a
+        batch free asks before it scrubs or releases anything."""
+        seen = set()
+        with self._lock:
+            for extent in extents:
+                need = self._live.get(extent.offset)
+                if (need is None or extent.nbytes > need
+                        or extent.offset in seen):
+                    raise OcmInvalidHandle(
+                        "free of unknown or already-freed extent at offset "
+                        f"{extent.offset}"
+                    )
+                seen.add(extent.offset)
+
     def free(self, extent: Extent) -> None:
         with self._lock:
             need = self._live.pop(extent.offset, None)

@@ -203,23 +203,54 @@ class Ocm:
     def free(self, handle: OcmAlloc) -> None:
         """``ocm_free`` (/root/reference/src/lib.c:347) — with the NULL-check
         ordering bug (lib.c:357-359) not replicated."""
-        if handle is None:
-            raise OcmInvalidHandle("free(None)")
+        self.free_many([handle])
+
+    def free_many(self, handles) -> int:
+        """``ocm_free`` for several handles at once. The checks of
+        :meth:`free` are made for every handle first (a double free, or a
+        handle listed twice, raises before anything is released), the
+        books are brought up in one pass under the lock, and the
+        LOCAL_DEVICE extents of one arena are scrubbed and released
+        together (``DeviceArena.free_many``: a dispatch a group where the
+        arena has been told their size, and every extent scrubbed before
+        any is released); every other kind is released as :meth:`free`
+        always has. Returns the device programs the scrubs took."""
+        handles = list(handles)
         with self._lock:
-            if handle.freed or handle.alloc_id not in self._allocs:
-                raise OcmInvalidHandle(f"double free of alloc {handle.alloc_id}")
-            del self._allocs[handle.alloc_id]
-            self._stagebufs.pop(handle.alloc_id, None)
-        if handle.daemon_owned:
-            # Includes single-node DEMOTED handles (kind LOCAL_*): the
-            # daemon registered the extent, so it must release it.
-            self._remote_or_raise(handle.kind).free(handle)
-        elif handle.kind == OcmKind.LOCAL_HOST:
-            self.host_arena.free(handle.extent)
-        elif handle.kind == OcmKind.LOCAL_DEVICE:
-            self.device_arenas[handle.device_index].free(handle.extent)
-        else:
-            self._remote_or_raise(handle.kind).free(handle)
+            ids = set()
+            for h in handles:
+                if h is None:
+                    raise OcmInvalidHandle("free(None)")
+                if (h.freed or h.alloc_id not in self._allocs
+                        or h.alloc_id in ids):
+                    raise OcmInvalidHandle(
+                        f"double free of alloc {h.alloc_id}")
+                ids.add(h.alloc_id)
+            for h in handles:
+                del self._allocs[h.alloc_id]
+                self._stagebufs.pop(h.alloc_id, None)
+        by_arena: dict[int, list[OcmAlloc]] = {}
+        for h in handles:
+            if h.kind == OcmKind.LOCAL_DEVICE and not h.daemon_owned:
+                by_arena.setdefault(h.device_index, []).append(h)
+                continue
+            if h.kind == OcmKind.LOCAL_HOST and not h.daemon_owned:
+                self.host_arena.free(h.extent)
+            else:
+                # A remote kind, or daemon-owned: that includes single-node
+                # DEMOTED handles (kind LOCAL_*), whose extent the daemon
+                # registered and so must release.
+                self._remote_or_raise(h.kind).free(h)
+            self._note_freed(h)
+        dispatches = 0
+        for index, held in by_arena.items():
+            dispatches += self.device_arenas[index].free_many(
+                [h.extent for h in held])
+            for h in held:
+                self._note_freed(h)
+        return dispatches
+
+    def _note_freed(self, handle: OcmAlloc) -> None:
         handle.freed = True
         alloctrace.note_free(self._trace_scope, handle.alloc_id)
 

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from oncilla_tpu.core.arena import ArenaAllocator, Extent, check_bounds
-from oncilla_tpu.core.errors import OcmError
+from oncilla_tpu.core.errors import OcmError, OcmOutOfMemory
 
 # dynamic_slice offsets are traced scalars; int32 covers arenas < 2 GiB.
 # Bigger arenas switch to BLOCK-indexed addressing — the buffer is stored as
@@ -107,7 +107,12 @@ def _pow2_chunks(n: int, cap: int) -> list[int]:
     dispatch one jitted program per chunk SIZE, so scrubbing arbitrary
     extent sizes compiles a bounded set of programs (one per power of
     two) instead of one per distinct size — compile cost matters more
-    than the ≤~30 extra dispatches on a free path."""
+    than the ≤~30 extra dispatches on a free path. That trade holds for
+    callers that free extents of ANY size (``ocm_free``, the daemon's
+    plane scrub, ``ops/ici.py``'s scrub-at-alloc, ``bench.py``); a caller
+    whose extents are all of one size known beforehand (the page store)
+    names it to :meth:`DeviceArena.prepare_scrub` and frees through
+    :meth:`DeviceArena.free_many`, a dispatch a group."""
     out = []
     c = 1 << (cap.bit_length() - 1)
     while n:
@@ -115,6 +120,42 @@ def _pow2_chunks(n: int, cap: int) -> list[int]:
             c >>= 1
         out.append(c)
         n -= c
+    return out
+
+
+# Extents of one size freed together are scrubbed a GROUP a dispatch: the
+# chained in-place updates of one program, the group padded by repeating an
+# offset (zeros written twice are zeros). A size takes len(_SCRUB_GROUPS)
+# programs, built when it is named to DeviceArena.prepare_scrub.
+_SCRUB_GROUPS = (1, 4, 16, 64)
+
+
+@partial(jax.jit, donate_argnums=0, static_argnums=2)
+def _arena_fill0_many(buf: jax.Array, offsets, nbytes: int) -> jax.Array:
+    """Zero ``nbytes`` at every one of ``offsets`` (a static count)."""
+    zeros = jnp.zeros((nbytes,), jnp.uint8)
+    for i in range(offsets.shape[0]):
+        buf = jax.lax.dynamic_update_slice(buf, zeros, (offsets[i],))
+    return buf
+
+
+@partial(jax.jit, donate_argnums=0, static_argnums=2)
+def _arena_fill0_rows_many(buf2d, r0s, nrows: int):
+    """Zero ``nrows`` whole blocks of a blocked arena from every one of
+    the rows ``r0s``."""
+    zeros = jnp.zeros((nrows, _BLOCK), jnp.uint8)
+    for i in range(r0s.shape[0]):
+        buf2d = jax.lax.dynamic_update_slice(buf2d, zeros, (r0s[i], 0))
+    return buf2d
+
+
+def _scrub_groups(n: int) -> list[int]:
+    """Group sizes that cover ``n`` extents: whole largest groups, then
+    the smallest group that takes the rest (padded)."""
+    top = _SCRUB_GROUPS[-1]
+    out = [top] * (n // top)
+    if n % top:
+        out.append(next(g for g in _SCRUB_GROUPS if g >= n % top))
     return out
 
 
@@ -195,6 +236,8 @@ class DeviceArena:
         # np.zeros is virtually mapped, so the host side is cheap.
         shape = (capacity // _BLOCK, _BLOCK) if self._blocked else (capacity,)
         self._buf = jax.device_put(np.zeros(shape, dtype=np.uint8), self.device)
+        # Extent sizes whose group scrubs are built (prepare_scrub).
+        self._scrub_sizes: set[int] = set()
 
     @staticmethod
     def _idx(off: int):
@@ -218,22 +261,93 @@ class DeviceArena:
         self.fill_zero(extent)
         self.allocator.free(extent)
 
+    def prepare_scrub(self, nbytes: int) -> bool:
+        """Name a size of which extents will be freed together
+        (:meth:`free_many`): its group scrubs are built now and run once,
+        over a scratch extent that is free and so all zeros already, so
+        that no later free compiles. False, and nothing built, where the
+        size cannot take the batched path (a blocked arena indexes whole
+        blocks) or the arena has no room for the scratch extent: such
+        extents are scrubbed one by one, as :meth:`free` does."""
+        if nbytes in self._scrub_sizes:
+            return True
+        unit = _BLOCK if self._blocked else 1
+        if nbytes % unit:
+            return False
+        try:
+            scratch = self.allocator.alloc(nbytes)
+        except OcmOutOfMemory:
+            return False
+        try:
+            on_block = scratch.offset % unit == 0
+            if on_block:
+                self._scrub_sizes.add(nbytes)
+                for g in _SCRUB_GROUPS:
+                    self.fill_zero_many([scratch] * g)
+        finally:
+            self.allocator.free(scratch)
+        return on_block
+
+    def free_many(self, extents) -> int:
+        """:meth:`free` for several extents: every one is scrubbed (the
+        fills enqueued, under the arena's lock) before any goes back to
+        the allocator, so the guarantee is :meth:`free`'s. An extent that
+        is not live, or listed twice, raises before anything is scrubbed
+        or released. Returns the device programs the scrubs took."""
+        extents = list(extents)
+        self.allocator.check_live(extents)
+        dispatches = self.fill_zero_many(extents)
+        for extent in extents:
+            self.allocator.free(extent)
+        return dispatches
+
+    def fill_zero_many(self, extents) -> int:
+        """Zero every extent whole. Extents of a size named to
+        :meth:`prepare_scrub` take one dispatch a group of up to
+        ``_SCRUB_GROUPS[-1]`` (in a blocked arena if they start on a
+        block); the others go through :meth:`fill_zero` one by one.
+        Returns the device programs dispatched."""
+        by_size: dict[int, list[Extent]] = {}
+        for extent in extents:
+            by_size.setdefault(extent.nbytes, []).append(extent)
+        unit = _BLOCK if self._blocked else 1
+        fill = _arena_fill0_rows_many if self._blocked else _arena_fill0_many
+        dispatches = 0
+        for nbytes, same in by_size.items():
+            starts = [e.offset // unit for e in same]
+            if nbytes not in self._scrub_sizes or any(
+                    e.offset % unit for e in same):
+                dispatches += sum(self.fill_zero(e) for e in same)
+                continue
+            with self._mu:
+                for g in _scrub_groups(len(starts)):
+                    group, starts = starts[:g], starts[g:]
+                    group += group[-1:] * (g - len(group))
+                    self._buf = fill(self._buf, np.asarray(group, np.int32),
+                                     nbytes // unit)
+                    dispatches += 1
+        return dispatches
+
     def fill_zero(self, extent: Extent, nbytes: int | None = None,
-                  offset: int = 0) -> None:
+                  offset: int = 0) -> int:
         """Zero a byte range of the extent with a device-side fill.
         Blocked (>2 GiB) arenas scrub as sub-block head + chunked whole
-        rows + sub-block tail, so byte indices never exceed int32."""
+        rows + sub-block tail, so byte indices never exceed int32.
+        Returns the device programs dispatched."""
         n = extent.nbytes - offset if nbytes is None else nbytes
         check_bounds(extent, offset, n)
         start = extent.offset + offset
+        dispatches = 0
         with self._mu:
             if not self._blocked:
                 for c in _pow2_chunks(n, 256 << 20):
                     self._buf = _arena_fill0(self._buf, self._idx(start), c)
                     start += c
-                return
+                    dispatches += 1
+                return dispatches
             end = start + n
             if start % _BLOCK:
+                dispatches += 1
                 r0 = start // _BLOCK
                 stop = min(end, (r0 + 1) * _BLOCK)
                 self._buf = _arena_fill0_partial(
@@ -250,12 +364,15 @@ class DeviceArena:
                         self._buf, self._idx(start // _BLOCK), rc
                     )
                     start += rc * _BLOCK
+                    dispatches += 1
             if start < end:
                 r0 = start // _BLOCK
                 self._buf = _arena_fill0_partial(
                     self._buf, self._idx(r0),
                     jnp.asarray([0, end - start], jnp.int32),
                 )
+                dispatches += 1
+        return dispatches
 
     @staticmethod
     def _window(start: int, nbytes: int) -> tuple[int, int, int]:

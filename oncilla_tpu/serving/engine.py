@@ -558,6 +558,9 @@ class ServingEngine:
         self.queue: list[Request] = []
         self.active: list[_Session] = []
         self.results: list[SessionResult] = []
+        # Pages of sessions that have stood up, until _free_ended frees
+        # them together.
+        self._ended: list = []
         # The tick's phase account (docs/OBSERVABILITY.md, "Serving tick
         # anatomy"): seconds since this engine was built, an entry a phase
         # (``_CHUNK`` ... ``_OUTSIDE``), summed from the ``dt`` of the spans
@@ -628,6 +631,7 @@ class ServingEngine:
     def close(self) -> None:
         for sess in self.active:
             self._finish(sess, abandon=True)
+        self._free_ended()
         self.active = []
         self._pool = [None] * len(self.kinds)
         self._pages_touched = []
@@ -954,6 +958,7 @@ class ServingEngine:
                 for sess in self.active:
                     if sess.done:
                         self._finish(sess)
+                self._free_ended()
                 self.active = [s for s in self.active if not s.done]
                 self._close_books(tick, finish, sum(acct[:_FINISH]) - named)
         self._last_tick = (tick, finish)
@@ -1646,6 +1651,7 @@ class ServingEngine:
         A session so lists ``ceil(window / P)`` pages of the kind at
         most."""
         P = self.page_tokens
+        passed = []
         for k, kind in enumerate(self.kinds):
             if kind.window is None:
                 continue
@@ -1657,9 +1663,13 @@ class ServingEngine:
                 if slot is not None:
                     self._pool_free[k].append(slot)
                 first.arrays = None
-                self.store.free_page(first.page)
+                passed.append(first.page)
                 sess.dropped[k] += 1
-                self.stats.note_window(dropped=1)
+        if passed:
+            # Freed here, not at the tick's end: HOT's occupancy, and every
+            # placement decision with it, stays what it was.
+            self.store.free_pages(passed)
+            self.stats.note_window(dropped=len(passed))
 
     def _publish_partial(self, sess: _Session) -> None:
         """End of prefill mid-page: publish the prompt's partial tail as
@@ -1679,12 +1689,15 @@ class ServingEngine:
         self.prefix.publish(sess.chain_parent, tuple(prompt_toks), page)
 
     def _finish(self, sess: _Session, abandon: bool = False) -> None:
+        """Stand a session up. The pages that were its own wait in
+        ``_ended`` for :meth:`_free_ended`, which frees them with those of
+        whoever else ended in the tick."""
         for ext in sess.shared_refs:
             self.prefix.release(ext)
         sess.shared_refs = []
-        for e in sess.entries:
-            if e.extent is None and not e.page.shared and not e.page.freed:
-                self.store.free_page(e.page)
+        self._ended += [
+            e.page for e in sess.entries
+            if e.extent is None and not e.page.shared and not e.page.freed]
         sess.entries = []
         if sess.seat is not None:
             self._seats[sess.seat] = None
@@ -1699,6 +1712,12 @@ class ServingEngine:
                 out_logits=list(sess.logits) if self.keep_logits else None,
                 ttft_parts=sess.ttft_parts,
             ))
+
+    def _free_ended(self) -> None:
+        """The pages of every session that ended since the last call leave
+        the store together: one scrub dispatch a group, the books once."""
+        ended, self._ended = self._ended, []
+        self.store.free_pages(ended)
 
     # -- introspection ----------------------------------------------------
 

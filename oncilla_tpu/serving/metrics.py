@@ -160,6 +160,12 @@ class ServingStats:
         # of every later query (freed, never demoted).
         self.window_pages_shipped = 0
         self.window_pages_dropped = 0
+        # Pages the store freed, the free_pages calls that took (pages
+        # freed together are one call) and the device programs their HOT
+        # extents' scrubs were.
+        self.frees_pages = 0
+        self.frees_calls = 0
+        self.frees_scrub_dispatches = 0
         # (layer, position) pairs the seated sessions' live pages held,
         # summed over the fused steps, and what they would have held had
         # every cached layer kept every position (equal unless a kind
@@ -378,6 +384,14 @@ class ServingStats:
             self.window_pages_shipped += shipped
             self.window_pages_dropped += dropped
 
+    def note_frees(self, pages: int, scrub_dispatches: int) -> None:
+        """One ``free_pages`` call of the store: ``pages`` freed, their
+        HOT extents scrubbed by ``scrub_dispatches`` device programs."""
+        with self._mu:
+            self.frees_pages += pages
+            self.frees_calls += 1
+            self.frees_scrub_dispatches += scrub_dispatches
+
     def note_kv(self, held: int, whole: int) -> None:
         """One fused step's context: ``held`` (layer, position) pairs in
         the seated sessions' live pages, ``whole`` had nothing been
@@ -485,6 +499,11 @@ class ServingStats:
                 "window": {
                     "pages_shipped": self.window_pages_shipped,
                     "pages_dropped": self.window_pages_dropped,
+                },
+                "frees": {
+                    "pages": self.frees_pages,
+                    "calls": self.frees_calls,
+                    "scrub_dispatches": self.frees_scrub_dispatches,
                 },
                 "kv": {
                     "positions_held": self.kv_positions_held,

@@ -270,7 +270,7 @@ class PrefixCache:
         """Reclaim unreferenced LEAF extents (children first — an inner
         node's page may still back a referenced chain below it).
         Returns the number of pages freed."""
-        freed = 0
+        freed = []
         changed = True
         while changed:
             changed = False
@@ -281,8 +281,8 @@ class PrefixCache:
                                 and not ext.partials):
                             del table[toks]
                             ext.page.shared = False
-                            self.store.free_page(ext.page)
+                            freed.append(ext.page)
                             self.stats.note_extents(-1)
-                            freed += 1
                             changed = True
-        return freed
+        self.store.free_pages(freed)
+        return len(freed)

@@ -197,6 +197,10 @@ class TieredPageStore:
         # fetch. Prefetch workers bring their own (serving/engine.py).
         self._recvbuf = np.empty(self.page_bytes, dtype=np.uint8)
         self._mu = threading.Lock()
+        # Every HOT extent is page_bytes long: pages freed together are
+        # scrubbed a dispatch a group (free_pages), by programs the arena
+        # builds and runs now, while a set-up pays for them.
+        self.ctx.device_arenas[0].prepare_scrub(self.page_bytes)
 
     # -- tier backends ----------------------------------------------------
 
@@ -408,23 +412,42 @@ class TieredPageStore:
         return clone
 
     def free_page(self, page: Page) -> None:
-        if page.freed:
+        self.free_pages([page])
+
+    def free_pages(self, pages) -> None:
+        """Free pages together (one already freed, or listed twice, is
+        passed over). Every page is validated first: a shared page with
+        live references raises and nothing is freed. The HOT handles go
+        back in one ``Ocm.free_many``, their extents scrubbed a dispatch a
+        group, every one before any is released; the other tiers' as
+        :meth:`_free_handle` frees them; the store's books are brought up
+        once."""
+        pages = list({p.page_id: p for p in pages if not p.freed}.values())
+        for page in pages:
+            if page.shared and page.refs > 0:
+                raise OcmInvalidHandle(
+                    f"free of shared page {page.page_id} with {page.refs} "
+                    "live reference(s)"
+                )
+        if not pages:
             return
-        if page.shared and page.refs > 0:
-            raise OcmInvalidHandle(
-                f"free of shared page {page.page_id} with {page.refs} "
-                "live reference(s)"
-            )
-        del self.pages[page.page_id]
-        page.freed = True
-        self._free_handle(page.tier, page.handle)
+        for page in pages:
+            del self.pages[page.page_id]
+            page.freed = True
+        dispatches = self.ctx.free_many(
+            [p.handle for p in pages if p.tier == Tier.HOT])
+        for page in pages:
+            if page.tier != Tier.HOT:
+                self._free_handle(page.tier, page.handle)
+        self.stats.note_frees(len(pages), dispatches)
         self._sync_stats()
 
     def close(self) -> None:
         """Free every live page (shared ones included: teardown)."""
-        for page in list(self.pages.values()):
+        pages = list(self.pages.values())
+        for page in pages:
             page.refs = 0
-            self.free_page(page)
+        self.free_pages(pages)
 
     # -- movement ---------------------------------------------------------
 

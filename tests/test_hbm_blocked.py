@@ -9,6 +9,7 @@ no JAX_ENABLE_X64, no int64 traced offsets.
 import numpy as np
 import pytest
 
+import _batch_free
 from oncilla_tpu.core.hbm import _BLOCK, DeviceArena
 
 GIB = 1 << 30
@@ -173,3 +174,42 @@ def test_blocked_scrub_on_free(big_arena, rng):
     np.testing.assert_array_equal(got[6000:], pat[6000:])
     a.free(ext3)
     a.free(first)
+
+
+# -- DeviceArena.free_many over the blocked layout (the flat one:
+# test_arena.py) -----------------------------------------------------------
+
+SLOT = 3 * _BLOCK        # whole blocks, no power of two
+
+
+@pytest.mark.parametrize("n", _batch_free.COUNTS)
+def test_blocked_free_many_scrubs_a_group_a_dispatch(big_arena, rng, n):
+    _batch_free.check_free_many(big_arena, SLOT, n, rng)
+
+
+def test_blocked_free_many_refuses_before_it_releases(big_arena, rng):
+    _batch_free.check_refusals(big_arena, SLOT, rng)
+
+
+def test_blocked_free_many_of_mixed_sizes_falls_back(big_arena, rng):
+    _batch_free.check_mixed_sizes(big_arena, SLOT, 5 * _BLOCK, rng)
+
+
+def test_blocked_free_many_off_a_block_goes_the_old_way(big_arena, rng):
+    """A size that is not whole blocks cannot be prepared, and extents of
+    a prepared size that do not start on a block are scrubbed one by one
+    (head, whole rows, tail): they still read zeros."""
+    a = big_arena
+    assert not a.prepare_scrub(SLOT + 512)
+    assert a.prepare_scrub(SLOT)
+    shim = a.alloc(512)                  # pushes what follows off a block
+    odd = [a.alloc(SLOT) for _ in range(3)]
+    assert all(e.offset % _BLOCK for e in odd)
+    for extent in odd:
+        _batch_free.fill(a, extent, rng)
+    assert a.free_many(odd) > 3
+    again = [a.alloc(SLOT) for _ in range(3)]
+    for extent in again:
+        assert not np.asarray(a.read(extent, SLOT)).any()
+    a.free_many(again + [shim])
+    assert a.allocator.bytes_live == 0

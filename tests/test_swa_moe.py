@@ -400,16 +400,23 @@ def test_a_finished_session_frees_both_kinds(tiny):
     freed = []
 
     def watch(eng):
-        finish = eng._finish
+        finish, tick = eng._finish, eng._tick
 
         def finished(sess, abandon=False):
             held = [(e.kind, e.page) for e in sess.entries]
             assert {k for k, _ in held} == {0, 1}
             finish(sess, abandon)
-            assert all(page.freed for _, page in held) and not sess.entries
-            freed.append([k for k, _ in held])
+            # its pages wait for the tick's one free_pages call
+            assert not sess.entries and [p for _, p in held] == eng._ended[
+                -len(held):]
+            freed.append(held)
 
-        eng._finish = finished
+        def checked_tick():
+            tick()
+            assert not eng._ended
+            assert all(page.freed for held in freed for _, page in held)
+
+        eng._finish, eng._tick = finished, checked_tick
 
     serve(cfg, params, prompts, (6, 9), max_active=2, max_batch=2,
           watch=watch)     # serve() asserts the store is empty at the end
@@ -427,7 +434,7 @@ def test_both_kinds_move_through_the_tiers_alike(tiny):
     moved, dropped_from = set(), set()
 
     def watch(eng):
-        move, free_page = eng.store._move, eng.store.free_page
+        move, free_pages = eng.store._move, eng.store.free_pages
         kind_of = {}
         ship = eng._ship
 
@@ -442,13 +449,14 @@ def test_both_kinds_move_through_the_tiers_alike(tiny):
             moved.add((kind_of[page.page_id], to.value))
             move(page, to, data=data)
 
-        def freeing(page):
-            if kind_of.get(page.page_id) == 1 and eng.active:
-                dropped_from.add(page.tier.value)
-            free_page(page)
+        def freeing(pages):
+            for page in pages:
+                if kind_of.get(page.page_id) == 1 and eng.active:
+                    dropped_from.add(page.tier.value)
+            free_pages(pages)
 
         eng._ship, eng.store._move = shipped, moving
-        eng.store.free_page = freeing
+        eng.store.free_pages = freeing
 
     results, meta = serve(cfg, params, prompts, new, hot=6, warm=8,
                           max_active=4, max_batch=2, watch=watch, prefetch=2)
