@@ -47,6 +47,9 @@ _EXPORTS = {
     # swa_moe: window and full attention layers with per-head gates, a
     # page of two kinds, experts of which the chip holds a share.
     "SwaMoeConfig": "swa_moe",
+    # conv_moe: gated short convolutions beside normed grouped-query
+    # attention, experts without a shared one, a carry of two rows a layer.
+    "ConvMoeConfig": "conv_moe",
 }
 
 __all__ = sorted(_EXPORTS)

@@ -448,7 +448,9 @@ def route(h, params, j: int, real, cfg):
     (T, k), the (T, E) weight of every expert for every row (zero where it
     was not chosen, and everywhere in a row that is padding) and which
     experts (E,) a real row chose. With ``cfg.n_group`` over 1 the choice
-    is group-limited (:func:`_group_limit`)."""
+    is group-limited (:func:`_group_limit`); a config that states a
+    ``router_norm_eps`` adds it to the sum the chosen scores are divided
+    by."""
     E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     s = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", h, params["w_router"][j],
@@ -458,7 +460,13 @@ def route(h, params, j: int, real, cfg):
         pick = _group_limit(pick, cfg.n_group, cfg.topk_group)
     _, idx = jax.lax.top_k(pick, k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    w = cfg.routed_scaling_factor * chosen / chosen.sum(-1, keepdims=True)
+    # Traced in this order (the scaling, the sum, the division): what the
+    # families without the constant have always compiled.
+    scaled = cfg.routed_scaling_factor * chosen
+    total = chosen.sum(-1, keepdims=True)
+    if getattr(cfg, "router_norm_eps", 0.0):
+        total = total + cfg.router_norm_eps
+    w = scaled / total
     sel = jax.nn.one_hot(idx, E, dtype=bool) & real[:, None, None]
     weights = jnp.where(sel, w[:, :, None], 0.0).sum(axis=1)
     return idx, weights, sel.any(axis=(0, 1))
@@ -472,8 +480,9 @@ def expert_ffn(h, params, j: int, real, cfg):
     ``cfg.n_routed_experts`` and computes the part of the result its own
     experts give: ``cfg.experts_held`` is the (first, count) range whose
     weights the stacked leaves hold; a row whose experts all live
-    elsewhere gets the shared expert alone. Returns (y (T, D) float32,
-    distinct held experts touched () int32, chosen experts (T, k))."""
+    elsewhere gets the shared expert alone, where the family has one (its
+    ``ws_*`` leaves). Returns (y (T, D) float32, distinct held experts
+    touched () int32, chosen experts (T, k))."""
     dt = jnp.dtype(cfg.dtype)
     idx, weights, hit = route(h, params, j, real, cfg)
     first, held = cfg.experts_held
@@ -498,8 +507,9 @@ def expert_ffn(h, params, j: int, real, cfg):
         return acc + weights[:, e][:, None] * y
 
     y = jax.lax.fori_loop(0, n_hit, body, jnp.zeros(h.shape, jnp.float32))
-    y = y + _swiglu(x, params["ws_gate"][j], params["ws_up"][j],
-                    params["ws_down"][j], dt)
+    if "ws_gate" in params:
+        y = y + _swiglu(x, params["ws_gate"][j], params["ws_up"][j],
+                        params["ws_down"][j], dt)
     return y, n_hit, idx
 
 

@@ -48,6 +48,7 @@ There is one scheduler, the tick (:meth:`ServingEngine._tick`):
 
 from __future__ import annotations
 
+import math
 import os
 import time
 import weakref
@@ -152,6 +153,29 @@ def _pack_pages_jit(kinds: tuple, dtype: str) -> tuple:
     to bytes (``core.hbm.to_bytes``). Returns a uint8 vector a kind."""
     return tuple(to_bytes(jnp.stack(leaves).astype(jnp.dtype(dtype)))
                  for leaves in kinds)
+
+
+# A carry on its way into a prefix extent and back (a family with a carry,
+# the prefix cache on): every leaf's bytes one after another, the float32
+# they are held in, so a snapshot round-trips bit for bit.
+
+
+@jax.jit
+def _carry_pack_jit(carry: tuple) -> jax.Array:
+    """One session's carry, (L, 1, ...) a leaf, as one uint8 vector."""
+    return jnp.concatenate([to_bytes(leaf) for leaf in carry])
+
+
+@partial(jax.jit, static_argnames=("spec",))
+def _carry_unpack_jit(data: jax.Array, spec: tuple) -> tuple:
+    """The inverse: ``spec`` is the (shape, dtype name) of every leaf. The
+    leaves are fresh arrays (the programs donate a carry)."""
+    out, at = [], 0
+    for shape, dtype in spec:
+        n = math.prod(shape) * jnp.dtype(dtype).itemsize
+        out.append(from_bytes(data[at:at + n], shape, dtype))
+        at += n
+    return tuple(out)
 
 
 # The fused step's page pool, one array of rows (capacity, L, KV, P, Hd) a
@@ -429,6 +453,10 @@ class _Session:
         #: a leaf, zeros before its first token; None while a seat holds
         #: it.
         self.carry = carry
+        #: The snapshot of the carry taken before the step that computes
+        #: the prompt's last token, until ``_publish_partial`` hands it to
+        #: the partial tail's extent.
+        self.boundary: Page | None = None
         self.reset_tail()
 
     def reset_tail(self) -> None:
@@ -503,11 +531,22 @@ class ServingEngine:
         # dispatched over them.
         self.family = family_of(cfg)
         self._has_carry = self.family.carry_leaves is not None
-        if self._has_carry and prefix is not None:
+        # One session's carry as a prefix extent keeps it: the (shape,
+        # dtype) of every leaf and the bytes of them all.
+        leaves = self.family.carry_leaves(cfg, 1) if self._has_carry else ()
+        self._carry_spec = tuple((tuple(shape), jnp.dtype(dt).name)
+                                 for shape, dt in leaves)
+        self._carry_nbytes = sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for shape, dt in self._carry_spec)
+        if prefix is not None and self._carry_nbytes > store.page_bytes:
             raise ValueError(
-                "prefix_cache with a family that keeps a recurrent carry: "
-                "a prefix page is adoptable only with the carry at its "
-                "boundary, and no extent holds one yet (ROADMAP.md, Queue 2)")
+                "prefix_cache with a family whose carry does not fit a slot "
+                "of the store: a prefix page is adoptable only with the "
+                "carry at its boundary, every extent keeps a snapshot of it "
+                f"in a slot as a page has, and a snapshot is "
+                f"{self._carry_nbytes} B against a slot of "
+                f"{store.page_bytes} B (ROADMAP.md, Queue 2)")
         # The kinds of the family's page (most families: one), the shape
         # of every leaf of a page, kind by kind, and where each kind's
         # leaves lie among them.
@@ -588,9 +627,10 @@ class ServingEngine:
         # incarnation persisted at close — cross-restart prefix hits
         # without recomputing a single prompt page. No backend (the
         # default everywhere) → byte-identical cold behavior.
-        if (self.prefix is not None
-                and getattr(store, "frozen_backend", None) is not None):
-            self.prefix.restore(store.frozen_backend)
+        if self.prefix is not None:
+            self.prefix.carry_nbytes = self._carry_nbytes
+            if getattr(store, "frozen_backend", None) is not None:
+                self.prefix.restore(store.frozen_backend)
 
     @staticmethod
     def page_nbytes(cfg, page_tokens: int,
@@ -688,17 +728,27 @@ class ServingEngine:
         if (self.prefix is None or not sess.chain_valid
                 or sess.tail_len != 0):
             return
+        last = self._adopt_extents(sess)
+        if last is not None:
+            if self._has_carry:
+                self._restore_carry(sess, last)
+            self.stats.note_adoption(restored=self._has_carry)
+
+    def _adopt_extents(self, sess: _Session) -> SharedExtent | None:
+        """Take every extent that extends the session's chain from where
+        it stands; returns the last one taken."""
         P = self.page_tokens
+        last = None
         while True:
             pc = sess.prompt_consumed
             rem = len(sess.prompt) - pc
             if rem <= 1:
-                return
+                return last
             if rem > P:
                 ext = self.prefix.child(sess.chain_parent,
                                         sess.prompt[pc:pc + P])
                 if ext is None or ext.fill != P:
-                    return
+                    return last
                 self.prefix.acquire(ext)
                 sess.shared_refs.append(ext)
                 sess.entries.append(self._entry_of(ext))
@@ -707,16 +757,22 @@ class ServingEngine:
                 sess.prompt_consumed += P
                 sess.prefix_tokens_reused += P
                 self.stats.note_tokens(P, phase="prefill")
+                last = ext
                 continue
             # 2 <= rem <= P: the prompt's tail chunk. Adopt all but the
             # final token by copy-on-write when a shared extent holds
-            # exactly these tokens (full page or partial alike).
+            # exactly these tokens (full page or partial alike). A full
+            # page's carry snapshot stands after its last token, where this
+            # adopter cannot resume: with a carry the page program takes
+            # the whole page.
             ext = self.prefix.child(sess.chain_parent, sess.prompt[pc:])
-            if ext is not None and ext.fill > 1:
+            if (ext is not None and ext.fill > 1
+                    and not (self._has_carry and ext.fill == P)):
                 self._adopt_partial(sess, ext, upto=rem - 1)
                 sess.prompt_consumed += rem - 1
                 self.stats.note_tokens(rem - 1, phase="prefill")
-            return
+                last = ext
+            return last
 
     def _adopt_partial(self, sess: _Session, ext: SharedExtent,
                        upto: int) -> None:
@@ -737,6 +793,31 @@ class ServingEngine:
         # node ABOVE the partial (its full token tuple replaces the
         # partial's).
         sess.chain_parent = ext.parent
+
+    def _snapshot(self, sess: _Session) -> Page:
+        """The session's carry as it stands, into a slot of the store: what
+        a prefix extent published at this boundary keeps beside its page.
+        A copy: the programs go on donating the carry itself."""
+        with GLOBAL_TRACER.span("prefix.snapshot"):
+            carry = (sess.carry if sess.seat is None
+                     else _seat_read_jit(self._carry, np.int32(sess.seat)))
+            page = self.store.alloc_page(_carry_pack_jit(carry), shared=True)
+            self.stats.note_carry_snapshot()
+        return page
+
+    def _restore_carry(self, sess: _Session, ext: SharedExtent) -> None:
+        """Adoption's other half: the session goes on from the carry the
+        last adopted extent keeps. Fresh arrays, never the snapshot's own
+        bytes."""
+        with GLOBAL_TRACER.span("prefix.restore"):
+            data = jnp.asarray(np.array(self.store.read_page(ext.carry),
+                                        copy=True))
+            carry = _carry_unpack_jit(data, self._carry_spec)
+            if sess.seat is None:
+                sess.carry = carry
+            else:
+                self._carry = _seat_write_jit(self._carry, carry,
+                                              np.int32(sess.seat))
 
     # -- residency / prefetch --------------------------------------------
 
@@ -943,7 +1024,8 @@ class ServingEngine:
                     # matching here is what lets identical prompts
                     # converge on shared pages (and CoW partial adoption)
                     # instead of prefilling in lockstep.
-                    self._match_more(sess)
+                    with span("prefill.match"):
+                        self._match_more(sess)
                     if self._bulk_prefill(sess):
                         self._prefill_chunk(sess)
                         chunked = True
@@ -1341,6 +1423,12 @@ class ServingEngine:
                         sess.prompt_consumed += 1
                         prefill = True
                         self.stats.note_tokens(1, phase="prefill")
+                        if (self._has_carry
+                                and sess.prompt_consumed == len(sess.prompt)
+                                and self._publishes_partial(sess, 1)):
+                            # Where an adopter of the partial tail resumes:
+                            # before the prompt's last token.
+                            sess.boundary = self._snapshot(sess)
                     else:
                         tok = sess.out[-1] if sess.out else sess.prompt[-1]
                         prefill = False
@@ -1387,8 +1475,11 @@ class ServingEngine:
                         logits, self._tails, touched = self.family.step(
                             *args)
                 except BaseException:
+                    self.store.free_pages(
+                        [sess.boundary for sess in batch if sess.boundary])
                     for sess in batch:
                         sess.seat = None
+                        sess.boundary = None
                     self._tails = None
                     self._carry = None
                     self._seats = []
@@ -1617,7 +1708,8 @@ class ServingEngine:
         if (self.prefix is not None and prompt_only and sess.chain_valid
                 and not entry.page.shared):
             ext = self.prefix.publish(
-                sess.chain_parent, tuple(sess.page_toks), entry.page
+                sess.chain_parent, tuple(sess.page_toks), entry.page,
+                self._snapshot(sess) if self._has_carry else None,
             )
             if ext.page is entry.page:
                 entry.extent = ext
@@ -1671,22 +1763,32 @@ class ServingEngine:
             self.store.free_pages(passed)
             self.stats.note_window(dropped=len(passed))
 
+    def _publishes_partial(self, sess: _Session, ahead: int = 0) -> bool:
+        """Whether the prompt's end, ``ahead`` tokens from here, leaves a
+        partial tail for :meth:`_publish_partial` to publish."""
+        return (self.share_partials and self.prefix is not None
+                and sess.chain_valid
+                and 0 < sess.tail_len + ahead < self.page_tokens
+                and sess.pos + ahead <= len(sess.prompt))
+
     def _publish_partial(self, sess: _Session) -> None:
         """End of prefill mid-page: publish the prompt's partial tail as
         a shareable extent (retention-only — this session's own copy
-        stays in its tail buffers)."""
-        if (self.prefix is None or not sess.chain_valid
-                or sess.tail_len == 0):
+        stays in its tail buffers), with the carry snapshot taken before
+        its last token where the family has a carry."""
+        boundary, sess.boundary = sess.boundary, None
+        if not self._publishes_partial(sess):
+            if boundary is not None:
+                self.store.free_page(boundary)
             return
         prompt_toks = sess.page_toks[:sess.tail_len]
-        if sess.pos > len(sess.prompt):
-            return
         packed = jnp.stack(list(self._tail(sess))).astype(
             jnp.dtype(self.store_dtype)
         )
         raw = np.asarray(to_bytes(packed))
         page = self.store.alloc_page(raw)
-        self.prefix.publish(sess.chain_parent, tuple(prompt_toks), page)
+        self.prefix.publish(sess.chain_parent, tuple(prompt_toks), page,
+                            boundary)
 
     def _finish(self, sess: _Session, abandon: bool = False) -> None:
         """Stand a session up. The pages that were its own wait in

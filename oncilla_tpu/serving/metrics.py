@@ -92,6 +92,15 @@ class ServingStats:
         self.prefix_hits = 0
         self.prefix_shared_bytes = 0
         self.prefix_extents = 0
+        # A family with a recurrent carry keeps a snapshot of it with
+        # every extent: snapshots taken and stored, adoptions (a session
+        # taking one extent or a chain of them at a boundary), adoptions
+        # that set the session's carry from the last extent's snapshot,
+        # and the bytes of the snapshots live extents hold (a gauge).
+        self.prefix_carry_snapshots = 0
+        self.prefix_adoptions = 0
+        self.prefix_carry_restores = 0
+        self.prefix_carry_bytes = 0
         # Prefetch / stall.
         self.prefetch_issued = 0
         self.prefetch_completed = 0
@@ -243,6 +252,19 @@ class ServingStats:
     def note_extents(self, delta: int) -> None:
         with self._mu:
             self.prefix_extents += delta
+
+    def note_carry_snapshot(self) -> None:
+        with self._mu:
+            self.prefix_carry_snapshots += 1
+
+    def note_carry_bytes(self, delta: int) -> None:
+        with self._mu:
+            self.prefix_carry_bytes += delta
+
+    def note_adoption(self, restored: bool) -> None:
+        with self._mu:
+            self.prefix_adoptions += 1
+            self.prefix_carry_restores += restored
 
     def note_prefetch(self, completed: bool = False) -> None:
         with self._mu:
@@ -441,6 +463,10 @@ class ServingStats:
                     "shared_bytes": max(self.prefix_shared_bytes, 0),
                     "extents": self.prefix_extents,
                     "cow": self.cow_copies,
+                    "adoptions": self.prefix_adoptions,
+                    "carry_snapshots": self.prefix_carry_snapshots,
+                    "carry_restores": self.prefix_carry_restores,
+                    "carry_bytes": self.prefix_carry_bytes,
                 },
                 "stalls": self.stalls,
                 "stall_s": round(self.stall_s, 6),

@@ -22,6 +22,13 @@ Sharing rules (the vLLM/Mooncake discipline on OCM pages):
 - ``refs == 0`` extents stay cached (retention is the point of a prefix
   cache) until :meth:`sweep` reclaims unreferenced leaves under store
   pressure.
+
+A family whose layers keep a recurrent carry beside their pages
+(``models/kv_paging.py::PagedFamily.carry_leaves``) can resume from a
+prefix only with the carry as it stood there, so each of its extents holds
+a **snapshot** of it beside the page (:attr:`SharedExtent.carry`): a page of
+the same store in a slot of its own, shared, referenced, counted, evicted,
+deduplicated, swept and persisted with the extent's page.
 """
 
 from __future__ import annotations
@@ -52,10 +59,21 @@ class SharedExtent:
     parent: "SharedExtent | None" = None
     children: dict = field(default_factory=dict)   # full-page nodes
     partials: dict = field(default_factory=dict)   # partial-tail nodes
+    #: A family with a carry: the publisher's carry where an adopter of
+    #: this extent resumes, as a page of the store. That is after the
+    #: extent's last token for a full page (the adopter goes on with the
+    #: next page) and before it for a partial tail (the adopter computes
+    #: the prompt's last token itself, for its logits).
+    carry: Page | None = None
 
     @property
     def fill(self) -> int:
         return len(self.tokens)
+
+    @property
+    def nbytes(self) -> int:
+        """The extent's bytes in the store: its page and its snapshot."""
+        return self.page.nbytes + (self.carry.nbytes if self.carry else 0)
 
     @property
     def refs(self) -> int:
@@ -70,6 +88,10 @@ class PrefixCache:
         self.store = store
         self.page_tokens = int(page_tokens)
         self.stats = stats or store.stats
+        #: Bytes of the carry snapshot every extent holds (the engine of a
+        #: family with a carry sets it; 0: no extent holds one). What
+        #: :meth:`restore` holds a persisted extent to.
+        self.carry_nbytes = 0
         self._root = SharedExtent(key="", tokens=(), page=None)  # sentinel
 
     # -- lookup -----------------------------------------------------------
@@ -113,14 +135,16 @@ class PrefixCache:
 
     # -- publication ------------------------------------------------------
 
-    def publish(self, parent: SharedExtent | None, tokens, page: Page
-                ) -> SharedExtent:
+    def publish(self, parent: SharedExtent | None, tokens, page: Page,
+                carry: Page | None = None) -> SharedExtent:
         """Publish ``page`` as the KV for ``tokens`` extending
-        ``parent`` (None = the prompt's first page). Content-hash
+        ``parent`` (None = the prompt's first page), with the carry
+        snapshot ``carry`` of a family that keeps one. Content-hash
         dedup: when the chain already carries this exact extent —
         another tenant prefilled the same prefix first — the fresh page
-        is returned to the store and the existing extent wins, so the
-        cache can never hold two copies of one prefix."""
+        and its snapshot are returned to the store and the existing
+        extent wins, so the cache can never hold two copies of one
+        prefix."""
         node = parent or self._root
         toks = tuple(int(t) for t in tokens)
         if not 0 < len(toks) <= self.page_tokens:
@@ -130,13 +154,18 @@ class PrefixCache:
                  else node.partials)
         existing = table.get(toks)
         if existing is not None:
-            if page is not existing.page:
-                self.store.free_page(page)
+            self.store.free_pages(
+                [p for p, kept in ((page, existing.page),
+                                   (carry, existing.carry))
+                 if p is not None and p is not kept])
             return existing
         page.shared = True
+        if carry is not None:
+            carry.shared = True
+            self.stats.note_carry_bytes(carry.nbytes)
         ext = SharedExtent(
             key=_chain_hash(node.key, toks), tokens=toks, page=page,
-            parent=None if node is self._root else node,
+            parent=None if node is self._root else node, carry=carry,
         )
         table[toks] = ext
         self.stats.note_extents(+1)
@@ -149,15 +178,19 @@ class PrefixCache:
 
     def acquire(self, ext: SharedExtent) -> None:
         ext.page.refs += 1
-        self.stats.note_prefix_hit(ext.page.nbytes)
+        if ext.carry is not None:
+            ext.carry.refs += 1
+        self.stats.note_prefix_hit(ext.nbytes)
         obs_journal.record("prefix_hit", key=ext.key[:12],
-                           refs=ext.page.refs, nbytes=ext.page.nbytes)
+                           refs=ext.page.refs, nbytes=ext.nbytes)
 
     def release(self, ext: SharedExtent) -> None:
         if ext.page.refs <= 0:
             raise ValueError(f"release of unreferenced extent {ext.key[:12]}")
         ext.page.refs -= 1
-        self.stats.note_prefix_release(ext.page.nbytes)
+        if ext.carry is not None:
+            ext.carry.refs -= 1
+        self.stats.note_prefix_release(ext.nbytes)
 
     # -- retention --------------------------------------------------------
 
@@ -172,18 +205,20 @@ class PrefixCache:
 
     def shared_bytes(self) -> int:
         """Bytes deduplicated: each extra reference beyond the first is
-        a page some tenant did NOT have to store privately."""
-        return sum(max(e.page.refs - 1, 0) * e.page.nbytes
+        a page, and with it a carry snapshot, some tenant did NOT have to
+        store privately."""
+        return sum(max(e.page.refs - 1, 0) * e.nbytes
                    for e in self.extents())
 
     # -- persistence (FROZEN tier, ROADMAP item 5) ------------------------
 
     def persist(self, frozen) -> int:
-        """Write every extent's page bytes + trie position into a
-        :class:`~oncilla_tpu.persist.FrozenStore` (``prefix-<chainhash>``
-        keys). Parent-first (:meth:`_walk` order) so a restored store is
-        always a valid trie prefix even if the write is cut short.
-        Returns the number of extents persisted."""
+        """Write every extent's page bytes (its carry snapshot's behind
+        them, in the one entry: a page never comes back without it) + trie
+        position into a :class:`~oncilla_tpu.persist.FrozenStore`
+        (``prefix-<chainhash>`` keys). Parent-first (:meth:`_walk` order)
+        so a restored store is always a valid trie prefix even if the
+        write is cut short. Returns the number of extents persisted."""
         n = 0
         live = {f"prefix-{ext.key}" for ext in self.extents()}
         for fkey in frozen.keys():
@@ -192,16 +227,19 @@ class PrefixCache:
             if fkey.startswith("prefix-") and fkey not in live:
                 frozen.delete(fkey)
         for ext in self.extents():
-            data = self.store.read_page(ext.page)
+            data = self.store.read_page(ext.page).tobytes()
+            if ext.carry is not None:
+                data += self.store.read_page(ext.carry).tobytes()
             frozen.write(
                 f"prefix-{ext.key}",
-                data.tobytes(),
+                data,
                 meta={
                     "kind": "prefix",
                     "key": ext.key,
                     "tokens": list(ext.tokens),
                     "parent": ext.parent.key if ext.parent else "",
                     "nbytes": int(ext.page.nbytes),
+                    "carry_nbytes": int(ext.carry.nbytes) if ext.carry else 0,
                 },
             )
             n += 1
@@ -213,7 +251,9 @@ class PrefixCache:
         the warm-boot leg. Parents restore before children (chain-hash
         identity demands it); a chain with a missing or corrupt ancestor
         is dropped WHOLE below the break (a child must never publish over
-        a hole — its chain hash would lie about the bytes beneath it).
+        a hole — its chain hash would lie about the bytes beneath it), and
+        so is one whose carry snapshot is not of :attr:`carry_nbytes` bytes
+        (persisted without one for a family that needs it, or the reverse).
         Returns the number of extents re-published."""
         import numpy as np
 
@@ -246,6 +286,11 @@ class PrefixCache:
             parent_key = meta["parent"]
             if parent_key not in published:
                 continue  # parent refused at read time below
+            if int(meta.get("carry_nbytes", 0)) != self.carry_nbytes:
+                printd("prefix restore: dropping chain at %s (its carry "
+                       "snapshot is %s B, this family's %d B)", fkey,
+                       meta.get("carry_nbytes", 0), self.carry_nbytes)
+                continue
             try:
                 data = frozen.read_bytes(fkey)
             except OcmError:
@@ -254,11 +299,13 @@ class PrefixCache:
                 printd("prefix restore: dropping chain at %s "
                        "(frozen entry refused)", fkey)
                 continue
-            page = self.store.alloc_page(
-                np.frombuffer(data, dtype=np.uint8), shared=True
-            )
+            raw = np.frombuffer(data, dtype=np.uint8)
+            cut = len(raw) - self.carry_nbytes
+            page = self.store.alloc_page(raw[:cut], shared=True)
+            carry = (self.store.alloc_page(raw[cut:], shared=True)
+                     if self.carry_nbytes else None)
             ext = self.publish(
-                published[parent_key], tuple(meta["tokens"]), page
+                published[parent_key], tuple(meta["tokens"]), page, carry
             )
             published[key] = ext
             n += 1
@@ -282,6 +329,11 @@ class PrefixCache:
                             del table[toks]
                             ext.page.shared = False
                             freed.append(ext.page)
+                            if ext.carry is not None:
+                                ext.carry.shared = False
+                                freed.append(ext.carry)
+                                self.stats.note_carry_bytes(
+                                    -ext.carry.nbytes)
                             self.stats.note_extents(-1)
                             changed = True
         self.store.free_pages(freed)

@@ -122,4 +122,28 @@ if hasattr(eng, "_pool_write_jit"):
         np.zeros(eng._POOL_GROUP, np.int32)))
     out["pool.gather"] = key(eng._pool_gather_jit.lower(
         (z(row), z(row)), np.zeros(2 * N, np.int32)))
+# The gated short-convolution family (PR 40; a tree from before lacks it).
+try:
+    from oncilla_tpu.models import conv_moe as cm
+except ImportError:
+    cm = None
+if cm is not None:
+    c5 = cm.ConvMoeConfig.tiny()
+    p5 = cm.init_params(jax.random.key(0), c5)
+    fam = cm.PAGED_FAMILY
+
+    def conv(rows=None, batch=1, tokens=P):
+        return tuple(z((rows, s[0]) + s[2:]) if rows else z(s)
+                     for s in fam.leaf_shapes(c5, tokens, batch))
+
+    def conv_carry(b):
+        (shape, dt), = fam.carry_leaves(c5, b)
+        return z(shape, dt)
+
+    out["conv.step"] = key(cm.conv_decode_batch_step_jit.lower(
+        p5, z((B,), i32), z((B, 4), i32), np.int32(B), conv(rows=N),
+        z((B, MP), i32), conv(batch=B), conv_carry(B), c5))
+    out["conv.page"] = key(cm.conv_decode_page_jit.lower(
+        p5, z((1, P), i32), z((2,), i32), conv(tokens=2 * P), conv(),
+        conv_carry(1), c5))
 print(json.dumps(out, indent=1))
