@@ -175,6 +175,11 @@ class ServingStats:
         self.frees_pages = 0
         self.frees_calls = 0
         self.frees_scrub_dispatches = 0
+        # Pages the store placed (sited in a tier: a new page, a
+        # copy-on-write clone) and the walks it made over every live page
+        # (a victim sought: a tier at its capacity or past its high mark).
+        self.places_pages = 0
+        self.places_walks = 0
         # (layer, position) pairs the seated sessions' live pages held,
         # summed over the fused steps, and what they would have held had
         # every cached layer kept every position (equal unless a kind
@@ -414,6 +419,16 @@ class ServingStats:
             self.frees_calls += 1
             self.frees_scrub_dispatches += scrub_dispatches
 
+    def note_place(self) -> None:
+        """The store sited one new page in a tier."""
+        with self._mu:
+            self.places_pages += 1
+
+    def note_walk(self) -> None:
+        """The store walked every live page once (``_victims``)."""
+        with self._mu:
+            self.places_walks += 1
+
     def note_kv(self, held: int, whole: int) -> None:
         """One fused step's context: ``held`` (layer, position) pairs in
         the seated sessions' live pages, ``whole`` had nothing been
@@ -530,6 +545,10 @@ class ServingStats:
                     "pages": self.frees_pages,
                     "calls": self.frees_calls,
                     "scrub_dispatches": self.frees_scrub_dispatches,
+                },
+                "places": {
+                    "pages": self.places_pages,
+                    "walks": self.places_walks,
                 },
                 "kv": {
                     "positions_held": self.kv_positions_held,

@@ -85,6 +85,11 @@ TIER_PRIORITY = {
 }
 
 _ORDER = (Tier.HOT, Tier.WARM, Tier.COLD, Tier.FROZEN)
+#: The tiers' names as ``occupancy()`` and the stats key them, by position
+#: in ``_ORDER``. The store's books (``_count`` / ``_bytes`` / ``_cap`` /
+#: ``_below``) are lists by that position too: ``_ORDER.index`` compares
+#: by identity, where a dict keyed on the enum runs its Python ``__hash__``.
+_NAMES = tuple(t.value for t in _ORDER)
 
 
 @dataclass(frozen=True)
@@ -169,6 +174,17 @@ class TieredPageStore:
                          Tier.COLD: int(cold_capacity),
                          Tier.FROZEN: (1 << 30) if frozen_backend is not None
                          else 0}
+        self._cap = tuple(self.capacity[t] for t in _ORDER)
+        # The tier a victim of each tier goes down to (None: the floor).
+        self._below = (Tier.WARM, Tier.COLD,
+                       Tier.FROZEN if frozen_backend is not None else None,
+                       None)
+        # The books: live pages and their bytes a tier, changed where a
+        # page enters a tier (_place), leaves one for another (_move) or
+        # leaves the store (free_pages), and nowhere else. Every question
+        # that is a count reads them; only _victims walks ``pages``.
+        self._count = [0] * len(_ORDER)
+        self._bytes = [0] * len(_ORDER)
         self.high_pct = high_pct
         self.low_pct = low_pct
         self.cold_backend = cold_backend
@@ -280,23 +296,13 @@ class TieredPageStore:
 
     # -- occupancy --------------------------------------------------------
 
-    def _live(self, tier: Tier) -> list[Page]:
-        return [p for p in self.pages.values() if p.tier == tier]
-
     def occupancy(self) -> dict:
-        out = {}
-        for t in _ORDER:
-            live = self._live(t)
-            out[t.value] = {"pages": len(live),
-                            "bytes": sum(p.nbytes for p in live)}
-        return out
+        return {name: {"pages": n, "bytes": b}
+                for name, n, b in zip(_NAMES, self._count, self._bytes)}
 
     def _sync_stats(self) -> None:
-        occ = self.occupancy()
-        self.stats.set_occupancy(
-            {k: v["pages"] for k, v in occ.items()},
-            {k: v["bytes"] for k, v in occ.items()},
-        )
+        self.stats.set_occupancy(dict(zip(_NAMES, self._count)),
+                                 dict(zip(_NAMES, self._bytes)))
 
     # -- page lifecycle ---------------------------------------------------
 
@@ -328,14 +334,14 @@ class TieredPageStore:
         """Site a new page of ``nbytes`` by the tier policy;
         ``fill(tier, handle)`` writes its bytes into the extent the policy
         chose."""
-        start = _ORDER.index(prefer)
         last_err: Exception | None = None
-        for tier in _ORDER[start:]:
+        for i in range(_ORDER.index(prefer), len(_ORDER)):
+            tier = _ORDER[i]
             # LRU residents demote to make room for the newcomer; if
             # nothing is demotable (all pinned / referenced-shared) the
             # newcomer degrades a tier instead — never the residents.
             self._make_room(tier)
-            if len(self._live(tier)) >= self.capacity[tier]:
+            if self._count[i] >= self._cap[i]:
                 continue
             try:
                 handle = self._alloc_in(tier)
@@ -349,6 +355,9 @@ class TieredPageStore:
                         shared=shared)
             self.touch(page)
             self.pages[page.page_id] = page
+            self._count[i] += 1
+            self._bytes[i] += nbytes
+            self.stats.note_place()
             self.enforce_watermarks()
             self._sync_stats()
             return page
@@ -434,6 +443,9 @@ class TieredPageStore:
         for page in pages:
             del self.pages[page.page_id]
             page.freed = True
+            i = _ORDER.index(page.tier)
+            self._count[i] -= 1
+            self._bytes[i] -= page.nbytes
         dispatches = self.ctx.free_many(
             [p.handle for p in pages if p.tier == Tier.HOT])
         for page in pages:
@@ -460,12 +472,13 @@ class TieredPageStore:
             return
         if data is None:
             data = self.read_page(page)
+        src, dst = _ORDER.index(page.tier), _ORDER.index(to)
         try:
             new_handle = self._alloc_in(to)
         except OcmError as e:
             # A full target arena cancels the move, never the page.
             self.stats.note_degrade(
-                capacity_free=len(self._live(to)) < self.capacity[to]
+                capacity_free=self._count[dst] < self._cap[dst]
             )
             printd("serving: move of page %d to %s declined: %s",
                    page.page_id, to.value, e)
@@ -474,12 +487,16 @@ class TieredPageStore:
         with self._mu:
             old_tier, old_handle = page.tier, page.handle
             page.tier, page.handle = to, new_handle
+            self._count[src] -= 1
+            self._bytes[src] -= page.nbytes
+            self._count[dst] += 1
+            self._bytes[dst] += page.nbytes
             # Any relocation invalidates in-flight prefetched bytes: a
             # worker mid-read of the OLD extent (freed and scrubbed
             # below) must see its version check fail at install time.
             page.version += 1
         self._free_handle(old_tier, old_handle)
-        promote = _ORDER.index(to) < _ORDER.index(old_tier)
+        promote = dst < src
         self.stats.note_move(promote, old_tier.value, to.value)
         obs_journal.record(
             "page_promote" if promote else "page_demote",
@@ -545,21 +562,24 @@ class TieredPageStore:
     def _victims(self, tier: Tier) -> list[Page]:
         """Demotion candidates, LRU-first. NEVER a pinned page, and
         NEVER a shared extent while referenced — the serving-side twin
-        of the reaper's never-an-active-above-low guarantee."""
+        of the reaper's never-an-active-above-low guarantee. The one walk
+        over every live page the store makes (counted: ``places.walks``),
+        so callers ask only once a page really has to go down."""
+        self.stats.note_walk()
         return sorted(
-            (p for p in self._live(tier)
-             if p.pins == 0 and not (p.shared and p.refs > 0)),
+            (p for p in self.pages.values()
+             if p.tier == tier and p.pins == 0
+             and not (p.shared and p.refs > 0)),
             key=lambda p: p.last_use,
         )
 
     def _make_room(self, tier: Tier) -> None:
         """Demote until ``tier`` has a free slot (promotion headroom)."""
-        nxt = {Tier.HOT: Tier.WARM, Tier.WARM: Tier.COLD}.get(tier)
-        if tier == Tier.COLD and self.frozen_backend is not None:
-            nxt = Tier.FROZEN
+        i = _ORDER.index(tier)
+        nxt = self._below[i]
         if nxt is None:
             return
-        while len(self._live(tier)) >= self.capacity[tier]:
+        while self._count[i] >= self._cap[i]:
             victims = self._victims(tier)
             if not victims:
                 return  # everything pinned/referenced: overshoot allowed
@@ -571,19 +591,18 @@ class TieredPageStore:
         daemon reaper's ``_pressure_evict`` shape: past high, demote
         LRU victims down to low. With a frozen backend attached, COLD is
         bounded too and spills to disk — the demote-to-FROZEN leg."""
-        pairs = [(Tier.HOT, Tier.WARM), (Tier.WARM, Tier.COLD)]
-        if self.frozen_backend is not None:
-            pairs.append((Tier.COLD, Tier.FROZEN))
-        for tier, nxt in pairs:
-            cap = self.capacity[tier]
+        for i, nxt in enumerate(self._below):
+            if nxt is None:
+                break
+            cap = self._cap[i]
             # Floor at one page: integer watermark math on a tiny tier
             # must never read "demote everything, always".
             high = max(cap * self.high_pct // 100, 1)
             low = max(cap * self.low_pct // 100, 1)
-            if len(self._live(tier)) <= high:
+            if self._count[i] <= high:
                 continue
-            for victim in self._victims(tier):
-                if len(self._live(tier)) <= low:
+            for victim in self._victims(_ORDER[i]):
+                if self._count[i] <= low:
                     break
                 self._move(victim, nxt)
 
