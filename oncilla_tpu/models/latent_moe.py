@@ -11,7 +11,8 @@ Three mechanisms, each under its own ``jax.named_scope``:
   (``qk_rope_head_dim``). A decode step *absorbs* the key half of ``wkv_b``
   into the query and applies its value half after the softmax, so it reads
   the latent as it lies in the page; a page's prefill *expands* K and V.
-- ``experts`` — sigmoid-scored top-k routing over ``n_routed_experts`` plus
+- ``experts`` — sigmoid-scored top-k routing (softmax-scored where a
+  config's ``scoring_func`` says so) over ``n_routed_experts`` plus
   shared experts, dropless: a loop over the DISTINCT experts that received a
   real token, each read once (a dynamic slice of the stacked expert
   weights) and applied to every row with that row's weight (zero where it
@@ -447,14 +448,35 @@ def route(h, params, j: int, real, cfg):
     """h: (T, D) float32; real: (T,) bool. Returns the chosen experts
     (T, k), the (T, E) weight of every expert for every row (zero where it
     was not chosen, and everywhere in a row that is padding) and which
-    experts (E,) a real row chose. With ``cfg.n_group`` over 1 the choice
-    is group-limited (:func:`_group_limit`); a config that states a
-    ``router_norm_eps`` adds it to the sum the chosen scores are divided
-    by."""
+    experts (E,) a real row chose.
+
+    Two score functions (``cfg.scoring_func``, sigmoid where a config
+    states none): ``sigmoid`` chooses on the scores plus the selection bias
+    ``e_bias``, group-limited with ``cfg.n_group`` over 1
+    (:func:`_group_limit`), and weighs the chosen scores normalised and
+    times ``routed_scaling_factor`` (a config that states a
+    ``router_norm_eps`` adds it to the sum they are divided by);
+    ``softmax`` chooses the ``k`` largest probabilities of a softmax over
+    every router output and weighs them normalised, with no bias and no
+    scaling."""
     E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
-    s = jax.nn.sigmoid(jnp.einsum(
-        "td,de->te", h, params["w_router"][j],
-        precision=jax.lax.Precision.HIGHEST))
+    logits = jnp.einsum("td,de->te", h, params["w_router"][j],
+                        precision=jax.lax.Precision.HIGHEST)
+    if getattr(cfg, "scoring_func", "sigmoid") == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(p, k)
+        chosen = jnp.take_along_axis(p, idx, axis=-1)
+        w = chosen / chosen.sum(-1, keepdims=True)
+    else:
+        w, idx = _sigmoid_choice(logits, params, j, k, cfg)
+    sel = jax.nn.one_hot(idx, E, dtype=bool) & real[:, None, None]
+    weights = jnp.where(sel, w[:, :, None], 0.0).sum(axis=1)
+    return idx, weights, sel.any(axis=(0, 1))
+
+
+def _sigmoid_choice(logits, params, j: int, k: int, cfg):
+    """The sigmoid router's (weights (T, k), chosen (T, k))."""
+    s = jax.nn.sigmoid(logits)
     pick = s + params["e_bias"][j]
     if cfg.n_group > 1:
         pick = _group_limit(pick, cfg.n_group, cfg.topk_group)
@@ -466,10 +488,7 @@ def route(h, params, j: int, real, cfg):
     total = chosen.sum(-1, keepdims=True)
     if getattr(cfg, "router_norm_eps", 0.0):
         total = total + cfg.router_norm_eps
-    w = scaled / total
-    sel = jax.nn.one_hot(idx, E, dtype=bool) & real[:, None, None]
-    weights = jnp.where(sel, w[:, :, None], 0.0).sum(axis=1)
-    return idx, weights, sel.any(axis=(0, 1))
+    return scaled / total, idx
 
 
 def expert_ffn(h, params, j: int, real, cfg):
@@ -481,10 +500,12 @@ def expert_ffn(h, params, j: int, real, cfg):
     experts give: ``cfg.experts_held`` is the (first, count) range whose
     weights the stacked leaves hold; a row whose experts all live
     elsewhere gets the shared expert alone, where the family has one (its
-    ``ws_*`` leaves). Returns (y (T, D) float32, distinct held experts
+    ``ws_*`` leaves). The choice (scores, top-k, weights) is under the
+    scope ``router``. Returns (y (T, D) float32, distinct held experts
     touched () int32, chosen experts (T, k))."""
     dt = jnp.dtype(cfg.dtype)
-    idx, weights, hit = route(h, params, j, real, cfg)
+    with jax.named_scope("router"):
+        idx, weights, hit = route(h, params, j, real, cfg)
     first, held = cfg.experts_held
     if (first, held) != (0, cfg.n_routed_experts):
         weights = weights[:, first:first + held]

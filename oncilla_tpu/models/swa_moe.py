@@ -1,6 +1,9 @@
-"""Window- and full-attention decoder with per-head gates and routed experts
-of which the chip holds a share (the Laguna shape): the family whose page
-comes in two **kinds**.
+"""Window- and full-attention decoder with routed experts of which the chip
+holds a share: the family whose page comes in two **kinds**. Two published
+shapes run through it: Laguna (per-head gates, a head count a kind, a
+leading dense layer, sigmoid-scored experts beside a shared one) and
+Mellum2 (no gate, one head count, every layer sparse, softmax-scored
+experts and no shared one).
 
 Layer ``i`` is a grouped-query attention layer of ``heads_per_layer[i]``
 query heads over ``num_key_value_heads`` KV heads. A ``full_attention``
@@ -8,16 +11,19 @@ layer attends causally to every position and rotates the leading
 ``full_partial_rotary_factor`` of a head by YaRN frequencies, cos and sin
 scaled by ``full_attention_factor``; a ``sliding_attention`` layer attends
 to its last ``sliding_window`` positions and rotates by plain frequencies.
-Every head's output is gated by a sigmoid of the layer's normed input
-before the output projection. Feed-forward layers are
-:mod:`~oncilla_tpu.models.latent_moe`'s as they stand: a dense SwiGLU in the
-leading layers, then sigmoid-scored experts beside one shared expert; the
+With ``gating`` ``per-head`` every head's output is gated by a sigmoid of
+the layer's normed input before the output projection; with ``none`` it
+is not. Feed-forward layers are :mod:`~oncilla_tpu.models.latent_moe`'s as
+they stand: a dense SwiGLU in the leading layers (none where every
+``mlp_layer_types`` entry is sparse), then experts scored by
+``scoring_func`` beside one shared expert (none where its width is 0); the
 chip holds ``num_experts`` of the router's ``router_experts``
 (``experts_held``) and computes their part. Plain pre-norm residual,
 float32. Mechanisms sit under the scopes ``attn_full``, ``attn_window``,
-``gate`` and ``experts``. The equations are written out in the plain
-reference (``benchmark/references/swa_gqa_moe.py``), which shares no code
-with this module.
+``gate`` (where there is one), ``router`` and ``experts``. The equations
+are written out in the plain references (``benchmark/references/
+swa_gqa_moe.py``, ``swa_gqa_softmax_moe.py``), which share no code with
+this module.
 
 Serving: :data:`PAGED_FAMILY` is what
 :class:`~oncilla_tpu.serving.engine.ServingEngine` takes from
@@ -60,7 +66,11 @@ class SwaMoeConfig:
     ``rope_parameters`` flattened to ``full_*`` and ``window_*``, the lists
     as tuples of ``num_hidden_layers`` entries), plus ``dtype`` and the
     chip's share: ``num_experts`` experts are HELD here, ``first_expert``
-    on, of the ``router_experts`` the router scores."""
+    on, of the ``router_experts`` the router scores. The defaults are
+    Laguna's; ``gating`` ``none``, a ``shared_expert_intermediate_size``
+    of 0, every ``mlp_layer_types`` entry sparse and ``scoring_func``
+    ``softmax`` take the gate, the shared expert, the dense layers and the
+    sigmoid router out (leaves, scopes and all)."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -90,6 +100,8 @@ class SwaMoeConfig:
     window_partial_rotary_factor: float = 1.0
     max_position_embeddings: int = 1048576
     rms_norm_eps: float = 1e-6
+    gating: str = "per-head"
+    scoring_func: str = "sigmoid"
     dtype: str = "bfloat16"
 
     def __post_init__(self):
@@ -104,6 +116,10 @@ class SwaMoeConfig:
                              "full and a window layer at least")
         if set(self.layer_types) - {FULL, WINDOW}:
             raise ValueError(f"layer_types {set(self.layer_types)}")
+        if self.gating not in ("per-head", "none"):
+            raise ValueError(f"gating {self.gating!r}")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func {self.scoring_func!r}")
         K = self.first_k_dense_replace
         if "dense" in self.mlp_layer_types[K:]:
             raise ValueError("dense layers after the first expert layer")
@@ -166,6 +182,29 @@ class SwaMoeConfig:
             full_original_max_position_embeddings=64,
             full_attention_factor=1.2, max_position_embeddings=4096,
             dtype="float32")
+        base.update(kw)
+        return SwaMoeConfig(**base)
+
+    @staticmethod
+    def tiny_softmax(**kw) -> "SwaMoeConfig":
+        """CI size of the Mellum2 shape: one period W W W F, one head count
+        (8 over 2 KV heads), no gate, every layer sparse, 16 experts all
+        held of which 4 a token by softmax, no shared expert, the whole head
+        rotated by YaRN (numbers at which its ramp has values between 0 and
+        1), a window of ten positions."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=4, num_key_value_heads=2, head_dim=16,
+            layer_types=(WINDOW, WINDOW, WINDOW, FULL),
+            num_attention_heads_per_layer=(8,) * 4,
+            mlp_layer_types=("sparse",) * 4, sliding_window=10,
+            num_experts=16, router_experts=16, num_experts_per_tok=4,
+            moe_intermediate_size=32, shared_expert_intermediate_size=0,
+            moe_routed_scaling_factor=1.0, full_rope_theta=100.0,
+            full_factor=4.0, full_original_max_position_embeddings=64,
+            full_attention_factor=1.2, full_partial_rotary_factor=1.0,
+            window_rope_theta=100.0, max_position_embeddings=4096,
+            gating="none", scoring_func="softmax", dtype="float32")
         base.update(kw)
         return SwaMoeConfig(**base)
 
@@ -279,22 +318,30 @@ def param_spec(cfg: SwaMoeConfig) -> dict:
             f"{pre}_wq": ((n, D, H * c.head_dim), s_in(D), w),
             f"{pre}_wk": ((n, D, KVd), s_in(D), w),
             f"{pre}_wv": ((n, D, KVd), s_in(D), w),
-            f"{pre}_wg": ((n, D, H), s_in(D), w),
-            f"{pre}_wo": ((n, H * c.head_dim, D), s_out(H * c.head_dim), w),
         })
+        if c.gating == "per-head":
+            spec[f"{pre}_wg"] = ((n, D, H), s_in(D), w)
+        spec[f"{pre}_wo"] = ((n, H * c.head_dim, D), s_out(H * c.head_dim), w)
+    if K:
+        spec.update({
+            "w_gate": ((K, D, F), s_in(D), w),
+            "w_up": ((K, D, F), s_in(D), w),
+            "w_down": ((K, F, D), s_out(F), w),
+        })
+    spec["w_router"] = ((Le, D, c.router_experts), s_in(D), f32)
+    if c.scoring_func == "sigmoid":
+        spec["e_bias"] = ((Le, c.router_experts), None, f32)
     spec.update({
-        "w_gate": ((K, D, F), s_in(D), w),
-        "w_up": ((K, D, F), s_in(D), w),
-        "w_down": ((K, F, D), s_out(F), w),
-        "w_router": ((Le, D, c.router_experts), s_in(D), f32),
-        "e_bias": ((Le, c.router_experts), None, f32),
         "w_gate_e": ((Le, c.num_experts, D, Fe), s_in(D), w),
         "w_up_e": ((Le, c.num_experts, D, Fe), s_in(D), w),
         "w_down_e": ((Le, c.num_experts, Fe, D), s_out(Fe), w),
-        "ws_gate": ((Le, D, Fs), s_in(D), w),
-        "ws_up": ((Le, D, Fs), s_in(D), w),
-        "ws_down": ((Le, Fs, D), s_out(Fs), w),
     })
+    if Fs:
+        spec.update({
+            "ws_gate": ((Le, D, Fs), s_in(D), w),
+            "ws_up": ((Le, D, Fs), s_in(D), w),
+            "ws_down": ((Le, Fs, D), s_out(Fs), w),
+        })
     return spec
 
 
@@ -357,7 +404,8 @@ def _rotate(x, positions, inv_freq, factor: float):
 
 def qkv_gate(h, params, i: int, positions, cfg: SwaMoeConfig):
     """h: (T, D) float32 -> rotated q (T, H, Hd), rotated k and v (T, KV,
-    Hd) and the heads' gates (T, H), all float32."""
+    Hd) and the heads' gates (T, H), all float32; the gates are None where
+    ``cfg.gating`` is ``none``."""
     dt = jnp.dtype(cfg.dtype)
     pre, m, full = _kind_of(cfg, i)
     T, hd = h.shape[0], cfg.head_dim
@@ -365,21 +413,24 @@ def qkv_gate(h, params, i: int, positions, cfg: SwaMoeConfig):
     q = lm._dot(h, params[f"{pre}_wq"][m], "td,da->ta", dt).reshape(T, -1, hd)
     k = lm._dot(h, params[f"{pre}_wk"][m], "td,da->ta", dt).reshape(T, -1, hd)
     v = lm._dot(h, params[f"{pre}_wv"][m], "td,da->ta", dt).reshape(T, -1, hd)
-    with jax.named_scope("gate"):
-        gate = jax.nn.sigmoid(
-            lm._dot(h, params[f"{pre}_wg"][m], "td,dh->th", dt))
+    gate = None
+    if cfg.gating == "per-head":
+        with jax.named_scope("gate"):
+            gate = jax.nn.sigmoid(
+                lm._dot(h, params[f"{pre}_wg"][m], "td,dh->th", dt))
     return (_rotate(q, positions, inv_freq, factor),
             _rotate(k, positions, inv_freq, factor), v, gate)
 
 
 def gated_out(o, gate, params, i: int, cfg: SwaMoeConfig):
-    """o: (T, H, Hd), gate: (T, H) -> (T, D): each head by its gate, then
-    the output projection."""
+    """o: (T, H, Hd), gate: (T, H) or None -> (T, D): each head by its
+    gate, where there is one, then the output projection."""
     pre, m, _ = _kind_of(cfg, i)
-    with jax.named_scope("gate"):
-        o = (o * gate[:, :, None]).reshape(o.shape[0], -1)
-    return lm._dot(o, params[f"{pre}_wo"][m], "ta,ad->td",
-                   jnp.dtype(cfg.dtype))
+    if gate is not None:
+        with jax.named_scope("gate"):
+            o = o * gate[:, :, None]
+    return lm._dot(o.reshape(o.shape[0], -1), params[f"{pre}_wo"][m],
+                   "ta,ad->td", jnp.dtype(cfg.dtype))
 
 
 def attend_seq(q, k, v, mask, cfg: SwaMoeConfig):

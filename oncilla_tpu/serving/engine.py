@@ -1137,6 +1137,14 @@ class ServingEngine:
         with span("prefill.residency"):
             self._ensure_resident(sess)
             ctx = self._context(sess)
+            held = [0] * len(self.kinds)
+            for e in sess.entries:
+                if not e.pending_fill:
+                    held[e.kind] += P
+            self.stats.note_kv_page(sum(
+                kind.layers * (n if kind.window is None
+                               else min(n, kind.window))
+                for kind, n in zip(self.kinds, held)))
             if sess.seat is not None:
                 self._unseat(sess)
         with span("prefill.dispatch"):

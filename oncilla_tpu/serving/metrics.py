@@ -186,6 +186,10 @@ class ServingStats:
         # drops pages).
         self.kv_positions_held = 0
         self.kv_positions_whole = 0
+        # (layer, position) pairs of the context handed to the page
+        # programs, summed over them: every position of a full layer, at
+        # most a window's of a layer with one.
+        self.kv_page_positions_read = 0
         self.preempts: dict[str, int] = {}
         # Time-to-first-token per session (submit -> first emitted
         # token), same cumulative prom-style bucket shape as the step
@@ -437,6 +441,11 @@ class ServingStats:
             self.kv_positions_held += held
             self.kv_positions_whole += whole
 
+    def note_kv_page(self, read: int) -> None:
+        """One page program's context: ``read`` (layer, position) pairs."""
+        with self._mu:
+            self.kv_page_positions_read += read
+
     def note_prefill_chunk(self) -> None:
         with self._mu:
             self.prefill_chunks += 1
@@ -553,6 +562,7 @@ class ServingStats:
                 "kv": {
                     "positions_held": self.kv_positions_held,
                     "positions_whole": self.kv_positions_whole,
+                    "page_positions_read": self.kv_page_positions_read,
                 },
                 "preempts": dict(self.preempts),
                 "ttft": {

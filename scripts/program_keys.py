@@ -122,6 +122,20 @@ if hasattr(eng, "_pool_write_jit"):
         np.zeros(eng._POOL_GROUP, np.int32)))
     out["pool.gather"] = key(eng._pool_gather_jit.lower(
         (z(row), z(row)), np.zeros(2 * N, np.int32)))
+# The window family's Mellum2 shape (PR 42; a tree from before lacks it).
+if hasattr(sm.SwaMoeConfig, "tiny_softmax"):
+    c6 = sm.SwaMoeConfig.tiny_softmax()
+    p6 = sm.init_params(jax.random.key(0), c6)
+
+    def mellum(rows=None, batch=1, tokens=P):
+        return tuple(z((rows, s[0]) + s[2:]) if rows else z(s)
+                     for s in sm.PAGED_FAMILY.leaf_shapes(c6, tokens, batch))
+
+    out["mellum.step"] = key(sm.swa_decode_batch_step_jit.lower(
+        p6, z((B,), i32), z((B, 6), i32), np.int32(B), mellum(rows=N),
+        (z((B, MP), i32), z((B, MP), i32)), mellum(batch=B), c6))
+    out["mellum.page"] = key(sm.swa_decode_page_jit.lower(
+        p6, z((1, P), i32), z((3,), i32), mellum(tokens=2 * P), mellum(), c6))
 # The gated short-convolution family (PR 40; a tree from before lacks it).
 try:
     from oncilla_tpu.models import conv_moe as cm
