@@ -65,7 +65,7 @@ class PagedFamily:
     ``aux`` back, is how many (layer, expert) pairs one token is routed
     to.
 
-    Four things are the family's to say, and most say none.
+    Five things are the family's to say, and most say none.
     ``cached_layers(cfg)`` is how many layers keep pages (the ``L`` of a
     leaf; absent: ``cfg.n_layers``). ``carry_leaves(cfg, batch)`` gives a
     family whose layers keep a recurrent state of fixed size beside (or
@@ -91,7 +91,16 @@ class PagedFamily:
     joining a session's pages into the page program's ``ctx``: ``pages``
     is, a kind, the list of that kind's pages in context order, each a
     tuple of leaves. Absent: every leaf's pages concatenated along the
-    token axis, one small program a context length and leaf shape."""
+    token axis, one small program a context length and leaf shape.
+
+    ``chunk_pages`` is the most whole pages of one prompt the ``page``
+    program takes in one dispatch (absent: 1). A family that states more,
+    which has no carry, also takes ``tokens_page`` of ``chunk_pages`` pages
+    and the count of the real ones as the keyword ``pages`` (a () int32;
+    the rows after them are padding), and returns the logits of each
+    page's last position and a tuple of leaves for each page, in order,
+    where it returns the new tails. Called without ``pages`` it is the
+    program of one page."""
 
     n_leaves: int
     leaf_dims: object
@@ -103,6 +112,7 @@ class PagedFamily:
     carry_leaves: object = None
     kinds: object = None
     context: object = None
+    chunk_pages: int = 1
 
     def page_kinds(self, cfg) -> tuple:
         if self.kinds is not None:

@@ -36,7 +36,8 @@ once it has left the window. The fused step
 (:func:`swa_decode_batch_step_jit`) reads a page pool and a block table a
 kind; the page program (:func:`swa_decode_page_jit`) a full-kind context
 padded to a power-of-two number of pages and a window-kind context of one
-size, both masked by position.
+size, both masked by position, and up to ``PAGED_FAMILY.chunk_pages`` pages
+of one prompt at once.
 """
 
 from __future__ import annotations
@@ -607,24 +608,32 @@ def swa_decode_batch_step_jit(
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("tails",))
 def swa_decode_page_jit(
     params: dict,
-    tokens_page: jax.Array,  # (1, P) one full page of token ids
+    tokens_page: jax.Array,  # (1, m * P) m full pages of token ids
     meta: jax.Array,         # (3,) int32 [pos0, full ctx_start, window's]
     ctx: tuple,              # (L, 1, KV, C, Hd): full K, V, window K, V
     tails: tuple,            # (L, 1, KV, P, Hd) as ctx (donated)
     cfg: SwaMoeConfig,
+    pages: jax.Array | None = None,  # () int32: pages [0, pages) are real
 ):
-    """One full page of prefill as ONE program that takes the page's P
-    tokens through each layer together. A context's slot ``j`` holds
-    position ``ctx_start + j`` and counts while that lies before ``pos0``;
-    the rest is padding, so one program serves every full-kind context up
-    to its length and every window-kind context. The page's own keys are
-    attended causally, a window layer's band-limited. The page's fresh K and
-    V are rounded through the tail's type before they are attended to, as a
-    later step will read them. Returns (logits (1, P, V), the full tails,
-    () int32 distinct (layer, held expert) pairs touched)."""
+    """Full pages of prefill as ONE program that takes their tokens through
+    each layer together. A context's slot ``j`` holds position ``ctx_start
+    + j`` and counts while that lies before ``pos0``; the rest is padding,
+    so one program serves every full-kind context up to its length and
+    every window-kind context. The chunk's own keys are attended causally,
+    a window layer's band-limited. Its fresh K and V are rounded through the
+    tail's type before they are attended to, as a later step will read
+    them.
+
+    Without ``pages`` the chunk is one page: returns (logits (1, P, V), the
+    full tails, () int32 distinct (layer, held expert) pairs touched). With
+    ``pages`` it is the m pages of ``tokens_page``, of which the first
+    ``pages`` are real (the rows after them are routed to no expert and
+    counted nowhere): returns (the logits of each page's last position (1,
+    m, V), a page's tails for each of the m pages in order, touched)."""
     P = tails[0].shape[3]
-    positions = meta[0] + jnp.arange(P)
-    own = jnp.tril(jnp.ones((P, P), bool))
+    T = tokens_page.shape[1]
+    positions = meta[0] + jnp.arange(T)
+    own = jnp.tril(jnp.ones((T, T), bool))
     masks = {}
     for full, n in ((True, 0), (False, 1)):
         at_ctx = meta[1 + n] + jnp.arange(ctx[2 * n].shape[3])
@@ -633,9 +642,11 @@ def swa_decode_page_jit(
              & _in_reach(cfg, full, at_ctx[None, :], positions[:, None]),
              own & _in_reach(cfg, full, positions[None, :],
                              positions[:, None])], axis=1)
-    real = jnp.ones((P,), bool)
+    real = (jnp.ones((T,), bool) if pages is None
+            else jnp.arange(T) < pages * P)
     x = params["embed"][tokens_page[0]].astype(jnp.float32)
     new_tails = list(tails)
+    fresh = [[] for _ in tails]     # each leaf's (KV, T, Hd), layer by layer
     touched = jnp.int32(0)
     for i in range(cfg.n_layers):
         _, m, full = _kind_of(cfg, i)
@@ -655,10 +666,20 @@ def swa_decode_page_jit(
             return gated_out(o, gate, params, i, cfg)
 
         x, n_hit, _ = _block(x, params, i, real, cfg, attend)
-        new_tails[at] = new_tails[at].at[m, 0].set(box["tails"][0])
-        new_tails[at + 1] = new_tails[at + 1].at[m, 0].set(box["tails"][1])
+        if pages is None:
+            new_tails[at] = new_tails[at].at[m, 0].set(box["tails"][0])
+            new_tails[at + 1] = new_tails[at + 1].at[m, 0].set(
+                box["tails"][1])
+        else:
+            fresh[at].append(box["tails"][0])
+            fresh[at + 1].append(box["tails"][1])
         touched = touched + n_hit
-    return _logits(params, x, cfg)[None], tuple(new_tails), touched
+    if pages is None:
+        return _logits(params, x, cfg)[None], tuple(new_tails), touched
+    leaves = [jnp.stack(layers)[:, None] for layers in fresh]
+    made = tuple(tuple(leaf[:, :, :, j * P:(j + 1) * P] for leaf in leaves)
+                 for j in range(T // P))
+    return _logits(params, x[P - 1::P], cfg)[None], made, touched
 
 
 def _leaf_dims(cfg: SwaMoeConfig) -> tuple:
@@ -675,8 +696,9 @@ def _step(params, tokens, meta, n_real, pool, table, tails, cfg):
         params, tokens, meta, np.int32(n_real), pool, table, tails, cfg)
 
 
-def _page(params, tokens_page, meta, ctx, tails, cfg):
-    return swa_decode_page_jit(params, tokens_page, meta, ctx, tails, cfg)
+def _page(params, tokens_page, meta, ctx, tails, cfg, pages=None):
+    return swa_decode_page_jit(params, tokens_page, meta, ctx, tails, cfg,
+                               pages)
 
 
 @jax.jit
@@ -725,8 +747,11 @@ def _assignments_per_token(cfg: SwaMoeConfig) -> int:
     return cfg.num_experts_per_tok * cfg.n_expert_layers
 
 
+# Eight pages a program: 128 tokens x the router's choices touch nearly every
+# held expert of a layer, and a page program is bound by reading them at that
+# length as at 16 tokens, so eight pages cost about what one does.
 PAGED_FAMILY = PagedFamily(
     n_leaves=4, leaf_dims=_leaf_dims, step=_step, page=_page,
     write_row=_write_row, assignments_per_token=_assignments_per_token,
-    kinds=_kinds, context=_context,
+    kinds=_kinds, context=_context, chunk_pages=8,
 )

@@ -596,6 +596,10 @@ class ServingEngine:
         self._shared = weakref.WeakValueDictionary()  # page_id -> _Entry
         self.queue: list[Request] = []
         self.active: list[_Session] = []
+        # The session whose chunk a tick took last, while some session
+        # that could prefill in that tick was not served: the next tick
+        # starts after it (_prefill_turn).
+        self._prefill_last: _Session | None = None
         self.results: list[SessionResult] = []
         # Pages of sessions that have stood up, until _free_ended frees
         # them together.
@@ -1007,14 +1011,22 @@ class ServingEngine:
                     self._note_pages_done(sess)
                     if prefetch_on:
                         self._prefetch_for(sess)
-            # Chunked prefill: a long prompt admits one page-sized slice
-            # per tick (one dispatch of the family's page program)
-            # instead of streaming its tokens through the shared batch —
-            # the batch never stalls behind a prompt.
-            chunked = False
-            for sess in self.active:
-                if not self._bulk_prefill(sess):
-                    continue
+            # Chunked prefill: a long prompt admits whole pages a tick (a
+            # chunk of up to the family's `chunk_pages`, one dispatch of its
+            # page program) instead of streaming its tokens through the
+            # shared batch — the batch never stalls behind a prompt.
+            # Chunks are taken in turn, from after the session served
+            # last, until the tick's pages reach max(n, most) for n
+            # sessions prefilling: never fewer than one page each of them.
+            # With a prefix cache a chunk is one page, so that the re-probe
+            # before it can adopt what a sibling just published.
+            most = 1 if self.prefix is not None else self.family.chunk_pages
+            ready = [s for s in self.active if self._bulk_prefill(s)]
+            budget, taken, chunked = max(len(ready), most), 0, False
+            for sess in self._prefill_turn(ready):
+                if taken >= budget:
+                    break
+                self._prefill_last = sess
                 # Span per chunk: its phases (and any cold-tier dcn fetch
                 # spans the chunk faults on) tree under it.
                 with span("serve_prefill_chunk") as chunk:
@@ -1027,11 +1039,13 @@ class ServingEngine:
                     with span("prefill.match"):
                         self._match_more(sess)
                     if self._bulk_prefill(sess):
-                        self._prefill_chunk(sess)
+                        taken += self._prefill_chunk(sess, most)
                         chunked = True
                     self._note_pages_done(sess)
                 acct[_CHUNK] += chunk.dt
                 sess.own_chunk_s += chunk.dt
+            else:
+                self._prefill_last = None   # every one served: from the top
             with span("tick.select"):
                 batch = self._select_batch(allow_force=not chunked)
             if batch:
@@ -1129,11 +1143,27 @@ class ServingEngine:
                 and len(sess.prompt) - sess.prompt_consumed
                 >= self.page_tokens)
 
-    def _prefill_chunk(self, sess: _Session) -> None:
-        """Teacher-force one full page of prompt in one fused dispatch,
-        ship it, and emit the seed token when the prompt completes."""
+    def _prefill_turn(self, ready: list[_Session]) -> list[_Session]:
+        """The sessions that can prefill (``ready``, in admission order) in
+        the order the tick takes them: from the one after the session
+        served last, round to it; from the first where every one was served
+        last tick."""
+        try:
+            at = self.active.index(self._prefill_last)
+        except ValueError:
+            return ready
+        served = {id(s) for s in self.active[:at + 1]}
+        k = sum(id(s) in served for s in ready)
+        return ready[k:] + ready[:k]
+
+    def _prefill_chunk(self, sess: _Session, most: int = 1) -> int:
+        """Teacher-force the next ``min(most, whole pages left)`` pages of
+        prompt in one dispatch of the family's page program, ship them a
+        page at a time, and emit the seed token when the prompt completes.
+        Returns the pages taken."""
         span = GLOBAL_TRACER.span
         P = self.page_tokens
+        pages = min(most, (len(sess.prompt) - sess.prompt_consumed) // P)
         with span("prefill.residency"):
             self._ensure_resident(sess)
             ctx = self._context(sess)
@@ -1149,42 +1179,58 @@ class ServingEngine:
                 self._unseat(sess)
         with span("prefill.dispatch"):
             pc = sess.prompt_consumed
-            chunk = sess.prompt[pc:pc + P]
+            chunk = sess.prompt[pc:pc + pages * P]
             # Where the chunk starts, and a kind where its context does.
             meta = jnp.asarray(
                 [sess.pos] + [n * P for n in sess.dropped], jnp.int32)
-            args = (self.params, jnp.asarray([chunk], jnp.int32), meta,
-                    ctx, sess.tails, self.cfg)
-            if self._has_carry:
-                logits, sess.tails, touched, sess.carry = self.family.page(
-                    *args, sess.carry)
+            made = None
+            if most > 1:
+                # A chunk of fewer pages fills the rest with padding: one
+                # program a context length, whatever the count.
+                tokens = chunk + [0] * ((most - pages) * P)
+                logits, made, touched = self.family.page(
+                    self.params, jnp.asarray([tokens], jnp.int32), meta, ctx,
+                    sess.tails, self.cfg, pages=np.int32(pages))
             else:
-                logits, sess.tails, touched = self.family.page(*args)
-        sess.pos += P
-        sess.tail_len = P
-        sess.page_toks = list(chunk)
-        sess.prompt_consumed += P
-        self.stats.note_tokens(P, phase="prefill")
-        self.stats.note_prefill_chunk()
+                args = (self.params, jnp.asarray([chunk], jnp.int32), meta,
+                        ctx, sess.tails, self.cfg)
+                if self._has_carry:
+                    logits, sess.tails, touched, sess.carry = (
+                        self.family.page(*args, sess.carry))
+                else:
+                    logits, sess.tails, touched = self.family.page(*args)
+        self.stats.note_prefill_chunk(pages)
         obs_journal.record("prefill_chunk", tenant=sess.req.tenant,
-                           tokens=P, pos=sess.pos)
-        if sess.prompt_consumed == len(sess.prompt):
-            with span("prefill.sync"):
-                sess.out.append(int(jnp.argmax(logits[0, -1])))
-                self._emitted.append(sess)
-                if self.keep_logits:
-                    sess.logits.append(np.asarray(logits[0, -1]))
-            self._note_first_token(sess)
-            if len(sess.out) == sess.req.max_new_tokens:
-                sess.done = True
-        with span("prefill.ship"):
-            self._ship(sess)
-            if touched is not None:
-                self._pages_touched.append(touched)
-            self._match_more(sess)
+                           tokens=pages * P, pos=sess.pos + pages * P)
+        for j in range(pages):
+            if made is not None:
+                sess.tails = made[j]
+            sess.pos += P
+            sess.tail_len = P
+            sess.page_toks = chunk[j * P:(j + 1) * P]
+            sess.prompt_consumed += P
+            self.stats.note_tokens(P, phase="prefill")
+            if sess.prompt_consumed == len(sess.prompt):
+                with span("prefill.sync"):
+                    last = logits[0, -1 if made is None else j]
+                    sess.out.append(int(jnp.argmax(last)))
+                    self._emitted.append(sess)
+                    if self.keep_logits:
+                        sess.logits.append(np.asarray(last))
+                self._note_first_token(sess)
+                if len(sess.out) == sess.req.max_new_tokens:
+                    sess.done = True
+            with span("prefill.ship"):
+                self._ship(sess)
+                if touched is not None and j == 0:
+                    self._pages_touched.append(touched)
+                self._match_more(sess)
+        # After the chunk's last ship: a window-kind page leaves only once
+        # no later query of the chunk reads it.
         if self._has_window:
             with span("prefill.drop"):
                 self._drop_passed(sess)
+        return pages
 
     def _yields_cold(self, sess: _Session) -> bool:
         """True when a seat should be given up this tick: some context

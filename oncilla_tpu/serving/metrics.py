@@ -124,7 +124,9 @@ class ServingStats:
         self.batch_size_hist = {b: 0 for b in self.BATCH_BUCKETS}
         self.step_s_sum = 0.0
         self.step_s_hist = {b: 0 for b in self.STEP_BUCKETS}
+        # Page programs (chunks) and the whole prompt pages they took.
         self.prefill_chunks = 0
+        self.prefill_pages = 0
         # The fused step's page pool, kept on the device between ticks:
         # rows a tick found in their slot, rows written (in place, or
         # into a new pool), and pools made (the first; the row bucket
@@ -446,9 +448,11 @@ class ServingStats:
         with self._mu:
             self.kv_page_positions_read += read
 
-    def note_prefill_chunk(self) -> None:
+    def note_prefill_chunk(self, pages: int = 1) -> None:
+        """One page program, over ``pages`` whole pages of a prompt."""
         with self._mu:
             self.prefill_chunks += 1
+            self.prefill_pages += pages
 
     def set_occupancy(self, tier_pages: dict[str, int],
                       tier_bytes: dict[str, int]) -> None:
@@ -518,6 +522,7 @@ class ServingStats:
                     "step_s_hist": dict(self.step_s_hist),
                     "prefill_chunks": self.prefill_chunks,
                 },
+                "prefill": {"pages": self.prefill_pages},
                 "pool": {
                     "rows_reused": self.pool_rows_reused,
                     "rows_written": self.pool_rows_written,

@@ -160,4 +160,14 @@ if cm is not None:
     out["conv.page"] = key(cm.conv_decode_page_jit.lower(
         p5, z((1, P), i32), z((2,), i32), conv(tokens=2 * P), conv(),
         conv_carry(1), c5))
+# The window family's page program over a chunk of several pages, three of
+# them real (a tree from before takes one page a program).
+K = getattr(sm.PAGED_FAMILY, "chunk_pages", 1)
+if K > 1:
+    out["swa.chunk"] = key(sm.swa_decode_page_jit.lower(
+        p4, z((1, K * P), i32), z((3,), i32), swa(tokens=2 * P), swa(), c4,
+        np.int32(3)))
+    out["mellum.chunk"] = key(sm.swa_decode_page_jit.lower(
+        p6, z((1, K * P), i32), z((3,), i32), mellum(tokens=2 * P), mellum(),
+        c6, np.int32(3)))
 print(json.dumps(out, indent=1))

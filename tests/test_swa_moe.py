@@ -288,7 +288,12 @@ def test_engine_prefill_then_decode_matches_the_reference(tiny, name):
     assert all(len(results[f"t{i}"].out_tokens) == n
                for i, n in enumerate(new))
     held_to_reference(results, prompts, params, conf, ref)
-    assert meta["batch"]["prefill_chunks"] == sum(n // P for n in lens)
+    # every whole page of prompt through a page program, up to the family's
+    # chunk_pages of one prompt a program
+    K = sm.PAGED_FAMILY.chunk_pages
+    assert meta["prefill"]["pages"] == sum(n // P for n in lens)
+    assert meta["batch"]["prefill_chunks"] == sum(
+        -(-(n // P) // K) for n in lens)
     # every page boundary shipped one page a kind; a window-kind page goes
     # once its last key is `window` positions behind the next query
     ends = [n + m - 1 for n, m in zip(lens, new)]       # positions consumed
@@ -339,7 +344,7 @@ def test_a_session_lists_a_windows_worth_of_window_pages_and_the_store_shrinks(
     rng = np.random.default_rng(7)
     lens, new = (30, 11, 21), (12, 20, 8)
     prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in lens]
-    drops = []
+    drops, seen = [], {}
 
     def watch(eng):
         drop, tick = eng._drop_passed, eng._tick
@@ -363,7 +368,11 @@ def test_a_session_lists_a_windows_worth_of_window_pages_and_the_store_shrinks(
                 assert key not in eng._pool_slots[1]
                 if key in rows:
                     assert rows[key] in eng._pool_free[1]
-            assert len(gone) <= 1       # a ship a time: one page leaves
+            # a drop follows the ships of one chunk or one step: no more
+            # pages leave than were shipped since the session's last drop
+            shipped = sess.pos // P - seen.get(sess.req.tenant, 0)
+            seen[sess.req.tenant] = sess.pos // P
+            assert len(gone) <= shipped
             drops.append((sess.req.tenant, len(gone), len(left), sess.pos))
 
         def checked_tick():
@@ -559,8 +568,8 @@ def test_the_share_of_the_experts_is_served_as_the_reference_computes_it(tiny):
 def test_the_drop_has_a_span_under_the_chunk_and_under_the_step(tiny):
     """``prefill.drop`` and ``step.drop`` run inside ``serve_prefill_chunk``
     and ``serve_batch_step`` (so the tick's unattributed share stays
-    honest), once a ship of a family with a window kind; a family without
-    one opens neither."""
+    honest), once a chunk's ships or a step's ship of a family with a
+    window kind; a family without one opens neither."""
     cfg, params, _, _ = tiny
     rng = np.random.default_rng(13)
     prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in (22, 7)]
@@ -592,9 +601,11 @@ def test_the_drop_has_a_span_under_the_chunk_and_under_the_step(tiny):
     assert {"serve_batch_step", "step.scatter"} <= inside["step.drop"]
     assert "step.ship" not in inside["step.drop"]
     count = {k: after[k] - before.get(k, 0) for k in after}
-    assert count["prefill.drop"] == count["prefill.ship"]
+    # once after a chunk's ships (a ship a page), once after a step's ship
+    assert count["prefill.drop"] == meta["batch"]["prefill_chunks"]
+    assert count["prefill.ship"] == meta["prefill"]["pages"] == 22 // P + 1
     assert count["step.drop"] == count["step.ship"]
-    assert (count["prefill.drop"] + count["step.drop"]
+    assert (count["prefill.ship"] + count["step.ship"]
             == meta["window"]["pages_shipped"])
 
 

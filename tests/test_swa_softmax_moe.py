@@ -249,17 +249,20 @@ def test_engine_prefill_then_decode_matches_the_reference(tiny, name):
     assert window["pages_dropped"] == sum(
         max((e // P * P - cfg.sliding_window) // P, 0) for e in ends) > 0
     moe = meta["moe"]
+    K = sm.PAGED_FAMILY.chunk_pages
     assert moe["page_count"] == meta["batch"]["prefill_chunks"] == sum(
-        n // P for n in lens)
+        -(-(n // P) // K) for n in lens)
+    assert meta["prefill"]["pages"] == sum(n // P for n in lens)
     assert moe["step_assignments"] == (meta["batch"]["size_sum"]
                                        * cfg.num_experts_per_tok * 4)
 
 
 def chunk_context(cfg, c: int) -> int:
-    """(layer, position) pairs of the context the page program of chunk
-    ``c`` (positions c P .. c P + P - 1) is handed: every earlier position
-    of a full layer; of a window layer the pages not yet dropped (a page
-    that starts at s goes once s + P <= pos - window), a window at most."""
+    """(layer, position) pairs of the context the page program of a chunk
+    that starts at page ``c`` (position c P) is handed: every earlier
+    position of a full layer; of a window layer the pages not yet dropped
+    (a page that starts at s goes once s + P <= pos - window), a window at
+    most."""
     dropped = max((c * P - cfg.sliding_window) // P, 0)
     return (len(cfg.full_layers) * c * P + len(cfg.window_layers)
             * min((c - dropped) * P, cfg.sliding_window))
@@ -267,9 +270,9 @@ def chunk_context(cfg, c: int) -> int:
 
 def test_the_counters_are_a_recount_of_the_routing_and_the_contexts(tiny):
     """One session, one seat: a fused step's distinct (layer, expert) pairs
-    are k a layer; a page program's are the distinct experts its P tokens
-    chose, as the reference routes them; its context is the recount of
-    :func:`chunk_context`."""
+    are k a layer; a page program's are the distinct experts the tokens of
+    its pages chose (up to ``chunk_pages`` pages of P), as the reference
+    routes them; its context is the recount of :func:`chunk_context`."""
     cfg, params, conf, ref = tiny
     prompt = np.random.default_rng(7).integers(1, cfg.vocab, 39).tolist()
     new = 8
@@ -278,22 +281,22 @@ def test_the_counters_are_a_recount_of_the_routing_and_the_contexts(tiny):
     out = results["t0"].out_tokens
     seq = np.asarray([prompt + out[:-1]], np.int32)
     routing = ref.experts_at(params, seq, conf)[:, 0]      # (L, S, k)
-    chunks = len(prompt) // P
+    pages, K = len(prompt) // P, sm.PAGED_FAMILY.chunk_pages
+    starts = range(0, pages, K)
     k, Le = cfg.num_experts_per_tok, cfg.n_expert_layers
     moe, kv = meta["moe"], meta["kv"]
-    assert moe["page_count"] == chunks
+    assert moe["page_count"] == len(starts) == 2
     assert moe["page_expert_rows"] == sum(
-        len(np.unique(routing[j, c * P:(c + 1) * P]))
-        for c in range(chunks) for j in range(Le))
+        len(np.unique(routing[j, c * P:min(c + K, pages) * P]))
+        for c in starts for j in range(Le))
     steps = meta["batch"]["steps"]
-    assert steps == len(prompt) - chunks * P + new - 1
+    assert steps == len(prompt) - pages * P + new - 1
     assert moe["step_expert_rows"] == steps * k * Le
     assert moe["step_assignments"] == steps * k * Le
     assert kv["page_positions_read"] == sum(
-        chunk_context(cfg, c) for c in range(chunks))
+        chunk_context(cfg, c) for c in starts)
     # past the window a window layer reads its window's worth, no more
-    assert chunk_context(cfg, chunks - 1) < (
-        cfg.n_layers * (chunks - 1) * P)
+    assert chunk_context(cfg, starts[-1]) < cfg.n_layers * starts[-1] * P
 
 
 def test_the_page_context_counter_sums_over_sessions_and_families(tiny):
@@ -305,8 +308,9 @@ def test_the_page_context_counter_sums_over_sessions_and_families(tiny):
     prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in lens]
     _, meta = serve(cfg, params, prompts, (3, 3, 3), max_active=3,
                     max_batch=3)
+    K = sm.PAGED_FAMILY.chunk_pages
     assert meta["kv"]["page_positions_read"] == sum(
-        chunk_context(cfg, c) for n in lens for c in range(n // P))
+        chunk_context(cfg, c) for n in lens for c in range(0, n // P, K))
     from test_swa_moe import _family_case
     dense_cfg, dense_params = _family_case("dense")
     _, meta = serve(dense_cfg, dense_params, [list(range(1, 14))], [2],
