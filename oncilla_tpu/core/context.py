@@ -290,6 +290,32 @@ class Ocm:
                     handle, data, offset, **kw
                 )
 
+    def put_many(self, handles, rows) -> int:
+        """:meth:`put` of row ``i`` of ``rows`` (a uint8 ``(n, nbytes)``
+        array) at the start of ``handles[i]``, ``n`` handles at most. The
+        LOCAL_DEVICE handles of one arena are written together
+        (``DeviceArena.write_many``) under ONE ``put`` span, whose
+        ``nbytes`` is the group's; every other handle as :meth:`put`
+        writes it. Returns the device programs the group writes took."""
+        handles = list(handles)
+        for h in handles:
+            self._check_live(h)
+        nbytes = int(rows.shape[1])
+        by_arena: dict[int, list[int]] = {}
+        for i, h in enumerate(handles):
+            if h.kind == OcmKind.LOCAL_DEVICE and not h.daemon_owned:
+                by_arena.setdefault(h.device_index, []).append(i)
+            else:
+                self.put(h, rows[i])
+        dispatches = 0
+        for index, picked in by_arena.items():
+            group = (rows if len(picked) == len(handles)
+                     else rows[np.asarray(picked)])
+            with self.tracer.span("put", nbytes=nbytes * len(picked)):
+                dispatches += self.device_arenas[index].write_many(
+                    [handles[i].extent for i in picked], group)
+        return dispatches
+
     def get(self, handle: OcmAlloc, nbytes: int | None = None, offset: int = 0,
             out=None, deadline_ms: int | None = None):
         """One-sided read (``ocm_copy_onesided`` op_flag=0). Returns uint8

@@ -168,6 +168,32 @@ def _arena_put_rows(buf2d, rows, r0):
     return jax.lax.dynamic_update_slice(buf2d, rows, (r0, 0))
 
 
+# Rows of one size written together (DeviceArena.write_many): the chained
+# in-place updates of one program, row ``picks[i]`` of ``rows`` at
+# ``starts[i]``. A group is padded to the count of ``rows`` by repeating its
+# last pair (the same bytes written twice are those bytes), so a size takes
+# one program an arena layout.
+
+
+@partial(jax.jit, donate_argnums=0)
+def _arena_put_many(buf: jax.Array, rows, starts, picks) -> jax.Array:
+    for i in range(starts.shape[0]):
+        row = jax.lax.dynamic_index_in_dim(rows, picks[i], keepdims=False)
+        buf = jax.lax.dynamic_update_slice(buf, row, (starts[i],))
+    return buf
+
+
+@partial(jax.jit, donate_argnums=0)
+def _arena_put_rows_many(buf2d, rows, r0s, picks):
+    """The blocked arena's: ``rows`` (n, nbytes) are whole blocks, written
+    from the block rows ``r0s``."""
+    blocks = rows.reshape(rows.shape[0], -1, _BLOCK)
+    for i in range(r0s.shape[0]):
+        row = jax.lax.dynamic_index_in_dim(blocks, picks[i], keepdims=False)
+        buf2d = jax.lax.dynamic_update_slice(buf2d, row, (r0s[i], 0))
+    return buf2d
+
+
 @partial(jax.jit, donate_argnums=0, static_argnums=(3,))
 def _arena_put_window(buf2d, raw, r0, nrows, intra):
     """Unaligned write via a row window: slice the covering rows, patch the
@@ -413,6 +439,42 @@ class DeviceArena:
                 self._buf = _arena_put_window(
                     self._buf, raw, self._idx(r0), nrows, self._idx(intra)
                 )
+
+    def write_many(self, extents, rows) -> int:
+        """:meth:`write` of row ``i`` of ``rows`` (a uint8 ``(n, nbytes)``
+        array) at the start of ``extents[i]``, for as many extents as are
+        given, ``n`` at most: ONE program for the group, padded to ``n``,
+        so that one program serves a size an arena layout. An extent the
+        program cannot take (one the DMA kernels would write, or, in a
+        blocked arena, one off a block boundary or a size that is no whole
+        count of blocks) is written by :meth:`write`. Returns the device
+        programs dispatched."""
+        extents = list(extents)
+        rows = jax.device_put(rows, self.device)
+        n, nbytes = rows.shape
+        if len(extents) > n:
+            raise ValueError(f"{len(extents)} extents for {n} rows")
+        unit = _BLOCK if self._blocked else 1
+        grouped, alone = [], []
+        for i, extent in enumerate(extents):
+            check_bounds(extent, 0, nbytes)
+            if (self._dma_eligible(extent.offset, nbytes)
+                    or extent.offset % unit or nbytes % unit):
+                alone.append(i)
+            else:
+                grouped.append(i)
+        for i in alone:
+            self.write(extents[i], rows[i])
+        if not grouped:
+            return len(alone)
+        picks = grouped + grouped[-1:] * (n - len(grouped))
+        starts = np.asarray([extents[i].offset // unit for i in picks],
+                            np.int32)
+        put = _arena_put_rows_many if self._blocked else _arena_put_many
+        with self._mu:
+            self._buf = put(self._buf, rows, starts,
+                            np.asarray(picks, np.int32))
+        return len(alone) + 1
 
     def read(self, extent: Extent, nbytes: int, offset: int = 0) -> jax.Array:
         """One-sided get; returns a fresh uint8 jax.Array of ``nbytes``."""

@@ -155,6 +155,19 @@ def _pack_pages_jit(kinds: tuple, dtype: str) -> tuple:
                  for leaves in kinds)
 
 
+@partial(jax.jit, static_argnames=("dtype",))
+def _pack_chunk_jit(kinds: tuple, dtype: str) -> tuple:
+    """A chunk's page tails on their way into the store, in ONE dispatch:
+    ``kinds[k][j]`` is page ``j``'s leaves of kind ``k``, each page packed
+    as :func:`_pack_pages_jit` packs it. Returns a uint8 ``(pages,
+    nbytes)`` array a kind; a chunk's padding pages are packed too, so one
+    program serves every count of real pages."""
+    return tuple(
+        jnp.stack([to_bytes(jnp.stack(leaves).astype(jnp.dtype(dtype)))
+                   for leaves in pages])
+        for pages in kinds)
+
+
 # A carry on its way into a prefix extent and back (a family with a carry,
 # the prefix cache on): every leaf's bytes one after another, the float32
 # they are held in, so a snapshot round-trips bit for bit.
@@ -1203,8 +1216,6 @@ class ServingEngine:
         obs_journal.record("prefill_chunk", tenant=sess.req.tenant,
                            tokens=pages * P, pos=sess.pos + pages * P)
         for j in range(pages):
-            if made is not None:
-                sess.tails = made[j]
             sess.pos += P
             sess.tail_len = P
             sess.page_toks = chunk[j * P:(j + 1) * P]
@@ -1221,7 +1232,19 @@ class ServingEngine:
                 if len(sess.out) == sess.req.max_new_tokens:
                     sess.done = True
             with span("prefill.ship"):
-                self._ship(sess)
+                if made is None:
+                    self._ship(sess)
+                else:
+                    if j == 0:
+                        # The chunk's pages go into the store together,
+                        # in the first page's ship.
+                        placed = self._place_chunk(made, pages)
+                    for k, page in enumerate(placed[j]):
+                        sess.entries.append(_Entry(
+                            page=page, arrays=made[j][self._kind_leaves[k]],
+                            version=page.version, kind=k))
+                    if j == pages - 1:
+                        sess.reset_tail()
                 if touched is not None and j == 0:
                     self._pages_touched.append(touched)
                 self._match_more(sess)
@@ -1231,6 +1254,22 @@ class ServingEngine:
             with span("prefill.drop"):
                 self._drop_passed(sess)
         return pages
+
+    def _place_chunk(self, made: tuple, pages: int) -> list:
+        """The first ``pages`` of a chunk's page tails ``made`` as stored
+        pages, a tuple of one a kind for each page in order: one pack of
+        the whole chunk and one :meth:`TieredPageStore.alloc_pages` a kind
+        (what :meth:`_ship` does a page at a time for a session's private
+        pages)."""
+        packed = _pack_chunk_jit(
+            tuple(tuple(tail[leaves] for tail in made)
+                  for leaves in self._kind_leaves),
+            self.store_dtype)
+        by_kind = [self.store.alloc_pages(rows, pages) for rows in packed]
+        for kind in self.kinds:
+            if kind.window is not None:
+                self.stats.note_window(shipped=pages)
+        return list(zip(*by_kind))
 
     def _yields_cold(self, sess: _Session) -> bool:
         """True when a seat should be given up this tick: some context

@@ -182,6 +182,11 @@ class ServingStats:
         # (a victim sought: a tier at its capacity or past its high mark).
         self.places_pages = 0
         self.places_walks = 0
+        # Pages the store wrote into HOT a group at a time (alloc_pages'
+        # group path) and the device programs those writes took; absent
+        # from the snapshot until a group has been written.
+        self.hot_writes_pages = 0
+        self.hot_writes_dispatches = 0
         # (layer, position) pairs the seated sessions' live pages held,
         # summed over the fused steps, and what they would have held had
         # every cached layer kept every position (equal unless a kind
@@ -430,6 +435,13 @@ class ServingStats:
         with self._mu:
             self.places_pages += 1
 
+    def note_hot_write(self, pages: int, dispatches: int) -> None:
+        """The store placed ``pages`` new pages in HOT together, their
+        bytes written by ``dispatches`` device programs."""
+        with self._mu:
+            self.hot_writes_pages += pages
+            self.hot_writes_dispatches += dispatches
+
     def note_walk(self) -> None:
         """The store walked every live page once (``_victims``)."""
         with self._mu:
@@ -474,7 +486,7 @@ class ServingStats:
         with self._mu:
             lookups, hits = self.lookups, self.hits
             itl = _filed(self._itl, _ITL_FIELDS)
-            return {
+            snap = {
                 "engine": self.engine,
                 "tokens": {
                     "prefill": self.prefill_tokens,
@@ -586,6 +598,12 @@ class ServingStats:
                     "hist": itl,
                 },
             }
+            if self.hot_writes_pages:
+                snap["hot_writes"] = {
+                    "pages": self.hot_writes_pages,
+                    "dispatches": self.hot_writes_dispatches,
+                }
+            return snap
 
 
 # -- co-located publication -------------------------------------------------

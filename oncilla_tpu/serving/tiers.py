@@ -330,6 +330,72 @@ class TieredPageStore:
             lambda tier, handle: self._put(tier, handle, data), shared,
             prefer, nbytes)
 
+    def alloc_pages(self, rows, count: int | None = None,
+                    shared: bool = False) -> list[Page]:
+        """Store the first ``count`` rows of ``rows`` (a uint8 ``(n,
+        nbytes)`` array, on the device or not; all ``n`` by default) as
+        pages, as that many :meth:`alloc_page` calls would: the same page
+        ids, ``last_use`` order, tiers, books and stats. Where HOT takes
+        every one at or below its high mark and no tier stands above its
+        own, no page can be demoted between them: their HOT extents are
+        taken, written by ONE ``Ocm.put_many`` and booked, and the
+        watermarks and the stats are brought up once. Otherwise, or where
+        the device arena refuses an extent, page by page."""
+        n, nbytes = (int(d) for d in rows.shape)
+        count = n if count is None else int(count)
+        if not 0 < nbytes <= self.page_bytes or not 0 <= count <= n:
+            raise ValueError(
+                f"{count} pages of {nbytes} B from {n} rows, store built "
+                f"for pages of {self.page_bytes} B at most"
+            )
+        handles = self._hot_extents(count) if self._fits_hot(count) else None
+        if handles is None:
+            return [self.alloc_page(rows[i], shared=shared)
+                    for i in range(count)]
+        if not handles:
+            return []
+        dispatches = self.ctx.put_many(handles, rows)
+        pages = []
+        for handle in handles:
+            page = Page(next(self._ids), nbytes, Tier.HOT, handle,
+                        shared=shared)
+            self.touch(page)
+            self.pages[page.page_id] = page
+            self.stats.note_place()
+            pages.append(page)
+        self._count[0] += count
+        self._bytes[0] += count * nbytes
+        self.stats.note_hot_write(count, dispatches)
+        self.enforce_watermarks()
+        self._sync_stats()
+        return pages
+
+    def _fits_hot(self, count: int) -> bool:
+        """Whether ``count`` more pages in HOT leave it within its
+        capacity and high mark, and every tier is within its own: then
+        neither a placement's make-room nor a watermark sweep demotes."""
+        if self._count[0] + count > self._cap[0]:
+            return False
+        for i, nxt in enumerate(self._below):
+            if nxt is None:
+                break
+            high = max(self._cap[i] * self.high_pct // 100, 1)
+            if self._count[i] + (count if i == 0 else 0) > high:
+                return False
+        return True
+
+    def _hot_extents(self, count: int) -> list | None:
+        """``count`` HOT extents, or None (and none kept) where the device
+        arena refuses one."""
+        handles = []
+        try:
+            for _ in range(count):
+                handles.append(self._alloc_in(Tier.HOT))
+        except OcmError:
+            self.ctx.free_many(handles)
+            return None
+        return handles
+
     def _place(self, fill, shared: bool, prefer: Tier, nbytes: int) -> Page:
         """Site a new page of ``nbytes`` by the tier policy;
         ``fill(tier, handle)`` writes its bytes into the extent the policy

@@ -41,6 +41,22 @@ def test_write_read_beyond_int32(big_arena, rng):
     a.free(first)
 
 
+def test_write_many_beyond_int32(big_arena, rng):
+    # Rows written a group at a time past the int32 cliff: one program,
+    # addressed by block rows, the group padded to the rows' count.
+    a = big_arena
+    first = a.alloc(2 * GIB)
+    exts = [a.alloc(8 * _BLOCK) for _ in range(3)]
+    assert exts[-1].offset > 2**31
+    rows = rng.integers(0, 256, (4, 8 * _BLOCK), dtype=np.uint8)
+    assert a.write_many(exts, rows) == 1
+    for i, ext in enumerate(exts):
+        np.testing.assert_array_equal(
+            np.asarray(a.read(ext, 8 * _BLOCK)), rows[i])
+        a.free(ext)
+    a.free(first)
+
+
 def test_unaligned_window_write_read(big_arena, rng):
     # Byte ranges straddling block boundaries go through the window path.
     a = big_arena

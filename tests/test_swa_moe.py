@@ -444,27 +444,36 @@ def test_both_kinds_move_through_the_tiers_alike(tiny):
 
     def watch(eng):
         move, free_pages = eng.store._move, eng.store.free_pages
-        kind_of = {}
-        ship = eng._ship
+        # The kinds' pages differ in size: a page's size says its kind.
+        sizes = [4 * int(np.prod(shape)) for shape in eng.page_shapes]
+        assert len(set(sizes)) == 2
+        ship, place_chunk = eng._ship, eng._place_chunk
+
+        def note(pages):
+            assert [p.nbytes for p in pages] == sizes
 
         def shipped(sess):
             ship(sess)
-            for e in sess.entries[-2:]:
-                kind_of[e.page.page_id] = e.kind
-                assert e.page.nbytes == (
-                    4 * int(np.prod(eng.page_shapes[e.kind])))
+            note([e.page for e in sess.entries[-2:]])
+
+        def placed_chunk(made, pages):
+            placed = place_chunk(made, pages)
+            for kinds in placed:
+                note(kinds)
+            return placed
 
         def moving(page, to, data=None):
-            moved.add((kind_of[page.page_id], to.value))
+            moved.add((sizes.index(page.nbytes), to.value))
             move(page, to, data=data)
 
         def freeing(pages):
             for page in pages:
-                if kind_of.get(page.page_id) == 1 and eng.active:
+                if sizes.index(page.nbytes) == 1 and eng.active:
                     dropped_from.add(page.tier.value)
             free_pages(pages)
 
         eng._ship, eng.store._move = shipped, moving
+        eng._place_chunk = placed_chunk
         eng.store.free_pages = freeing
 
     results, meta = serve(cfg, params, prompts, new, hot=6, warm=8,
@@ -754,21 +763,26 @@ def _family_case(name):
 
 @pytest.mark.parametrize("name", ["dense", "latent", "kda", "swa"])
 def test_every_familys_ship_hands_the_store_its_page_on_the_device(name):
-    """ONE ship path: whatever the family, a page goes to the store as a
-    uint8 vector that lies on the device (HOT takes it device to device),
-    and a family with experts has its chunks' expert counts looked at
-    later, all of them."""
+    """Whatever the family, a page goes to the store as a uint8 vector
+    that lies on the device (HOT takes it device to device), or, a chunk
+    of several pages, as rows of one uint8 array there, and a family with
+    experts has its chunks' expert counts looked at later, all of them."""
     cfg, params = _family_case(name)
     handed = []
 
     def watch(eng):
-        alloc_page = eng.store.alloc_page
+        alloc_page, alloc_pages = eng.store.alloc_page, eng.store.alloc_pages
 
         def alloc(data, *a, **kw):
             handed.append(data)
             return alloc_page(data, *a, **kw)
 
-        eng.store.alloc_page = alloc
+        def alloc_many(rows, count, *a, **kw):
+            assert isinstance(rows, jax.Array) and rows.ndim == 2
+            handed.extend(rows[i] for i in range(count))
+            return alloc_pages(rows, count, *a, **kw)
+
+        eng.store.alloc_page, eng.store.alloc_pages = alloc, alloc_many
 
     rng = np.random.default_rng(5)
     prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in (13, 6)]

@@ -504,12 +504,14 @@ def test_a_window_familys_run_with_hot_ample_walks_nothing(monkeypatch):
     def watch(eng):
         eng.store.pages = WatchedPages()
         seen["ships"] = count_calls(monkeypatch, eng, "_ship")
+        seen["chunks"] = count_calls(monkeypatch, eng, "_place_chunk")
         seen["store"], seen["kinds"] = eng.store, len(eng.kinds)
 
     _, meta = serve(cfg, params, prompts, [9, 6, 5], watch=watch)
-    ships = len(seen["ships"])
+    # a chunk's pages are placed together, a decode's page by its ship
+    ships = len(seen["ships"]) + sum(pages for _, pages in seen["chunks"])
     assert ships > 10 and meta["window"]["pages_dropped"] > 0
-    # a page a kind a ship, dropped window pages and all
+    # a page a kind a page shipped, dropped window pages and all
     assert meta["places"] == {"pages": seen["kinds"] * ships,
                               "walks": 0}
     assert meta["places"]["pages"] == meta["frees"]["pages"]
